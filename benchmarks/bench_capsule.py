@@ -125,34 +125,24 @@ def main() -> None:
         f"{fused_op.uhat_hbm_bytes:.0f})")
 
     # STREAMED schedule (forced by a budget under the resident floor):
-    # the fused s+b pass streams W iters+1 times per forward where the
-    # 2-pass oracle streamed it 2*iters+1 times -- both timed, plus the
-    # modeled W traffic each moves and the fused backward's iters+4.
+    # the fused s+b pass streams W iters+1 times per forward -- timed,
+    # plus the modeled W traffic and the fused backward's iters+4.
     iters = CFG.routing_iters
     floor = execplan._fused_resident_vmem(
-        BATCH, CFG.num_primary, 1, CFG.primary_dim, jd, CFG.num_classes)
+        BATCH, CFG.num_primary, execplan._min_block_i(CFG.num_primary),
+        CFG.primary_dim, jd, CFG.num_classes)
     tight = plan_votes_routing(CFG.num_primary, CFG.primary_dim, jd,
                                CFG.num_classes, batch=BATCH, iters=iters,
                                vmem_budget=floor - 1)
-    stre, us = timed(lambda: np.asarray(ops.votes_routing(
+    _, us = timed(lambda: np.asarray(ops.votes_routing(
         u, w, iters=iters, mode=tight.mode, block_i=tight.block_i,
         bwd_mode=tight.mode, bwd_block_i=tight.block_i)))
     row("votes-routing-streamed-fused", us,
         f"mode={tight.mode} block_i={tight.block_i} w_passes={tight.n_passes}")
-    oracle, us = timed(lambda: np.asarray(ops.votes_routing(
-        u, w, iters=iters, mode="streamed-2pass", block_i=tight.block_i,
-        bwd_mode="streamed-2pass", bwd_block_i=tight.block_i)))
-    row("votes-routing-streamed-2pass", us,
-        f"w_passes={2 * iters + 1} maxdiff={np.abs(stre - oracle).max():.2e}")
     stre_bytes = votes_routing_hbm_bytes(BATCH, CFG.num_primary,
                                          CFG.primary_dim, jd, tight.n_passes)
-    oracle_bytes = votes_routing_hbm_bytes(BATCH, CFG.num_primary,
-                                           CFG.primary_dim, jd, 2 * iters + 1)
     row("votes-routing/hbm-bytes-streamed", 0.0,
         f"{stre_bytes:.0f} (W x {tight.n_passes} = iters+1 passes)")
-    row("votes-routing/hbm-bytes-streamed-2pass", 0.0,
-        f"{oracle_bytes:.0f} (W x {2 * iters + 1} passes; fused saves "
-        f"{oracle_bytes - stre_bytes:.0f})")
     row("votes-routing-bwd/hbm-bytes-streamed", 0.0,
         f"{votes_routing_bwd_hbm_bytes(BATCH, CFG.num_primary, CFG.primary_dim, jd, mode='streamed', iters=iters):.0f} "
         f"(W x {iters + 4} = iters+4 passes)")

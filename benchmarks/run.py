@@ -32,6 +32,7 @@ from benchmarks import (bench_capsule, bench_dataflow, bench_fig4,
                         bench_fig5, bench_fig10, bench_fig11, bench_kernels,
                         bench_paper_validation, bench_planner, bench_roofline,
                         bench_table2, common)
+from repro.core.compile_cache import enable_compile_cache
 
 MODULES = {
     "capsule": bench_capsule,
@@ -227,6 +228,7 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown module(s) {unknown}; choose from {list(MODULES)}")
     names = args.modules or list(MODULES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for name in names:

@@ -29,6 +29,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core import capsnet  # noqa: E402
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.energy import SRAMConfig  # noqa: E402
 from repro.core.execplan import compile_plan  # noqa: E402
 from repro.core.pmu import schedule_from_plan  # noqa: E402
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--use-async", action="store_true",
                     help="submit through the asyncio host loop")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = capsnet.CapsNetConfig(image_hw=14, conv1_channels=16,
                                 conv1_kernel=5, pc_kernel=3,

@@ -216,7 +216,11 @@ def init_params(key: jax.Array, cfg: CapsNetConfig = CapsNetConfig(),
     return params
 
 
-def squash(s: jax.Array, axis: int = -1, eps: float = 1e-7) -> jax.Array:
+SQUASH_EPS = 1e-7
+
+
+def squash(s: jax.Array, axis: int = -1,
+           eps: float = SQUASH_EPS) -> jax.Array:
     """v = ||s||^2 / (1 + ||s||^2) * s / ||s|| (paper Sec. 2.1)."""
     sq = jnp.sum(jnp.square(s), axis=axis, keepdims=True)
     return (sq / (1.0 + sq)) * s * jax.lax.rsqrt(sq + eps)
@@ -301,7 +305,7 @@ def forward(params: Params, images: jax.Array,
             cfg: CapsNetConfig = CapsNetConfig(), *,
             labels: jax.Array | None = None,
             backend: str = "jnp", plan=None,
-            interpret: bool = True) -> dict[str, jax.Array]:
+            interpret: bool | None = None) -> dict[str, jax.Array]:
     """images: [B, H, W, C] in [0, 1] -> class capsules + reconstruction.
 
     ``backend="jnp"`` (default) is the pure-JAX reference.
@@ -314,8 +318,9 @@ def forward(params: Params, images: jax.Array,
     routing, the inter-layer activation u resident in VMEM); a per-op
     plan runs the three-call path (conv_im2col PrimaryCaps with fused
     squash -> fused votes_routing megakernel) -- the pipelined plan's
-    fallback and parity oracle.  ``interpret=True`` validates on CPU,
-    pass False on real TPU.
+    fallback and parity oracle.  The kernels compile with Mosaic on a TPU
+    and run in the Pallas interpreter elsewhere unless ``interpret`` says
+    otherwise (``kernels.ops.should_interpret``).
 
     ``labels`` masks the reconstruction decoder with the true class
     (training semantics); when omitted the decoder masks with argmax.
@@ -414,7 +419,8 @@ def margin_loss(lengths: jax.Array, labels: jax.Array,
 def total_loss(params: Params, images: jax.Array, labels: jax.Array,
                cfg: CapsNetConfig = CapsNetConfig(),
                recon_weight: float = 0.0005, *, backend: str = "jnp",
-               plan=None, interpret: bool = True) -> tuple[jax.Array, dict]:
+               plan=None,
+               interpret: bool | None = None) -> tuple[jax.Array, dict]:
     """Margin loss + masked reconstruction, differentiable on BOTH backends.
 
     The decoder reconstructs the LABELED capsule (training semantics), so
@@ -443,7 +449,7 @@ def total_loss(params: Params, images: jax.Array, labels: jax.Array,
 def train_step(params: Params, images: jax.Array, labels: jax.Array,
                cfg: CapsNetConfig = CapsNetConfig(),
                lr: float = 1e-3, *, backend: str = "jnp",
-               interpret: bool = True) -> tuple[Params, dict]:
+               interpret: bool | None = None) -> tuple[Params, dict]:
     (_, metrics), grads = jax.value_and_grad(total_loss, has_aux=True)(
         params, images, labels, cfg, backend=backend, interpret=interpret)
     params = jax.tree_util.tree_map(lambda p, g: p - lr * g, params, grads)

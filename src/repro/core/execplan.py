@@ -29,8 +29,9 @@ from typing import Sequence
 from repro.core import analysis
 from repro.core.analysis import OperationProfile
 from repro.core.capsnet import CapsNetConfig
-from repro.core.planner import (MXU, VMEM_BYTES, BlockPlan, MatmulWorkload,
-                                plan_matmul)
+from repro.core.planner import (LANES, MXU, SUBLANES, VMEM_BYTES,
+                                BlockPlan, MatmulWorkload,
+                                plan_matmul, tile_padded)
 from repro.core.pmu import PhaseRequirement
 
 # Kernels run in fp32 (interpret-mode validated; fp32 accumulation on TPU).
@@ -40,6 +41,10 @@ SQUASH_BLOCK_ROWS = 1024
 # The fused ClassCaps megakernel: ONE plan op / PMU phase covering the
 # dataflow model's ClassCaps-FC + Sum+Squash + Update+Sum operations.
 FUSED_NAME = "ClassCaps-Routing"
+# Routing kernel layouts in plan preference order: input capsules on the
+# lanes first (dense for narrow heads), output capsules on the lanes for
+# layers whose W tile or class vectors do not fit that way.
+LAYOUTS = ("caps", "classes")
 FUSED_COVERS = ("ClassCaps-FC", "Sum+Squash", "Update+Sum")
 
 # The pipelined producer->consumer pair: PrimaryCaps' squash-epilogue
@@ -100,10 +105,10 @@ class OpPlan:
     uhat_hbm_bytes: float | None = None
     intermediate_hbm_bytes: float | None = None
     block_k: int | None = None   # pipelined produce-phase K tile
-    # im2col extraction row block (conv and pipelined ops): None emits
-    # the full patch matrix per batch element; a degraded budget blocks
-    # the extraction so VMEM holds image + patch_rows rows only.
-    patch_rows: int | None = None
+    # Routing kernel layout: which capsule axis lies on the 128 lanes --
+    # "caps" (input capsules I) or "classes" (output capsules J); see
+    # ``kernels.votes_routing``.  None for ops without a routing kernel.
+    lanes: str | None = None
     # Modeled W-stream pass count of the fused/pipelined kernels (1
     # resident / iters+1 streamed forward, 2 / iters+4 backward; None
     # for ops without a W stream).  A first-class plan claim so the
@@ -265,6 +270,8 @@ class ExecutionPlan:
         for op in self.ops:
             if op.mode is not None and op.mode not in ("resident", "streamed"):
                 raise PlanError(f"{op.name}: unknown mode {op.mode!r}")
+            if op.lanes is not None and op.lanes not in LAYOUTS:
+                raise PlanError(f"{op.name}: unknown lanes {op.lanes!r}")
             if op.vmem_bytes > self.vmem_budget:
                 raise PlanError(
                     f"{op.name}: VMEM footprint {op.vmem_bytes} exceeds "
@@ -291,6 +298,7 @@ class ExecutionPlan:
                 block_i=op.block_i,
                 block_rows=op.block_rows,
                 mode=op.mode,
+                lanes=op.lanes,
                 n_passes=op.n_passes,
                 vmem_kib=op.vmem_bytes / 1024,
                 est_cycles=op.est_cycles,
@@ -405,32 +413,76 @@ class VotesRoutingSchedule:
     vmem_bytes: int          # footprint of the CHOSEN schedule
     n_passes: int            # W streams: 1 resident, iters+1 streamed
     workload: MatmulWorkload
+    lanes: str = "caps"      # kernel layout (see ``LAYOUTS``)
 
 
 def _i_padded(num_caps: int, block_i: int) -> int:
     return math.ceil(num_caps / block_i) * block_i
 
 
-def _pad_min_block_i(num_caps: int, bi0: int) -> int:
-    """Shrink a generic matmul ``block_m`` pick to the halving candidate
-    with the least i-padding (ties keep the largest tile), floored at the
-    MXU-aligned 128 rows.
+def _lane_block_i(num_caps: int, bi0: int) -> int:
+    """The lane-legal i-tile nearest a generic matmul ``block_m`` pick.
 
-    The fused/pipelined kernels zero-pad u/W/scratch to
-    ``ceil(I/block_i) * block_i`` rows, so the generic pick can be
-    catastrophically wasteful: block_i=1024 over MNIST's I=1152 pads to
-    2048 rows -- 78% phantom W traffic on every stream and ~5 MB of dead
-    votes scratch -- where block_i=128 divides 1152 exactly.  The static
+    The caps-on-lanes routing layout keeps the capsule axis on the
+    128-lane axis, so an i-tile is either the whole axis (one block) or a
+    multiple of 128.  Among the multiples of 128 up to ``bi0`` the pick
+    minimizes the zero-padded ``ceil(I/block_i) * block_i`` rows the
+    kernels allocate and stream (ties keep the largest): block_i=1024
+    over MNIST's I=1152 would pad to 2048 rows -- 78% phantom W traffic
+    on every stream -- where 384 or 128 divide 1152 exactly.  The static
     auditor (repro.verify.lowering) found exactly this drift between the
-    modeled traffic and the lowering's index maps.
-    """
-    floor = min(bi0, 128)
-    best, bi = bi0, bi0
-    while bi >= floor and bi >= 1:
-        if _i_padded(num_caps, bi) < _i_padded(num_caps, best):
-            best = bi
-        bi //= 2
-    return best
+    modeled traffic and the lowering's index maps."""
+    if num_caps <= LANES or bi0 >= num_caps:
+        return num_caps
+    return min(range(max(bi0 // LANES, 1) * LANES, 0, -LANES),
+               key=lambda bi: (_i_padded(num_caps, bi), -bi))
+
+
+def _shrink_ladder(start: int) -> list[int]:
+    """Lane-legal tiles of a lane axis from ``start`` down:
+    ``start`` (the whole axis or a multiple of 128), then the largest
+    multiple of 128 below a ragged ``start``, then successive halvings
+    rounded down to multiples of 128.  A tile below one lane tile frees
+    no VMEM under the (8, 128) tiling, so the ladder stops at 128 -- or
+    at ``start`` itself when the axis is shorter."""
+    out = [start]
+    t = (start // LANES * LANES if start % LANES
+         else start // 2 // LANES * LANES)
+    while t >= LANES and t < out[-1]:
+        out.append(t)
+        t = t // 2 // LANES * LANES
+    return out
+
+
+def _sublane_ladder(num_caps: int) -> list[int]:
+    """i-tiles of the classes-on-lanes layout, where I lies on sublanes:
+    the whole axis when it is at most one 128-row tile, else 128, then
+    halvings rounded down to multiples of 8, down to 8."""
+    out = [min(num_caps, LANES)]
+    t = out[0] // 2 // SUBLANES * SUBLANES
+    while t >= SUBLANES:
+        out.append(t)
+        t = t // 2 // SUBLANES * SUBLANES
+    return out
+
+
+def _i_ladder(lanes: str, num_caps: int, caps_dim: int, jd: int
+              ) -> list[int]:
+    """Candidate i-tiles of one layout, largest first."""
+    if lanes == "classes":
+        return _sublane_ladder(num_caps)
+    wl = MatmulWorkload(m=num_caps, k=caps_dim, n=jd, in_bytes=ELEM_BYTES)
+    # Tile-shape pick only (the per-mode footprint model is what is held
+    # to the budget, not the generic double-buffered matmul model),
+    # refined to the lane-legal, i-padding-minimal candidate.
+    return _shrink_ladder(_lane_block_i(
+        num_caps, max(min(plan_matmul(wl).block_m, num_caps), 1)))
+
+
+def _min_block_i(num_caps: int, lanes: str = "caps") -> int:
+    return min(num_caps, SUBLANES if lanes == "classes" else LANES)
+
+
 def _i_buf(num_caps: int, block_i: int) -> int:
     """Tile buffer count: 2 (double-buffered) when the i-axis spans more
     than one block, 1 when a single block covers it -- a block whose
@@ -440,46 +492,117 @@ def _i_buf(num_caps: int, block_i: int) -> int:
     return 2 if _i_padded(num_caps, block_i) > block_i else 1
 
 
-def _fused_resident_vmem(batch: int, num_caps: int, block_i: int,
-                         caps_dim: int, jd: int, j: int) -> int:
-    """Resident schedule: the full votes tensor + routing logits live in
-    VMEM scratch while double-buffered u/W i-tiles stream past once; each
-    grid step also materializes one [B, block_i, J*D] votes block before
-    storing it into the scratch."""
+def _caps_vec(batch: int, j: int, d: int, lanes: str = "caps") -> int:
+    """One class-capsule vector buffer: [B, J, D, 1] (D on sublanes, one
+    lane) with caps on lanes, [B, D, 1, J] with classes on lanes."""
+    if lanes == "classes":
+        return tile_padded((batch, d, 1, j))
+    return tile_padded((batch, j, d, 1))
+
+
+def _u_buf(batch: int, num_caps: int, block_i: int, caps_dim: int,
+           lanes: str) -> int:
+    """u as the kernels hold it: resident [B, C, I_pad] (fetched once)
+    with caps on lanes; one streamed [B, TI, C] i-block with classes on
+    lanes."""
+    if lanes == "classes":
+        return (_i_buf(num_caps, block_i)
+                * tile_padded((batch, block_i, caps_dim)))
+    return tile_padded((batch, caps_dim, _i_padded(num_caps, block_i)))
+
+
+def _logits(batch: int, num_caps: int, block_i: int, j: int,
+            lanes: str) -> int:
+    """The routing logits slab over all of I_pad."""
     i_pad = _i_padded(num_caps, block_i)
-    votes = batch * i_pad * jd
-    logits = batch * i_pad * j
-    tiles = _i_buf(num_caps, block_i) * (batch * block_i * caps_dim
-                                        + block_i * jd * caps_dim)
-    uh_block = batch * block_i * jd
-    out = batch * jd
-    return (votes + logits + tiles + uh_block + out) * ELEM_BYTES
+    if lanes == "classes":
+        return tile_padded((batch, i_pad, j))
+    return tile_padded((batch, j, i_pad))
+
+
+def _votes(batch: int, rows: int, j: int, d: int, lanes: str) -> int:
+    """A votes buffer over ``rows`` capsules: [B, J, D, rows] with caps on
+    lanes, [B, D, rows, J] with classes on lanes."""
+    if lanes == "classes":
+        return tile_padded((batch, d, rows, j))
+    return tile_padded((batch, j, d, rows))
+
+
+def _routing_state(batch: int, num_caps: int, block_i: int, caps_dim: int,
+                   jd: int, j: int, lanes: str = "caps") -> int:
+    """Buffers every fused forward schedule holds (elements, tile-padded):
+    u, the routing logits, the s / v candidates and the output vector,
+    and one votes block in flight per step."""
+    d = jd // j
+    return (_u_buf(batch, num_caps, block_i, caps_dim, lanes)
+            + _logits(batch, num_caps, block_i, j, lanes)
+            + 3 * _caps_vec(batch, j, d, lanes)
+            + _votes(batch, block_i, j, d, lanes))
+
+
+def _w_tile(num_caps: int, block_i: int, caps_dim: int, jd: int,
+            j: int, lanes: str = "caps") -> int:
+    """The streamed W tile ([C, J, D, block_i] with caps on lanes,
+    [C, D, block_i, J] with classes on lanes) and its buffers."""
+    d = jd // j
+    shape = ((caps_dim, d, block_i, j) if lanes == "classes"
+             else (caps_dim, j, d, block_i))
+    return _i_buf(num_caps, block_i) * tile_padded(shape)
+
+
+def _fused_resident_vmem(batch: int, num_caps: int, block_i: int,
+                         caps_dim: int, jd: int, j: int,
+                         lanes: str = "caps") -> int:
+    """Resident schedule: the full votes tensor lives in VMEM scratch next
+    to the routing state while W i-tiles stream past once (every buffer
+    tile-padded: the footprint Mosaic allocates)."""
+    votes = _votes(batch, _i_padded(num_caps, block_i), j, jd // j, lanes)
+    return (votes
+            + _routing_state(batch, num_caps, block_i, caps_dim, jd, j, lanes)
+            + _w_tile(num_caps, block_i, caps_dim, jd, j, lanes)
+            ) * ELEM_BYTES
 
 
 def _fused_streamed_vmem(batch: int, num_caps: int, block_i: int,
-                         caps_dim: int, jd: int, j: int) -> int:
-    """Streamed schedule: only u (fetched once), the logits, and the s/v
-    candidates stay resident; W tiles stream (double-buffered) each pass,
-    and every step recomputes one [B, block_i, J*D] votes block."""
-    i_pad = _i_padded(num_caps, block_i)
-    u_res = batch * i_pad * caps_dim
-    logits = batch * i_pad * j
-    w_tile = _i_buf(num_caps, block_i) * block_i * jd * caps_dim
-    uh_block = batch * block_i * jd
-    sv = 2 * batch * jd
-    out = batch * jd
-    return (u_res + logits + w_tile + uh_block + sv + out) * ELEM_BYTES
+                         caps_dim: int, jd: int, j: int,
+                         lanes: str = "caps") -> int:
+    """Streamed schedule: only the routing state stays resident; W tiles
+    stream (double-buffered) each pass and every step recomputes one
+    votes block."""
+    return (_routing_state(batch, num_caps, block_i, caps_dim, jd, j, lanes)
+            + _w_tile(num_caps, block_i, caps_dim, jd, j, lanes)
+            ) * ELEM_BYTES
+
+
+def _residual_vmem(batch: int, j: int, d: int, lanes: str) -> int:
+    """The [B, J*D] skip operand a coupling half's epilogue holds."""
+    return _caps_vec(batch, j, d, lanes) * ELEM_BYTES
+
+
+def _streamed_floor(batch: int, num_caps: int, caps_dim: int, jd: int,
+                    j: int, residual: bool = False) -> tuple[int, int, str]:
+    """(bytes, block_i, lanes) of the cheapest streamed schedule at the
+    smallest legal i-tile of either layout -- the point below which no
+    schedule can keep the routing state on-chip."""
+    return min((_fused_streamed_vmem(batch, num_caps,
+                                     _min_block_i(num_caps, lanes),
+                                     caps_dim, jd, j, lanes)
+                + (_residual_vmem(batch, j, jd // j, lanes)
+                   if residual else 0),
+                _min_block_i(num_caps, lanes), lanes)
+               for lanes in LAYOUTS)
 
 
 def _fused_max_batch(num_caps: int, caps_dim: int, jd: int, j: int,
-                     vmem_budget: int, extra_per_batch: int = 0) -> int:
-    """Largest batch whose streamed block_i=1 forward footprint fits (the
-    footprint is affine in batch at fixed block_i; ``extra_per_batch``
-    carries a residual-epilogue operand's per-element bytes)."""
-    fixed = _fused_streamed_vmem(0, num_caps, 1, caps_dim, jd, j)
-    per = (_fused_streamed_vmem(1, num_caps, 1, caps_dim, jd, j) - fixed
-           + extra_per_batch)
-    return max((vmem_budget - fixed) // per, 0)
+                     vmem_budget: int, residual: bool = False) -> int:
+    """Largest batch whose cheapest streamed footprint (either layout, at
+    its smallest legal i-tile) fits; ``residual`` counts a coupling
+    half's skip operand."""
+    b = 0
+    while _streamed_floor(b + 1, num_caps, caps_dim, jd, j,
+                          residual)[0] <= vmem_budget:
+        b += 1
+    return b
 
 
 def plan_votes_routing(num_caps: int, caps_dim: int, jd: int, j: int, *,
@@ -487,62 +610,61 @@ def plan_votes_routing(num_caps: int, caps_dim: int, jd: int, j: int, *,
                        vmem_budget: int = VMEM_BYTES,
                        name: str = FUSED_NAME,
                        residual: bool = False) -> VotesRoutingSchedule:
-    """Resident-vs-streamed decision for the fused megakernel.
+    """Layout, resident-vs-streamed and i-tile decision for the fused
+    megakernel.
 
-    Prefer **resident** (votes computed once into scratch, routing
-    iterates on-chip -- the split path's behavior minus the u_hat HBM
-    round-trip); fall back to **streamed** (votes recomputed from
-    re-streamed W tiles each pass) when the votes tensor cannot fit the
-    budget at any i-tile.  The streamed schedule fuses each iteration's
-    s-accumulation with its logits update into ONE W stream (the b-update
-    runs against the previous pass's ``v`` held in scratch), so ``W``
-    moves ``iters + 1`` times per forward -- half the old separate
-    s-pass/b-pass schedule's ``2*iters + 1``.  Raises ``PlanError`` only
-    when even streamed ``block_i=1`` exceeds the budget -- the point
-    where no schedule can keep the routing state on-chip at this batch.
+    Prefer the caps-on-lanes layout, then **resident** (votes computed
+    once into scratch, routing iterates on-chip -- the split path's
+    behavior minus the u_hat HBM round-trip); fall back to **streamed**
+    (votes recomputed from re-streamed W tiles each pass) when the votes
+    tensor cannot fit the budget at any i-tile.  The streamed schedule
+    fuses each iteration's s-accumulation with its logits update into
+    ONE W stream (the b-update runs against the previous pass's ``v``
+    held in scratch), so ``W`` moves ``iters + 1`` times per forward.
+    When no caps-on-lanes schedule fits (a wide layer: one 128-capsule W
+    tile, or the lane-padded class vectors, exceed the budget) the same
+    search runs with the output capsules on the lanes.  Raises
+    ``PlanError`` only when even the cheapest streamed floor exceeds the
+    budget -- the point where no schedule can keep the routing state
+    on-chip at this batch.
 
     ``name`` labels the layer instance in the error (deep stacks plan one
     schedule per routing layer); ``residual`` adds the [B, J*D] residual
     operand a coupling half's epilogue holds alongside the output.
     """
     wl = MatmulWorkload(m=num_caps, k=caps_dim, n=jd, in_bytes=ELEM_BYTES)
-    # Tile-shape pick only (our per-mode footprint model is what is held
-    # to the budget, not the generic double-buffered matmul model),
-    # refined to the i-padding-minimal halving candidate.
-    bi0 = _pad_min_block_i(
-        num_caps, max(min(plan_matmul(wl).block_m, num_caps), 1))
-    extra = batch * jd * ELEM_BYTES if residual else 0
-
-    bi = bi0
-    while bi > 1 and _fused_resident_vmem(batch, num_caps, bi, caps_dim,
-                                          jd, j) + extra > vmem_budget:
-        bi //= 2
-    need = _fused_resident_vmem(batch, num_caps, bi, caps_dim, jd, j) + extra
-    if need <= vmem_budget:
-        return VotesRoutingSchedule(mode="resident", block_i=bi,
-                                    vmem_bytes=need, n_passes=1, workload=wl)
-
-    bi = bi0
-    while bi > 1 and _fused_streamed_vmem(batch, num_caps, bi, caps_dim,
-                                          jd, j) + extra > vmem_budget:
-        bi //= 2
-    need = _fused_streamed_vmem(batch, num_caps, bi, caps_dim, jd, j) + extra
-    if need > vmem_budget:
-        raise PlanError(
-            f"{name}: no feasible schedule at batch={batch}: even "
-            f"streamed block_i=1 needs {need} B of VMEM, over the "
-            f"{vmem_budget} B budget; largest feasible batch is "
-            f"{_fused_max_batch(num_caps, caps_dim, jd, j, vmem_budget, jd * ELEM_BYTES if residual else 0)}")
-    return VotesRoutingSchedule(mode="streamed", block_i=bi, vmem_bytes=need,
-                                n_passes=iters + 1, workload=wl)
+    for lanes in LAYOUTS:
+        extra = (_residual_vmem(batch, j, jd // j, lanes) if residual
+                 else 0)
+        ladder = _i_ladder(lanes, num_caps, caps_dim, jd)
+        for mode, vmem_of, n_passes in (
+                ("resident", _fused_resident_vmem, 1),
+                ("streamed", _fused_streamed_vmem, iters + 1)):
+            for bi in ladder:
+                need = vmem_of(batch, num_caps, bi, caps_dim, jd, j,
+                               lanes) + extra
+                if need <= vmem_budget:
+                    return VotesRoutingSchedule(
+                        mode=mode, block_i=bi, vmem_bytes=need,
+                        n_passes=n_passes, workload=wl, lanes=lanes)
+    need, bi, lanes = _streamed_floor(batch, num_caps, caps_dim, jd, j,
+                                      residual)
+    raise PlanError(
+        f"{name}: no feasible schedule at batch={batch}: even "
+        f"streamed block_i={bi} ({lanes} on lanes) needs {need} B of "
+        f"VMEM, over the {vmem_budget} B budget; largest feasible batch "
+        f"is {_fused_max_batch(num_caps, caps_dim, jd, j, vmem_budget, residual)}")
 
 
 def votes_routing_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
                             jd: int, n_passes: int,
-                            block_i: int | None = None) -> float:
+                            block_i: int | None = None,
+                            lanes: str = "caps") -> float:
     """Modeled HBM traffic of the fused megakernel per forward: u read
-    once, W streamed ``n_passes`` times, v written once -- and NO u_hat
-    term (the tensor never exists off-chip).
+    once (caps on lanes: resident) or alongside every W stream (classes
+    on lanes: u streams by i-block with W), W streamed ``n_passes``
+    times, v written once -- and NO u_hat term (the tensor never exists
+    off-chip).
 
     With ``block_i`` the model counts the i-rows the lowering actually
     moves: the wrapper zero-pads u/W to ``ceil(I/block_i) * block_i``
@@ -553,10 +675,38 @@ def votes_routing_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
     idealization (what a perfectly divisible tile achieves)."""
     i_eff = _i_padded(num_caps, block_i) if block_i else num_caps
     w_sweeps = 1 if block_i is not None and i_eff <= block_i else n_passes
-    u = batch * i_eff * caps_dim
+    u = batch * i_eff * caps_dim * (w_sweeps if lanes == "classes" else 1)
     w = i_eff * jd * caps_dim * w_sweeps
     v = batch * jd
     return float((u + w + v) * ELEM_BYTES)
+
+
+def lane_relayout_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
+                            jd: int, *, lanes: str = "caps",
+                            vectors: int = 1) -> float:
+    """HBM bytes the routing wrappers spend laying the operands out for
+    the kernel (``kernels.votes_routing`` ``to_kernel`` / ``to_vec``), one
+    read and one write per transposed array (the i-axis zero-padding
+    fuses into the same pass).  Caps on lanes transposes ``u [B, I, C]``
+    and ``W [I, J*D, C]``; ``batch=0`` counts W alone (the pipelined
+    pair never holds u in HBM).  Classes on lanes transposes W and each
+    of the ``vectors`` [B, J*D] class vectors crossing the kernel
+    boundary (output, residual) -- u keeps its layout."""
+    if lanes == "classes":
+        return float(2 * (jd * num_caps * caps_dim + vectors * batch * jd)
+                     * ELEM_BYTES)
+    return float(2 * (batch + jd) * num_caps * caps_dim * ELEM_BYTES)
+
+
+def lane_relayout_bwd_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
+                                jd: int, *, lanes: str = "caps") -> float:
+    """The backward's relayouts: the inputs in and du / dW back out --
+    with caps on lanes the forward's bytes twice; with classes on lanes
+    W and dW, the cotangent vector in and du out."""
+    if lanes == "classes":
+        return float(2 * (2 * jd * num_caps * caps_dim + batch * jd
+                          + batch * num_caps * caps_dim) * ELEM_BYTES)
+    return 2 * lane_relayout_hbm_bytes(batch, num_caps, caps_dim, jd)
 
 
 def split_votes_routing_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
@@ -598,47 +748,38 @@ class PrimaryRoutingSchedule:
     block: BlockPlan         # producer tiling (VJP replay matmuls)
 
 
-def _pipe_produce_vmem(batch: int, p_pos: int, n_ch: int, block_k: int,
-                       i_pad: int, caps_dim: int) -> int:
+def _pipe_produce_vmem(batch: int, p_pos: int, n_ch: int,
+                       block_k: int) -> int:
     """Produce-phase residency shared by both pipelined schedules: the
-    full producer output scratch (pre-activation, squashed in place) plus
-    double-buffered patch / conv-weight K tiles and the bias row."""
-    u_scr = batch * i_pad * caps_dim
-    tiles = 2 * (batch * p_pos * block_k + block_k * n_ch)
-    return u_scr + tiles + n_ch
+    transposed pre-activation scratch [B, N, P], double-buffered patch
+    [B, P, block_k] / conv-weight [block_k, N] K tiles and the bias
+    column (u's [B, C, I_pad] scratch is part of the routing state)."""
+    return (tile_padded((batch, n_ch, p_pos))
+            + 2 * (tile_padded((batch, p_pos, block_k))
+                   + tile_padded((block_k, n_ch)))
+            + tile_padded((n_ch, 1)))
 
 
 def _pipe_resident_vmem(batch: int, p_pos: int, n_ch: int, block_k: int,
                         num_caps: int, block_i: int, caps_dim: int,
                         jd: int, j: int) -> int:
     """Resident consumer on top of the produce-phase residency: the full
-    votes tensor + routing logits in scratch, double-buffered W i-tiles,
-    one [B, block_i, J*D] votes block per step."""
+    votes tensor in scratch, W_cc i-tiles, the routing state."""
     i_pad = _i_padded(num_caps, block_i)
-    votes = batch * i_pad * jd
-    logits = batch * i_pad * j
-    w_tile = _i_buf(num_caps, block_i) * block_i * jd * caps_dim
-    uh_block = batch * block_i * jd
-    out = batch * jd
-    return (_pipe_produce_vmem(batch, p_pos, n_ch, block_k, i_pad, caps_dim)
-            + votes + logits + w_tile + uh_block + out) * ELEM_BYTES
+    return (_pipe_produce_vmem(batch, p_pos, n_ch, block_k)
+            + tile_padded((batch, j, jd // j, i_pad))
+            + _routing_state(batch, num_caps, block_i, caps_dim, jd, j)
+            + _w_tile(num_caps, block_i, caps_dim, jd, j)) * ELEM_BYTES
 
 
 def _pipe_streamed_vmem(batch: int, p_pos: int, n_ch: int, block_k: int,
                         num_caps: int, block_i: int, caps_dim: int,
                         jd: int, j: int) -> int:
-    """Streamed consumer on top of the produce-phase residency: logits +
-    s/v candidates resident, W tiles re-streamed each pass, one votes
-    block recomputed per step (u is the produce scratch itself -- the
-    streamed megakernel's constant-index u fetch becomes free)."""
-    i_pad = _i_padded(num_caps, block_i)
-    logits = batch * i_pad * j
-    w_tile = _i_buf(num_caps, block_i) * block_i * jd * caps_dim
-    uh_block = batch * block_i * jd
-    sv = 2 * batch * jd
-    out = batch * jd
-    return (_pipe_produce_vmem(batch, p_pos, n_ch, block_k, i_pad, caps_dim)
-            + logits + w_tile + uh_block + sv + out) * ELEM_BYTES
+    """Streamed consumer on top of the produce-phase residency: W_cc
+    tiles re-streamed each pass, one votes block recomputed per step."""
+    return (_pipe_produce_vmem(batch, p_pos, n_ch, block_k)
+            + _routing_state(batch, num_caps, block_i, caps_dim, jd, j)
+            + _w_tile(num_caps, block_i, caps_dim, jd, j)) * ELEM_BYTES
 
 
 def plan_primary_routing(p_pos: int, k_in: int, n_ch: int, num_caps: int,
@@ -651,9 +792,9 @@ def plan_primary_routing(p_pos: int, k_in: int, n_ch: int, num_caps: int,
     Prefer the resident consumer (votes computed once into scratch);
     fall back to streamed (votes recomputed from re-streamed W, the
     fused s+b pass -- ``iters + 1`` W streams).  Both shrink the votes
-    i-tile first, then halve the produce K tile, before giving up.
-    Raises ``PlanError`` when even streamed ``block_i=1, block_k=1``
-    exceeds the budget -- ``compile_plan`` then falls back to the
+    i-tile first, then the produce K tile (lane-legal tiles only), before
+    giving up.  Raises ``PlanError`` when even the smallest streamed
+    tiles exceed the budget -- ``compile_plan`` then falls back to the
     per-op pair (which may itself still fit: its phases never coexist).
     """
     wl = MatmulWorkload(m=batch * p_pos, k=k_in, n=n_ch,
@@ -663,47 +804,38 @@ def plan_primary_routing(p_pos: int, k_in: int, n_ch: int, num_caps: int,
     except ValueError as err:
         raise PlanError(f"{PIPE_NAME}: no feasible producer tiling at "
                         f"batch={batch}: {err}")
-    bk0 = max(min(blk.block_k, k_in), 1)
+    k_ladder = _shrink_ladder(max(min(blk.block_k, k_in), 1))
     vr_wl = MatmulWorkload(m=num_caps, k=caps_dim, n=jd,
                            in_bytes=ELEM_BYTES)
-    bi0 = _pad_min_block_i(
-        num_caps, max(min(plan_matmul(vr_wl).block_m, num_caps), 1))
+    i_ladder = _shrink_ladder(_lane_block_i(
+        num_caps, max(min(plan_matmul(vr_wl).block_m, num_caps), 1)))
 
     def _fit(vmem_of):
-        bk = bk0
-        while True:
-            bi = bi0
-            while bi > 1 and vmem_of(bi, bk) > vmem_budget:
-                bi //= 2
-            need = vmem_of(bi, bk)
-            if need <= vmem_budget:
-                return bi, bk, need
-            if bk == 1:
-                return None
-            bk = max(bk // 2, 1)
+        for bk in k_ladder:
+            for bi in i_ladder:
+                need = vmem_of(bi, bk)
+                if need <= vmem_budget:
+                    return bi, bk, need
+        return None
 
-    fit = _fit(lambda bi, bk: _pipe_resident_vmem(
-        batch, p_pos, n_ch, bk, num_caps, bi, caps_dim, jd, j))
-    if fit is not None:
-        bi, bk, need = fit
-        return PrimaryRoutingSchedule(
-            mode="resident", block_i=bi, block_k=bk,
-            k_steps=math.ceil(k_in / bk), vmem_bytes=need, n_passes=1,
-            workload=wl, block=blk)
-    fit = _fit(lambda bi, bk: _pipe_streamed_vmem(
-        batch, p_pos, n_ch, bk, num_caps, bi, caps_dim, jd, j))
-    if fit is None:
-        need = _pipe_streamed_vmem(batch, p_pos, n_ch, 1, num_caps, 1,
-                                   caps_dim, jd, j)
-        raise PlanError(
-            f"{PIPE_NAME}: no feasible pipelined schedule at batch={batch}: "
-            f"even streamed block_i=1, block_k=1 needs {need} B of VMEM, "
-            f"over the {vmem_budget} B budget")
-    bi, bk, need = fit
-    return PrimaryRoutingSchedule(
-        mode="streamed", block_i=bi, block_k=bk,
-        k_steps=math.ceil(k_in / bk), vmem_bytes=need, n_passes=iters + 1,
-        workload=wl, block=blk)
+    for mode, vmem_of, n_passes in (
+            ("resident", _pipe_resident_vmem, 1),
+            ("streamed", _pipe_streamed_vmem, iters + 1)):
+        fit = _fit(lambda bi, bk: vmem_of(batch, p_pos, n_ch, bk, num_caps,
+                                          bi, caps_dim, jd, j))
+        if fit is not None:
+            bi, bk, need = fit
+            return PrimaryRoutingSchedule(
+                mode=mode, block_i=bi, block_k=bk,
+                k_steps=math.ceil(k_in / bk), vmem_bytes=need,
+                n_passes=n_passes, workload=wl, block=blk)
+    bi, bk = i_ladder[-1], k_ladder[-1]
+    need = _pipe_streamed_vmem(batch, p_pos, n_ch, bk, num_caps, bi,
+                               caps_dim, jd, j)
+    raise PlanError(
+        f"{PIPE_NAME}: no feasible pipelined schedule at batch={batch}: "
+        f"even streamed block_i={bi}, block_k={bk} needs {need} B of VMEM, "
+        f"over the {vmem_budget} B budget")
 
 
 def primary_routing_hbm_bytes(batch: int, p_pos: int, k_in: int, n_ch: int,
@@ -769,131 +901,140 @@ def _pipe_requirement(in_caps: int, j: int, jd: int,
 # Fused votes+routing BACKWARD schedule (the custom-VJP kernels' DSE)
 # ---------------------------------------------------------------------------
 
+def _bwd_state(batch: int, num_caps: int, block_i: int, caps_dim: int,
+               jd: int, j: int, lanes: str = "caps") -> int:
+    """Buffers both backward schedules hold (elements, tile-padded): u,
+    the W tile, the output cotangent, one du / dW block each, a ROLLING
+    PAIR of logits slabs (only ``b_{T-1}``/``b_T`` are ever consumed
+    again under the stop-gradient convention; ``db_T`` is rebuilt per
+    block, never held), the s pair (overwritten by the ds pair),
+    accumulator and v vectors, and one votes block in flight.
+    Independent of ``iters``: the replay reuses the two slots."""
+    d = jd // j
+    if lanes == "classes":
+        du_blk = tile_padded((caps_dim, batch, block_i, 1))
+        dw_blk = tile_padded((caps_dim, d, block_i, j))
+    else:
+        du_blk = tile_padded((batch, caps_dim, block_i))
+        dw_blk = tile_padded((caps_dim, j, d, block_i))
+    return (_u_buf(batch, num_caps, block_i, caps_dim, lanes)
+            + _w_tile(num_caps, block_i, caps_dim, jd, j, lanes)
+            + _caps_vec(batch, j, d, lanes)                    # cotangent
+            + du_blk + dw_blk
+            + 2 * _logits(batch, num_caps, block_i, j, lanes)  # b pair
+            + 4 * _caps_vec(batch, j, d, lanes)                # s/acc/v
+            + _votes(batch, block_i, j, d, lanes))             # votes block
+
+
 def _fused_resident_bwd_vmem(batch: int, num_caps: int, block_i: int,
                              caps_dim: int, jd: int, j: int,
-                             iters: int) -> int:
-    """Resident backward: the rebuilt votes scratch (overwritten by
-    ``d u_hat`` in place) plus the routing replay's vjp residuals -- the
-    logits trajectory and couplings per iteration -- with double-buffered
-    u/W tiles streaming past twice and one du/dW block emitted per step."""
-    i_pad = _i_padded(num_caps, block_i)
-    votes = batch * i_pad * jd                     # u_hat -> d u_hat in place
-    traj = 2 * (iters + 1) * batch * i_pad * j     # replay: b trajectory + c
-    tiles = _i_buf(num_caps, block_i) * (batch * block_i * caps_dim
-                                        + block_i * jd * caps_dim)
-    uh_block = batch * block_i * jd
-    grads = batch * block_i * caps_dim + block_i * jd * caps_dim
-    sv = 4 * batch * jd                            # s/v/ds/dv temporaries
-    cot = batch * jd                               # output cotangent
-    return (votes + traj + tiles + uh_block + grads + sv + cot) * ELEM_BYTES
+                             iters: int, lanes: str = "caps") -> int:
+    """Resident backward: the rebuilt votes scratch on top of the shared
+    backward state (``W`` streams twice: rebuild + emit)."""
+    del iters
+    votes = _votes(batch, _i_padded(num_caps, block_i), j, jd // j, lanes)
+    return (votes + _bwd_state(batch, num_caps, block_i, caps_dim, jd, j,
+                               lanes)) * ELEM_BYTES
 
 
 def _fused_streamed_bwd_vmem(batch: int, num_caps: int, block_i: int,
                              caps_dim: int, jd: int, j: int,
-                             iters: int) -> int:
-    """Streamed backward: u, a ROLLING PAIR of logits slabs (only
-    ``b_{T-1}``/``b_T`` are ever consumed again under the stop-gradient
-    convention), ``db_T``, and the small s/ds pairs stay resident; W
-    tiles stream (double-buffered) on every pass and each step recomputes
-    one votes block -- ``d u_hat`` exists only one i-block at a time.
-    Independent of ``iters``: the replay reuses the two slots."""
+                             iters: int, lanes: str = "caps") -> int:
+    """Streamed backward: W tiles stream on every pass and each step
+    recomputes one votes block -- ``d u_hat`` exists only one i-block at
+    a time."""
     del iters
-    i_pad = _i_padded(num_caps, block_i)
-    u_res = batch * i_pad * caps_dim
-    b_pair = 2 * batch * i_pad * j
-    db = batch * i_pad * j
-    w_tile = _i_buf(num_caps, block_i) * block_i * jd * caps_dim
-    uh_block = batch * block_i * jd
-    s_ds = 4 * batch * jd                          # s pair + ds pair
-    accv = 2 * batch * jd                          # accumulator + v
-    grads = batch * block_i * caps_dim + block_i * jd * caps_dim
-    cot = batch * jd
-    return (u_res + b_pair + db + w_tile + uh_block + s_ds + accv + grads
-            + cot) * ELEM_BYTES
+    return _bwd_state(batch, num_caps, block_i, caps_dim, jd, j,
+                      lanes) * ELEM_BYTES
+
+
+def _streamed_bwd_floor(batch: int, num_caps: int, caps_dim: int, jd: int,
+                        j: int, iters: int) -> tuple[int, int, str]:
+    """(bytes, block_i, lanes) of the cheapest streamed backward at the
+    smallest legal i-tile of either layout."""
+    return min((_fused_streamed_bwd_vmem(batch, num_caps,
+                                         _min_block_i(num_caps, lanes),
+                                         caps_dim, jd, j, iters, lanes),
+                _min_block_i(num_caps, lanes), lanes)
+               for lanes in LAYOUTS)
 
 
 def _fused_bwd_max_batch(num_caps: int, caps_dim: int, jd: int, j: int,
                          iters: int, vmem_budget: int) -> int:
-    """Largest batch whose streamed-backward block_i=1 footprint fits
-    (the footprint is affine in batch at fixed block_i)."""
-    fixed = _fused_streamed_bwd_vmem(0, num_caps, 1, caps_dim, jd, j, iters)
-    per = (_fused_streamed_bwd_vmem(1, num_caps, 1, caps_dim, jd, j, iters)
-           - fixed)
-    return max((vmem_budget - fixed) // per, 0)
+    """Largest batch whose cheapest streamed-backward footprint fits."""
+    b = 0
+    while _streamed_bwd_floor(b + 1, num_caps, caps_dim, jd, j,
+                              iters)[0] <= vmem_budget:
+        b += 1
+    return b
 
 
 def plan_votes_routing_bwd(num_caps: int, caps_dim: int, jd: int, j: int, *,
                            batch: int = 1, iters: int = 3,
                            vmem_budget: int = VMEM_BYTES,
                            name: str = FUSED_NAME) -> VotesRoutingSchedule:
-    """Resident-vs-streamed decision for the fused megakernel's BACKWARD.
+    """Layout, resident-vs-streamed and i-tile decision for the fused
+    megakernel's BACKWARD, in the forward's preference order.
 
     Chosen independently of the forward: the backward's scratch is larger
-    (the logits trajectory rides along, and resident additionally holds
-    the in-place ``d u_hat``), so a budget can plan the forward resident
+    (the logits pair rides along, and resident additionally
+    holds the rebuilt votes), so a budget can plan the forward resident
     -- or plan the forward at all -- and still be unable to run the
     backward.  That boundary raises a ``PlanError`` naming the backward
     op and the largest feasible batch, instead of failing opaquely in
     ``validate()``.
 
     ``n_passes`` counts W streams: 2 resident (votes rebuild + du/dW
-    emit), ``iters + 4`` streamed (fused forward replay ``T+1`` -- one W
-    stream per replayed iteration, the logits update folded into the
-    s-pass like the forward kernel -- then db seed, ONE dv/ds reverse
-    pass, emit; the stop-gradient convention means ``d u_hat`` only ever
-    needs ``ds_T`` and ``ds_{T-1}``, so there is no deep reverse
-    recurrence to stream W for).
+    emit), ``iters + 4`` streamed (forward replay ``T+1`` -- one W stream
+    per replayed iteration -- then the ds seed, ONE dv/ds reverse pass,
+    emit; the stop-gradient convention means ``d u_hat`` only ever needs
+    ``ds_T`` and ``ds_{T-1}``, so there is no deep reverse recurrence to
+    stream W for).
     """
     wl = MatmulWorkload(m=num_caps, k=caps_dim, n=jd, in_bytes=ELEM_BYTES)
-    bi0 = _pad_min_block_i(
-        num_caps, max(min(plan_matmul(wl).block_m, num_caps), 1))
-
-    bi = bi0
-    while bi > 1 and _fused_resident_bwd_vmem(batch, num_caps, bi, caps_dim,
-                                              jd, j, iters) > vmem_budget:
-        bi //= 2
-    need = _fused_resident_bwd_vmem(batch, num_caps, bi, caps_dim, jd, j,
-                                    iters)
-    if need <= vmem_budget:
-        return VotesRoutingSchedule(mode="resident", block_i=bi,
-                                    vmem_bytes=need, n_passes=2, workload=wl)
-
-    bi = bi0
-    while bi > 1 and _fused_streamed_bwd_vmem(batch, num_caps, bi, caps_dim,
-                                              jd, j, iters) > vmem_budget:
-        bi //= 2
-    need = _fused_streamed_bwd_vmem(batch, num_caps, bi, caps_dim, jd, j,
-                                    iters)
-    if need > vmem_budget:
-        raise PlanError(
-            f"{name}{BWD_SUFFIX}: no feasible backward schedule at "
-            f"batch={batch}: even streamed block_i=1 needs {need} B of "
-            f"VMEM, over the {vmem_budget} B budget; largest feasible "
-            f"batch is "
-            f"{_fused_bwd_max_batch(num_caps, caps_dim, jd, j, iters, vmem_budget)}")
-    return VotesRoutingSchedule(mode="streamed", block_i=bi, vmem_bytes=need,
-                                n_passes=iters + 4, workload=wl)
+    for lanes in LAYOUTS:
+        ladder = _i_ladder(lanes, num_caps, caps_dim, jd)
+        for mode, vmem_of, n_passes in (
+                ("resident", _fused_resident_bwd_vmem, 2),
+                ("streamed", _fused_streamed_bwd_vmem, iters + 4)):
+            for bi in ladder:
+                need = vmem_of(batch, num_caps, bi, caps_dim, jd, j, iters,
+                               lanes)
+                if need <= vmem_budget:
+                    return VotesRoutingSchedule(
+                        mode=mode, block_i=bi, vmem_bytes=need,
+                        n_passes=n_passes, workload=wl, lanes=lanes)
+    need, bi, lanes = _streamed_bwd_floor(batch, num_caps, caps_dim, jd, j,
+                                          iters)
+    raise PlanError(
+        f"{name}{BWD_SUFFIX}: no feasible backward schedule at "
+        f"batch={batch}: even streamed block_i={bi} ({lanes} on lanes) "
+        f"needs {need} B of VMEM, over the {vmem_budget} B budget; "
+        f"largest feasible batch is "
+        f"{_fused_bwd_max_batch(num_caps, caps_dim, jd, j, iters, vmem_budget)}")
 
 
 def votes_routing_bwd_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
                                 jd: int, *, mode: str, iters: int,
-                                block_i: int | None = None) -> float:
+                                block_i: int | None = None,
+                                lanes: str = "caps") -> float:
     """Modeled HBM traffic of the fused backward per step: W streamed once
-    per pass, u read per pass (resident) or once (streamed: constant index
-    map), the output cotangent read once, du/dW written once -- and NO
-    ``u_hat`` or ``d u_hat`` term (neither ever exists off-chip).
+    per pass (2 resident: rebuild + emit; ``iters + 4`` streamed), u read
+    once (constant index map), the output cotangent read once, du/dW
+    written once -- and NO ``u_hat`` or ``d u_hat`` term (neither ever
+    exists off-chip).
 
     ``block_i`` makes the i-terms padding-aware (u/W/du/dW are all padded
     to the i-tile grid by the wrapper; the kernel emits padded du/dW that
-    the wrapper slices) -- and when one block covers the i-axis, u/W are
+    the wrapper slices) -- and when one block covers the i-axis, W is
     fetched once however many passes the grid makes (the block index
-    never changes, so Pallas keeps them in VMEM).  ``None`` is the
+    never changes, so Pallas keeps it in VMEM).  ``None`` is the
     unpadded idealization."""
     i_eff = _i_padded(num_caps, block_i) if block_i else num_caps
     single = block_i is not None and i_eff <= block_i
     w_passes = (2 if mode == "resident" else iters + 4) if not single else 1
-    u_passes = (2 if mode == "resident" else 1) if not single else 1
-    u = batch * i_eff * caps_dim * u_passes
+    # u rides every W stream when it streams by i-block (classes on lanes)
+    u = batch * i_eff * caps_dim * (w_passes if lanes == "classes" else 1)
     w = i_eff * jd * caps_dim * w_passes
     cot = batch * jd
     du = batch * i_eff * caps_dim
@@ -916,34 +1057,6 @@ def spilled_votes_routing_bwd_hbm_bytes(batch: int, num_caps: int,
     dw = num_caps * jd * caps_dim
     return (float((uhat + u + w + cot + du + dw) * ELEM_BYTES),
             float(uhat * ELEM_BYTES))
-
-
-def _conv_patch_vmem(in_hw: int, cin: int, k: int, out_hw: int, *,
-                     batch: int = 1, block_p: int | None = None) -> int:
-    """im2col patch-extraction footprint per grid step: the resident
-    input feature map (double-buffered when the grid walks more than one
-    batch element -- its block index changes, so the pipeline prefetches)
-    plus the emitted patch rows (``block_p`` of them when the extraction
-    is row-blocked, the whole matrix when ``block_p`` is None)."""
-    image = in_hw * in_hw * cin * ELEM_BYTES * (2 if batch > 1 else 1)
-    rows = out_hw * out_hw if block_p is None else block_p
-    return image + rows * k * k * cin * ELEM_BYTES
-
-
-def _conv_patch_bwd_vmem(in_hw: int, cin: int, k: int, out_hw: int, *,
-                         batch: int = 1,
-                         block_p: int | None = None) -> int:
-    """col2im scatter footprint (the conv backward's dx stage): the
-    resident dx image accumulator plus the dpatches cotangent stream,
-    double-buffered whenever its block index varies over the grid --
-    across the row blocks when the scatter is blocked, across batch
-    elements when it is not."""
-    image = in_hw * in_hw * cin * ELEM_BYTES
-    p_pos = out_hw * out_hw
-    rows = p_pos if block_p is None else block_p
-    streams = 2 if (batch > 1 or (block_p is not None
-                                  and block_p < p_pos)) else 1
-    return image + streams * rows * k * k * cin * ELEM_BYTES
 
 
 def conv_extract_hbm_bytes(in_hw: int, cin: int, k: int, out_hw: int, *,
@@ -981,55 +1094,18 @@ def _conv_bwd_matmul_vmem(block, m: int, kcol: int, n: int) -> int:
     bk = max(1, min(block.block_k, kcol))
     bn = max(1, min(block.block_n, n))
     m_steps = steps(m, bm)
-    at_b = (dbuf(m_steps * steps(kcol, bk)) * bm * bk
-            + dbuf(m_steps * steps(n, bn)) * bm * bn
-            + bk * bn) * ELEM_BYTES
+    at_b = (dbuf(m_steps * steps(kcol, bk)) * tile_padded((bm, bk))
+            + dbuf(m_steps * steps(n, bn)) * tile_padded((bm, bn))
+            + tile_padded((bk, bn))) * ELEM_BYTES
     bm2 = max(1, min(block.block_m, m))
     bk2 = max(1, min(block.block_n, n))
     bn2 = max(1, min(block.block_k, kcol))
     m2, k2, n2 = steps(m, bm2), steps(n, bk2), steps(kcol, bn2)
-    dpatches = (dbuf(m2 * k2) * bm2 * bk2 + dbuf(k2 * n2) * bk2 * bn2
-                + dbuf(n2) * bn2 + bm2 * bn2) * ELEM_BYTES
+    dpatches = (dbuf(m2 * k2) * tile_padded((bm2, bk2))
+                + dbuf(k2 * n2) * tile_padded((bk2, bn2))
+                + dbuf(n2) * tile_padded((1, bn2))
+                + tile_padded((bm2, bn2))) * ELEM_BYTES
     return max(at_b, dpatches)
-
-
-def _plan_patch_rows(in_hw: int, cin: int, k: int, out_hw: int, *,
-                     batch: int, budget: int,
-                     train: bool = False) -> int | None:
-    """Pick the im2col extraction row block under ``budget``.
-
-    ``None`` (emit the whole patch matrix per batch element) whenever it
-    fits -- fewest grid steps, and the schedule every contract was
-    calibrated against.  Otherwise the largest ``block_p`` that tiles
-    the output grid (whole output rows, then within-row windows -- the
-    shapes ``kernels.conv_im2col.im2col_patches`` accepts) and fits; the
-    static auditor found the unblocked extraction claiming budgets it
-    could not honor (MNIST PrimaryCaps: 3.4 MB patch matrix under a
-    600 kB plan).  A train plan also pays the col2im scatter
-    (``_conv_patch_bwd_vmem`` -- its dpatches stream double-buffers, so
-    it binds tighter) with the same ``block_p``.  Falls to
-    ``block_p=1`` when nothing fits -- ``validate()`` then rejects the
-    plan, which is the honest answer."""
-    p_pos = out_hw * out_hw
-
-    def fits(bp):
-        need = _conv_patch_vmem(in_hw, cin, k, out_hw, batch=batch,
-                                block_p=bp)
-        if train:
-            need = max(need, _conv_patch_bwd_vmem(in_hw, cin, k, out_hw,
-                                                  batch=batch, block_p=bp))
-        return need <= budget
-
-    if fits(None):
-        return None
-    rows = [d * out_hw for d in range(out_hw, 0, -1) if out_hw % d == 0]
-    cols = [d for d in range(out_hw, 0, -1) if out_hw % d == 0]
-    for bp in sorted(set(rows + cols), reverse=True):
-        if bp >= p_pos:
-            continue
-        if fits(bp):
-            return bp
-    return 1
 
 
 def _fused_requirement(in_caps: int, j: int, jd: int,
@@ -1178,16 +1254,6 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
         "Conv1": (dims.in_hw, dims.conv1_cin, dims.conv1_k, dims.conv1_out),
         "PrimaryCaps": (dims.conv1_out, dims.pc_cin, dims.pc_k, dims.pc_out),
     }
-    conv_patch_rows = {
-        name: _plan_patch_rows(*geom, batch=batch, budget=vmem_budget,
-                               train=train)
-        for name, geom in conv_geom.items()
-    }
-    conv_patch = {
-        name: _conv_patch_vmem(*geom, batch=batch,
-                               block_p=conv_patch_rows[name])
-        for name, geom in conv_geom.items()
-    }
     squash_rows = batch * dims.num_primary
     block_rows = max(min(SQUASH_BLOCK_ROWS, squash_rows), 1)
     for name, wl in conv_wls.items():
@@ -1203,16 +1269,14 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                    > vmem_budget and eff > 1):
                 eff = eff * 3 // 4
                 block = plan_matmul(wl, eff)
-        bias_tile = 2 * block.block_n * ELEM_BYTES
+        bias_tile = 2 * tile_padded((1, block.block_n)) * ELEM_BYTES
         op = OpPlan(name=name, kernel="conv_im2col", workload=wl, block=block,
-                    vmem_bytes=max(block.vmem_total + bias_tile,
-                                   conv_patch[name]),
+                    vmem_bytes=block.vmem_total + bias_tile,
                     est_cycles=block.est_cycles,
                     requirement=_requirement(prof), profiles=(prof,),
                     hbm_bytes=(block.hbm_bytes
                                + conv_extract_hbm_bytes(*conv_geom[name],
-                                                        batch=batch)),
-                    patch_rows=conv_patch_rows[name])
+                                                        batch=batch)))
         if name == "PrimaryCaps":
             # The primary-capsule squash activation rides on this op: fused
             # into the matmul epilogue when every n-tile holds whole
@@ -1252,9 +1316,13 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                                    name=lay.name, residual=lay.residual)
         votes_cycles = sched.workload.flops / (2 * MXU * MXU)
         routing_cycles = sum(p.total_cycles for p in lay_profs[1:])
-        hbm = votes_routing_hbm_bytes(batch, lay.in_caps, lay.in_dim,
-                                      lay.jd, sched.n_passes,
-                                      block_i=sched.block_i)
+        hbm = (votes_routing_hbm_bytes(batch, lay.in_caps, lay.in_dim,
+                                       lay.jd, sched.n_passes,
+                                       block_i=sched.block_i,
+                                       lanes=sched.lanes)
+               + lane_relayout_hbm_bytes(batch, lay.in_caps, lay.in_dim,
+                                         lay.jd, lanes=sched.lanes,
+                                         vectors=1 + lay.residual))
         if lay.residual:
             hbm += batch * lay.jd * ELEM_BYTES     # skip operand read
         # An intermediate layer's output round-trips HBM to the next
@@ -1265,7 +1333,7 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
         ops.append(OpPlan(
             name=lay.name, kernel="votes_routing", workload=sched.workload,
             block=None, block_i=sched.block_i, mode=sched.mode,
-            n_passes=sched.n_passes,
+            lanes=sched.lanes, n_passes=sched.n_passes,
             vmem_bytes=sched.vmem_bytes,
             est_cycles=votes_cycles * sched.n_passes + routing_cycles,
             hbm_bytes=hbm,
@@ -1298,16 +1366,6 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                 dims.pc_cout, first.in_caps, first.in_dim, first.jd,
                 first.num_caps, batch=batch, iters=first.iters,
                 vmem_budget=vmem_budget)
-            # The pipelined pair still runs the im2col patch extraction
-            # as its own call; its (row-blocked) footprint caps the
-            # pair's real peak.  A schedule that fits the budget while
-            # that call does not is a claim the lowering cannot honor
-            # (the static auditor measured the patch call as the peak on
-            # degraded budgets), so the pair's footprint is the max of
-            # the two, and when even a one-row extraction block is over
-            # budget the pair falls back to the per-op path.
-            if conv_patch["PrimaryCaps"] > vmem_budget:
-                pipe_sched = None
         except PlanError:
             pipe_sched = None            # per-op pair is the fallback
     if pipe_sched is not None:
@@ -1317,10 +1375,8 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
             name=PIPE_NAME, kernel="primary_routing",
             workload=pipe_sched.workload, block=pipe_sched.block,
             block_i=pipe_sched.block_i, block_k=pipe_sched.block_k,
-            mode=pipe_sched.mode, n_passes=pipe_sched.n_passes,
-            patch_rows=conv_patch_rows["PrimaryCaps"],
-            vmem_bytes=max(pipe_sched.vmem_bytes,
-                           conv_patch["PrimaryCaps"]),
+            mode=pipe_sched.mode, lanes="caps", n_passes=pipe_sched.n_passes,
+            vmem_bytes=pipe_sched.vmem_bytes,
             est_cycles=(prod_cycles + first_votes * pipe_sched.n_passes
                         + first_routing),
             hbm_bytes=(primary_routing_hbm_bytes(
@@ -1329,10 +1385,13 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                 pipe_sched.n_passes, block_i=pipe_sched.block_i,
                 block_k=pipe_sched.block_k)
                 # ...plus the im2col extraction feeding the produce
-                # phase (image read + patch store), which the routing
-                # model deliberately excludes.
+                # phase (image read + patch store) and the wrapper's
+                # W_cc relayout onto lanes, which the routing model
+                # deliberately excludes.
                 + conv_extract_hbm_bytes(*conv_geom["PrimaryCaps"],
-                                         batch=batch)),
+                                         batch=batch)
+                + lane_relayout_hbm_bytes(0, first.in_caps, first.in_dim,
+                                          first.jd)),
             uhat_hbm_bytes=0.0,
             intermediate_hbm_bytes=(
                 0.0 if len(stack) == 1 else
@@ -1358,10 +1417,13 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
             bwd_profs = tuple(_backward_profile(p)
                               for p in reversed(lay_profs))
             est = votes_cycles * bwd_sched.n_passes + 2 * routing_cycles
-            hbm = votes_routing_bwd_hbm_bytes(
+            hbm = (votes_routing_bwd_hbm_bytes(
                 batch, lay.in_caps, lay.in_dim, lay.jd,
                 mode=bwd_sched.mode, iters=lay.iters,
-                block_i=bwd_sched.block_i)
+                block_i=bwd_sched.block_i, lanes=bwd_sched.lanes)
+                + lane_relayout_bwd_hbm_bytes(batch, lay.in_caps,
+                                              lay.in_dim, lay.jd,
+                                              lanes=bwd_sched.lanes))
             vmem = bwd_sched.vmem_bytes
             if lay.residual:
                 # Reversible inversion (MoCapsNet-style): the backward
@@ -1370,15 +1432,19 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                 # then runs the ordinary fused VJP -- the recompute cost
                 # of never saving the stack's activations.
                 est += votes_cycles * fwd_sched.n_passes + routing_cycles
-                hbm += votes_routing_hbm_bytes(
+                hbm += (votes_routing_hbm_bytes(
                     batch, lay.in_caps, lay.in_dim, lay.jd,
-                    fwd_sched.n_passes, block_i=fwd_sched.block_i)
+                    fwd_sched.n_passes, block_i=fwd_sched.block_i,
+                    lanes=fwd_sched.lanes)
+                    + lane_relayout_hbm_bytes(batch, lay.in_caps,
+                                              lay.in_dim, lay.jd,
+                                              lanes=fwd_sched.lanes))
                 vmem = max(vmem, fwd_sched.vmem_bytes)
             ops.append(OpPlan(
                 name=lay.name + BWD_SUFFIX, kernel="votes_routing_bwd",
                 workload=bwd_sched.workload, block=None,
                 block_i=bwd_sched.block_i, mode=bwd_sched.mode,
-                n_passes=bwd_sched.n_passes,
+                lanes=bwd_sched.lanes, n_passes=bwd_sched.n_passes,
                 vmem_bytes=vmem,
                 est_cycles=est,
                 hbm_bytes=hbm,
@@ -1396,24 +1462,21 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                             or (pipe_sched is not None
                                 and fwd is pc_op)) else 2
             patches = wl.m * wl.k * ELEM_BYTES       # dpatches write + read
+            # The weight transpose feeding the dpatches matmul.
+            relayout = 2 * wl.k * wl.n * ELEM_BYTES
             prof = _backward_profile(fwd.profile)
             ops.append(OpPlan(
                 name=fwd.name + BWD_SUFFIX, kernel="conv_im2col_bwd",
                 workload=wl, block=fwd.block, block_rows=fwd.block_rows,
-                patch_rows=fwd.patch_rows,
-                # The backward's peak adds the col2im scatter (dx image
-                # resident, the dpatches stream double-buffered) and the
-                # at_b/dpatches matmuls, whose two bm-tall streams can
-                # exceed the forward tiles' peak (both measured by the
-                # static auditor).
+                # The at_b/dpatches matmuls' two bm-tall streams can
+                # exceed the forward tiles' peak (measured by the static
+                # auditor).
                 vmem_bytes=max(fwd.vmem_bytes,
-                               _conv_patch_bwd_vmem(
-                                   *conv_geom[fwd.name], batch=batch,
-                                   block_p=fwd.patch_rows),
                                _conv_bwd_matmul_vmem(fwd.block, wl.m,
                                                      wl.k, wl.n)),
                 est_cycles=matmuls * fwd.est_cycles,
-                hbm_bytes=matmuls * fwd.block.hbm_bytes + 2 * patches,
+                hbm_bytes=(matmuls * fwd.block.hbm_bytes + 2 * patches
+                           + relayout),
                 requirement=_requirement(prof), profiles=(prof,)))
 
     plan = ExecutionPlan(cfg=cfg, batch=batch, dataflow=dataflow,
@@ -1456,9 +1519,8 @@ def _feasible_batch(cfg: CapsNetConfig, vmem_budget: int,
     footprint -- it is larger, so it usually decides."""
     best = None
     for lay in cfg.routing_stack():
-        extra = lay.jd * ELEM_BYTES if lay.residual else 0
         b = _fused_max_batch(lay.in_caps, lay.in_dim, lay.jd, lay.num_caps,
-                             vmem_budget, extra)
+                             vmem_budget, lay.residual)
         if train:
             b = min(b, _fused_bwd_max_batch(lay.in_caps, lay.in_dim, lay.jd,
                                             lay.num_caps, lay.iters,
@@ -1483,10 +1545,12 @@ def _plan_concessions(baseline: ExecutionPlan,
         base = base_ops.get(op.name)
         if base is None:
             continue
+        if base.lanes != op.lanes and op.lanes is not None:
+            notes.append(f"{op.name}: lanes {base.lanes} -> {op.lanes}")
         if base.mode != op.mode and op.mode is not None:
             notes.append(f"{op.name}: {base.mode} -> {op.mode}")
         if (base.block_i is not None and op.block_i is not None
-                and op.block_i < base.block_i):
+                and base.lanes == op.lanes and op.block_i < base.block_i):
             notes.append(f"{op.name}: block_i {base.block_i} "
                          f"-> {op.block_i}")
         if (base.block_k is not None and op.block_k is not None
@@ -1518,7 +1582,7 @@ def degrade_plan(cfg: CapsNetConfig = CapsNetConfig(),
     already embodies most of the ladder (pipelined pair -> per-op pair,
     resident -> streamed, shrinking ``block_i``/``block_k``/conv tiles),
     so the walk here is: recompile at the reduced budget, and when even
-    streamed ``block_i=1`` cannot fit the batch, drop to the largest
+    the smallest streamed i-tile cannot fit the batch, drop to the largest
     feasible batch (``_fused_max_batch`` bound, halving as a safety net
     when a non-routing constraint binds instead) down to ``min_batch``.
 

@@ -28,10 +28,27 @@ import math
 from typing import Sequence
 
 # TPU v5e-ish constants (per core).
-VMEM_BYTES = 128 * 1024 * 1024 // 8          # 16 MiB VMEM
+VMEM_BYTES = 128 * 1024 * 1024 // 8          # 16 MiB VMEM plan budget
 LANES = 128
 SUBLANES = 8
 MXU = 128
+# Scoped-VMEM limit every kernel hands Mosaic: the plan budget plus as
+# much again for the compiler's own internal scratch (spilled in-register
+# temporaries), which no plan models.  v5e has 128 MiB of VMEM per core;
+# its default scoped limit (16 MiB) equals the budget and leaves none.
+VMEM_LIMIT_BYTES = 2 * VMEM_BYTES
+
+
+def tile_padded(shape: Sequence[int]) -> int:
+    """Elements a VMEM buffer of ``shape`` occupies under the TPU's
+    (8, 128) tiling of its two minor dims (32-bit elements): the second
+    minor dim pads to a multiple of 8 sublanes, the minor dim to a
+    multiple of 128 lanes, leading dims multiply."""
+    if not shape:
+        return 1
+    *lead, sub, lane = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    return (math.prod(lead) * (-(-sub // SUBLANES) * SUBLANES)
+            * (-(-lane // LANES) * LANES))
 
 # Relative energy weights (pJ/byte-ish; only ratios matter for the argmin).
 E_HBM = 1.0

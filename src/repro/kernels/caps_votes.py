@@ -39,7 +39,7 @@ def _votes_kernel(u_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_i", "interpret"))
 def caps_votes(u: jax.Array, w: jax.Array, *, block_i: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """u: [B, I, C], w: [I, N, C] -> [B, I, N].
 
     ``block_i`` is the CapStore-planned i-tile (see

@@ -3,13 +3,12 @@
 CapsAcc (Marchisio et al. 2018) and DESCNet run the CapsuleNet conv stack
 as im2col matmuls on the same PE array as the capsule operations; CapStore
 sizes the on-chip memories from that schedule.  These kernels are the TPU
-translation, in two Pallas stages:
+translation, in two stages:
 
-  1. ``im2col_patches``: strided patch extraction.  One grid step per batch
-     element keeps the (small) input feature map resident in VMEM (the
-     paper's data memory) and emits the [OH*OW, KH*KW*C] patch matrix.
+  1. ``im2col_patches``: patch extraction by XLA into the
+     [B, OH*OW, KH*KW*C] patch matrix in HBM.
 
-  2. ``matmul_bias_act``: blocked [M, K] x [K, N] matmul over the plan's
+  2. ``matmul_bias_act`` (Pallas): blocked [M, K] x [K, N] matmul over the plan's
      ``block_m/k/n`` grid tiles with a fused epilogue (bias + ReLU for
      Conv1, bias + per-capsule squash for PrimaryCaps).  The patch tile is
      the data memory, the weight tile streams (double-buffered), and the
@@ -24,14 +23,14 @@ block would double-count the overlap -- so K is zero-padded up to a
 multiple of ``block_k`` instead (zero rows contribute nothing).
 
 ``conv2d_im2col`` carries a ``jax.custom_vjp``, so ``jax.grad`` through the
-Pallas backend works end to end.  The backward pass is Pallas too:
+Pallas backend works end to end.  The backward matmuls are Pallas too:
 
   * dL/dW = patchesT @ dy via ``matmul_at_b`` (a blocked A^T B matmul over
     the SAME plan ``block_m/k/n`` tiles, with the shared M axis as the
     zero-padded reduction -- no HBM transpose of the patch slab);
   * dL/dpatches = dy @ W^T through ``matmul_bias_act`` (the weight
-    transpose is tiny), then dL/dx via the ``col2im_patches`` scatter
-    kernel, the exact transpose of the strided patch extraction;
+    transpose is tiny), then dL/dx via ``col2im_patches``, the exact
+    transpose of the patch extraction (XLA);
   * epilogue cotangents come from the saved output (ReLU mask) or a
     recomputed pre-activation (per-capsule squash), matching ``jax.grad``
     of the jnp reference to float32 accuracy.
@@ -45,106 +44,38 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.capsnet import SQUASH_EPS
 from repro.core.capsnet import squash as squash_reference
+from repro.core.planner import VMEM_LIMIT_BYTES
 
 EPILOGUES = ("none", "relu", "squash")
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _patches_kernel(x_ref, o_ref, *, kh: int, kw: int, stride: int,
-                    oh: int, ow: int):
-    x = x_ref[0]                                   # [H, W, C]
-    c = x.shape[-1]
-    taps = []
-    for i in range(kh):                            # static unroll: one strided
-        for j in range(kw):                        # slice per kernel tap
-            taps.append(jax.lax.slice(
-                x, (i, j, 0),
-                (i + (oh - 1) * stride + 1, j + (ow - 1) * stride + 1, c),
-                (stride, stride, 1)))              # [OH, OW, C]
-    p = jnp.stack(taps, axis=2)                    # [OH, OW, KH*KW, C]
-    o_ref[0] = p.reshape(oh * ow, kh * kw * c)
-
-
-def _patches_block_kernel(x_ref, o_ref, *, kh: int, kw: int, stride: int,
-                          ow: int, br: int, bc: int):
-    """Row-blocked patch extraction: this grid step emits the ``br x bc``
-    window of output positions starting at block ``pl.program_id(1)``.
-    The image stays resident (its block index never changes within a
-    batch element); only ``br * bc`` patch rows occupy VMEM at once."""
-    q = pl.program_id(1)
-    per_row = ow // bc
-    oy0 = (q // per_row) * br
-    ox0 = (q % per_row) * bc
-    x = x_ref[0]                                   # [H, W, C]
-    c = x.shape[-1]
-    xs = jax.lax.dynamic_slice(
-        x, (oy0 * stride, ox0 * stride, 0),
-        ((br - 1) * stride + kh, (bc - 1) * stride + kw, c))
-    taps = []
-    for i in range(kh):
-        for j in range(kw):
-            taps.append(jax.lax.slice(
-                xs, (i, j, 0),
-                (i + (br - 1) * stride + 1, j + (bc - 1) * stride + 1, c),
-                (stride, stride, 1)))              # [br, bc, C]
-    p = jnp.stack(taps, axis=2)                    # [br, bc, KH*KW, C]
-    o_ref[0] = p.reshape(br * bc, kh * kw * c)
-
-
-@functools.partial(jax.jit, static_argnames=("kh", "kw", "stride", "block_p",
-                                             "interpret"))
-def im2col_patches(x: jax.Array, *, kh: int, kw: int, stride: int = 1,
-                   block_p: int | None = None,
-                   interpret: bool = True) -> jax.Array:
-    """x: [B, H, W, C] -> patches [B, OH*OW, KH*KW*C] (VALID padding).
-
-    Patch column order is ``(kh, kw, c)``-major, matching
-    ``w.reshape(KH*KW*C, Cout)`` of an HWIO weight tensor.
-
-    ``block_p`` bounds the VMEM held per grid step: ``None`` emits the
-    whole patch matrix of one batch element at once (image + full matrix
-    resident -- fine under a full budget), while a plan-chosen block
-    emits ``block_p`` patch rows per step so a degraded budget only pays
-    image + one row block.  ``block_p`` must tile the output grid: a
-    divisor of ``OW`` (a within-row window) or a multiple of ``OW``
-    whose row count divides ``OH`` (whole output rows).
-    """
+def _patches_xla(x: jax.Array, kh: int, kw: int, stride: int) -> jax.Array:
     b, h, w, c = x.shape
     oh = (h - kh) // stride + 1
     ow = (w - kw) // stride + 1
-    if block_p is None or block_p >= oh * ow:
-        kernel = functools.partial(_patches_kernel, kh=kh, kw=kw,
-                                   stride=stride, oh=oh, ow=ow)
-        return pl.pallas_call(
-            kernel,
-            grid=(b,),
-            in_specs=[pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0))],
-            out_specs=pl.BlockSpec((1, oh * ow, kh * kw * c),
-                                   lambda i: (i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, oh * ow, kh * kw * c),
-                                           x.dtype),
-            interpret=interpret,
-        )(x)
-    if block_p % ow == 0 and (oh % (block_p // ow)) == 0:
-        br, bc = block_p // ow, ow
-    elif block_p < ow and ow % block_p == 0:
-        br, bc = 1, block_p
-    else:
-        raise ValueError(
-            f"block_p={block_p} does not tile the {oh}x{ow} output grid "
-            f"(need a divisor of OW or a multiple of OW dividing OH*OW)")
-    kernel = functools.partial(_patches_block_kernel, kh=kh, kw=kw,
-                               stride=stride, ow=ow, br=br, bc=bc)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, (oh * ow) // block_p),
-        in_specs=[pl.BlockSpec((1, h, w, c), lambda i, q: (i, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, block_p, kh * kw * c),
-                               lambda i, q: (i, q, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, oh * ow, kh * kw * c), x.dtype),
-        interpret=interpret,
-    )(x)
+    taps = [x[:, i:i + (oh - 1) * stride + 1:stride,
+              j:j + (ow - 1) * stride + 1:stride, :]
+            for i in range(kh) for j in range(kw)]
+    return jnp.stack(taps, axis=3).reshape(b, oh * ow, kh * kw * c)
+
+
+@functools.partial(jax.jit, static_argnames=("kh", "kw", "stride"))
+def im2col_patches(x: jax.Array, *, kh: int, kw: int,
+                   stride: int = 1) -> jax.Array:
+    """x: [B, H, W, C] -> patches [B, OH*OW, KH*KW*C] (VALID padding).
+
+    Patch column order is ``(kh, kw, c)``-major, matching
+    ``w.reshape(KH*KW*C, Cout)`` of an HWIO weight tensor.  XLA extracts
+    the patches: the matrix crosses HBM once either way (the matmul
+    streams it in K tiles), and strided tap windows are not a Mosaic
+    layout.
+    """
+    return _patches_xla(x, kh, kw, stride)
 
 
 def _matmul_kernel(p_ref, w_ref, b_ref, o_ref, *, k_steps: int,
@@ -157,6 +88,7 @@ def _matmul_kernel(p_ref, w_ref, b_ref, o_ref, *, k_steps: int,
 
     o_ref[...] += jnp.dot(
         p_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
     @pl.when(ki == k_steps - 1)
@@ -165,10 +97,17 @@ def _matmul_kernel(p_ref, w_ref, b_ref, o_ref, *, k_steps: int,
         if epilogue == "relu":
             acc = jnp.maximum(acc, 0.0)
         elif epilogue == "squash":
-            tm, tn = acc.shape
-            acc = squash_reference(
-                acc.reshape(tm, tn // squash_dim, squash_dim)
-            ).reshape(tm, tn)
+            # Each capsule's squared norm, broadcast back over its
+            # squash_dim lanes by a block-diagonal 0/1 matmul (a lane
+            # reshape into capsules is not a Mosaic layout).
+            tn = acc.shape[1]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
+            peer = jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 1)
+            same_caps = (lane // squash_dim == peer // squash_dim)
+            sq = jnp.dot(acc * acc, same_caps.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+            acc = (sq / (1.0 + sq)) * acc * jax.lax.rsqrt(sq + SQUASH_EPS)
         o_ref[...] = acc
 
 
@@ -177,7 +116,7 @@ def _matmul_kernel(p_ref, w_ref, b_ref, o_ref, *, k_steps: int,
 def matmul_bias_act(p: jax.Array, w: jax.Array, bias: jax.Array, *,
                     block_m: int = 128, block_k: int = 128,
                     block_n: int = 128, epilogue: str = "none",
-                    squash_dim: int = 0, interpret: bool = True) -> jax.Array:
+                    squash_dim: int = 0, interpret: bool) -> jax.Array:
     """p: [M, K], w: [K, N], bias: [N] -> epilogue(p @ w + bias): [M, N].
 
     ``epilogue="squash"`` treats every ``squash_dim`` consecutive output
@@ -215,6 +154,7 @@ def matmul_bias_act(p: jax.Array, w: jax.Array, bias: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(p, w, bias.reshape(1, n))
 
@@ -232,6 +172,7 @@ def _at_b_kernel(a_ref, b_ref, o_ref):
 
     o_ref[...] += jnp.dot(
         a_ref[...].astype(jnp.float32).T, b_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -239,7 +180,7 @@ def _at_b_kernel(a_ref, b_ref, o_ref):
     "block_m", "block_k", "block_n", "interpret"))
 def matmul_at_b(a: jax.Array, b: jax.Array, *, block_m: int = 128,
                 block_k: int = 128, block_n: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """a: [M, K], b: [M, N] -> a^T @ b: [K, N] without an HBM transpose.
 
     The backward-pass dW matmul (patches^T @ dy): the shared M axis is the
@@ -268,110 +209,25 @@ def matmul_at_b(a: jax.Array, b: jax.Array, *, block_m: int = 128,
         ],
         out_specs=pl.BlockSpec((bk, bn), lambda ki, ni, mi: (ki, ni)),
         out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(a, b)
 
 
-def _col2im_kernel(dp_ref, o_ref, *, kh: int, kw: int, stride: int,
-                   oh: int, ow: int, h: int, w: int):
-    c = o_ref.shape[-1]
-    dp = dp_ref[0].reshape(oh, ow, kh * kw, c)
-    dx = jnp.zeros((h, w, c), jnp.float32)
-    tap = 0
-    for i in range(kh):                            # static unroll: one strided
-        for j in range(kw):                        # scatter-add per kernel tap
-            dx = dx.at[i:i + (oh - 1) * stride + 1:stride,
-                       j:j + (ow - 1) * stride + 1:stride, :].add(
-                dp[:, :, tap].astype(jnp.float32))
-            tap += 1
-    o_ref[0] = dx.astype(o_ref.dtype)
-
-
-def _col2im_block_kernel(dp_ref, o_ref, *, kh: int, kw: int, stride: int,
-                         ow: int, br: int, bc: int, h: int, w: int):
-    """Row-blocked col2im: dx stays resident as the accumulator across
-    the row-block grid axis; each step scatter-adds one ``br x bc``
-    window of patch cotangents into its strided dx region (windows of
-    adjacent blocks overlap when ``stride < k``; the sequential grid
-    makes the read-modify-write safe)."""
-    q = pl.program_id(1)
-
-    @pl.when(q == 0)
-    def _():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-
-    per_row = ow // bc
-    oy0 = (q // per_row) * br
-    ox0 = (q % per_row) * bc
-    c = o_ref.shape[-1]
-    dp = dp_ref[0].reshape(br, bc, kh * kw, c)
-    hs = (br - 1) * stride + kh
-    ws = (bc - 1) * stride + kw
-    dx = jnp.zeros((hs, ws, c), jnp.float32)
-    tap = 0
-    for i in range(kh):
-        for j in range(kw):
-            dx = dx.at[i:i + (br - 1) * stride + 1:stride,
-                       j:j + (bc - 1) * stride + 1:stride, :].add(
-                dp[:, :, tap].astype(jnp.float32))
-            tap += 1
-    base = o_ref[0]
-    cur = jax.lax.dynamic_slice(
-        base, (oy0 * stride, ox0 * stride, 0), (hs, ws, c))
-    o_ref[0] = jax.lax.dynamic_update_slice(
-        base, (cur + dx).astype(base.dtype),
-        (oy0 * stride, ox0 * stride, 0))
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "stride", "h", "w", "block_p", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kh", "kw", "stride", "h", "w"))
 def col2im_patches(dp: jax.Array, *, kh: int, kw: int, stride: int,
-                   h: int, w: int, block_p: int | None = None,
-                   interpret: bool = True) -> jax.Array:
-    """dp: [B, OH*OW, KH*KW*C] -> dx: [B, H, W, C].
+                   h: int, w: int) -> jax.Array:
+    """dp: [B, OH*OW, KH*KW*C] -> dx: [B, H, W, C] (fp32).
 
-    The exact transpose of ``im2col_patches``: each kernel tap's cotangent
-    slab is scatter-added back onto the strided input positions it was
-    sliced from (one grid step per batch element, dx resident in VMEM).
-    ``block_p`` streams the cotangent ``block_p`` patch rows at a time
-    (same tiling constraints as ``im2col_patches``) so a degraded budget
-    never holds the whole dpatches slab on chip.
+    The exact transpose of ``im2col_patches``: each kernel tap's
+    cotangent slab is scatter-added back onto the input positions it was
+    sliced from (the VJP of the XLA extraction).
     """
     bsz = dp.shape[0]
     c = dp.shape[2] // (kh * kw)
-    oh = (h - kh) // stride + 1
-    ow = (w - kw) // stride + 1
-    if block_p is None or block_p >= oh * ow:
-        kernel = functools.partial(_col2im_kernel, kh=kh, kw=kw,
-                                   stride=stride, oh=oh, ow=ow, h=h, w=w)
-        return pl.pallas_call(
-            kernel,
-            grid=(bsz,),
-            in_specs=[pl.BlockSpec((1, oh * ow, kh * kw * c),
-                                   lambda i: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((bsz, h, w, c), jnp.float32),
-            interpret=interpret,
-        )(dp)
-    if block_p % ow == 0 and (oh % (block_p // ow)) == 0:
-        br, bc = block_p // ow, ow
-    elif block_p < ow and ow % block_p == 0:
-        br, bc = 1, block_p
-    else:
-        raise ValueError(
-            f"block_p={block_p} does not tile the {oh}x{ow} output grid "
-            f"(need a divisor of OW or a multiple of OW dividing OH*OW)")
-    kernel = functools.partial(_col2im_block_kernel, kh=kh, kw=kw,
-                               stride=stride, ow=ow, br=br, bc=bc, h=h, w=w)
-    return pl.pallas_call(
-        kernel,
-        grid=(bsz, (oh * ow) // block_p),
-        in_specs=[pl.BlockSpec((1, block_p, kh * kw * c),
-                               lambda i, q: (i, q, 0))],
-        out_specs=pl.BlockSpec((1, h, w, c), lambda i, q: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, h, w, c), jnp.float32),
-        interpret=interpret,
-    )(dp)
+    x0 = jnp.zeros((bsz, h, w, c), dp.dtype)
+    _, pull = jax.vjp(lambda x: _patches_xla(x, kh, kw, stride), x0)
+    return pull(dp)[0].astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +244,6 @@ class _ConvStatics(NamedTuple):
     epilogue: str
     squash_dim: int
     interpret: bool
-    block_p: int | None = None
 
 
 def _conv_apply(st: _ConvStatics, x, w, bias):
@@ -396,8 +251,7 @@ def _conv_apply(st: _ConvStatics, x, w, bias):
     kh, kw, _, cout = w.shape
     oh = (h - kh) // st.stride + 1
     ow = (w_hw - kw) // st.stride + 1
-    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride,
-                             block_p=st.block_p, interpret=st.interpret)
+    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride)
     out = matmul_bias_act(
         patches.reshape(b * oh * ow, kh * kw * cin),
         w.reshape(kh * kw * cin, cout), bias,
@@ -430,8 +284,7 @@ def _conv_core_bwd(st: _ConvStatics, res, dy):
     kk = kh * kw * cin
     dy2 = dy.reshape(m, cout).astype(jnp.float32)
     w2 = w.reshape(kk, cout)
-    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride,
-                             block_p=st.block_p, interpret=st.interpret)
+    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride)
     p2 = patches.reshape(m, kk)
 
     # Epilogue cotangent: ReLU masks from the saved output; the fused
@@ -458,8 +311,7 @@ def _conv_core_bwd(st: _ConvStatics, res, dy):
         block_m=st.block_m, block_k=st.block_n, block_n=st.block_k,
         epilogue="none", interpret=st.interpret)
     dx = col2im_patches(dpatches.reshape(b, oh * ow, kk), kh=kh, kw=kw,
-                        stride=st.stride, h=h, w=w_hw,
-                        block_p=st.block_p, interpret=st.interpret)
+                        stride=st.stride, h=h, w=w_hw)
     return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype), dbias)
 
 
@@ -468,22 +320,20 @@ _conv_core.defvjp(_conv_core_fwd, _conv_core_bwd)
 
 @functools.partial(jax.jit, static_argnames=(
     "stride", "block_m", "block_k", "block_n", "epilogue", "squash_dim",
-    "block_p", "interpret"))
+    "interpret"))
 def conv2d_im2col(x: jax.Array, w: jax.Array, bias: jax.Array, *,
                   stride: int = 1, block_m: int = 128, block_k: int = 128,
                   block_n: int = 128, epilogue: str = "none",
-                  squash_dim: int = 0, block_p: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  squash_dim: int = 0, interpret: bool) -> jax.Array:
     """VALID conv as im2col matmul: x [B,H,W,Cin], w [KH,KW,Cin,Cout] HWIO.
 
     Returns ``epilogue(conv(x, w) + bias)`` as [B, OH, OW, Cout].  Block
     shapes come from the ExecutionPlan (see ``kernels/ops.py``).
     Differentiable: carries a custom VJP whose backward runs the Pallas
-    ``matmul_at_b`` (dW), ``matmul_bias_act`` (dpatches) and
-    ``col2im_patches`` (dx) kernels over the same block tiles.
+    ``matmul_at_b`` (dW) and ``matmul_bias_act`` (dpatches) kernels over
+    the same block tiles, then ``col2im_patches`` (dx).
     """
     st = _ConvStatics(stride=stride, block_m=block_m, block_k=block_k,
                       block_n=block_n, epilogue=epilogue,
-                      squash_dim=squash_dim, interpret=interpret,
-                      block_p=block_p)
+                      squash_dim=squash_dim, interpret=interpret)
     return _conv_core(st, x, w, bias)

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels, driven by one ExecutionPlan.
 
-Every wrapper takes ``interpret`` (default True: CPU-validated execution;
-on real TPU pass False).  Block shapes come from an ``ExecutionPlan``
+Every wrapper takes ``interpret``; the default ``None`` compiles the
+kernels with Mosaic on a TPU and runs the Pallas interpreter on any other
+backend (``should_interpret``).  Block shapes come from an ``ExecutionPlan``
 (``repro.core.execplan.compile_plan``) when one is passed; otherwise the
 planner pick is computed once per shape and memoized -- wrappers never
 re-run the block-shape DSE per invocation.  The oracles live in
@@ -31,6 +32,15 @@ from repro.kernels.votes_routing import \
 from repro.kernels.votes_routing import votes_routing as _votes_routing
 
 
+def should_interpret(interpret: bool | None = None) -> bool:
+    """Whether the Pallas kernels run in interpret mode: as the caller
+    says, else exactly when the default backend is not a TPU (Mosaic only
+    compiles for TPUs; everywhere else the interpreter is the executor)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 @functools.lru_cache(maxsize=64)            # m folds in the batch: bounded
 def planned_conv_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:
     """CapStore planner pick for a conv's im2col matmul tiles (memoized,
@@ -40,7 +50,7 @@ def planned_conv_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:
 
 
 def conv2d(x, w, b, *, stride: int = 1, plan_op=None, epilogue: str = "none",
-           squash_dim: int = 0, interpret: bool = True):
+           squash_dim: int = 0, interpret: bool | None = None):
     """Plan-driven im2col conv: x [B,H,W,Cin], w [KH,KW,Cin,Cout] (HWIO).
 
     ``plan_op`` is the matching ``OpPlan`` (``plan.op("Conv1")`` /
@@ -51,11 +61,9 @@ def conv2d(x, w, b, *, stride: int = 1, plan_op=None, epilogue: str = "none",
     custom VJP reuses the same block tiles for the backward matmuls and
     the col2im scatter.
     """
-    bp = None
     if plan_op is not None:
         bm, bk, bn = (plan_op.block.block_m, plan_op.block.block_k,
                       plan_op.block.block_n)
-        bp = plan_op.patch_rows
         if plan_op.fuses_squash:
             epilogue = "squash"
     else:
@@ -66,7 +74,7 @@ def conv2d(x, w, b, *, stride: int = 1, plan_op=None, epilogue: str = "none",
                                          kh * kw * cin, cout)
     out = _conv2d(x, w, b, stride=stride, block_m=bm, block_k=bk,
                   block_n=bn, epilogue=epilogue, squash_dim=squash_dim,
-                  block_p=bp, interpret=interpret)
+                  interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_CONV2D, out)
     return out
@@ -88,7 +96,8 @@ def planned_block_i(num_caps: int, caps_dim: int, out_dim: int,
 
 
 def caps_votes(u: jax.Array, w: jax.Array, *, plan=None,
-               block_i: int | None = None, interpret: bool = True) -> jax.Array:
+               block_i: int | None = None,
+               interpret: bool | None = None) -> jax.Array:
     """u: [B, I, C], w: [I, N, C] -> [B, I, N] (split-path oracle/fallback;
     the plan executes the fused ``votes_routing`` instead)."""
     if block_i is None:
@@ -97,7 +106,8 @@ def caps_votes(u: jax.Array, w: jax.Array, *, plan=None,
         else:
             block_i = planned_block_i(u.shape[1], u.shape[2], w.shape[1],
                                       u.shape[0])
-    out = _caps_votes(u, w, block_i=block_i, interpret=interpret)
+    out = _caps_votes(u, w, block_i=block_i,
+                      interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_CAPS_VOTES, out)
     return out
@@ -105,13 +115,13 @@ def caps_votes(u: jax.Array, w: jax.Array, *, plan=None,
 
 def routing(u_hat: jax.Array, *, plan=None, iters: int | None = None,
             num_classes: int | None = None,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool | None = None) -> jax.Array:
     if iters is None:
         iters = plan.cfg.routing_iters if plan is not None else 3
     if num_classes is None:
         num_classes = plan.cfg.num_classes if plan is not None else 10
     out = _routing(u_hat, iters=iters, num_classes=num_classes,
-                   interpret=interpret)
+                   interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_ROUTING, out)
     return out
@@ -120,12 +130,14 @@ def routing(u_hat: jax.Array, *, plan=None, iters: int | None = None,
 @functools.lru_cache(maxsize=64)
 def planned_votes_routing(num_caps: int, caps_dim: int, jd: int,
                           num_classes: int, iters: int, batch: int,
-                          vmem_budget: int = VMEM_BYTES) -> tuple[str, int]:
-    """Memoized (mode, block_i) decision for the fused megakernel."""
+                          vmem_budget: int = VMEM_BYTES
+                          ) -> tuple[str, int, str]:
+    """Memoized (mode, block_i, lanes) decision for the fused
+    megakernel."""
     sched = execplan.plan_votes_routing(num_caps, caps_dim, jd, num_classes,
                                         batch=batch, iters=iters,
                                         vmem_budget=vmem_budget)
-    return sched.mode, sched.block_i
+    return sched.mode, sched.block_i, sched.lanes
 
 
 @functools.lru_cache(maxsize=64)            # bounded like the plan caches
@@ -140,13 +152,13 @@ def _warn_bwd_fallback_once(msg: str) -> None:
 def planned_votes_routing_bwd(num_caps: int, caps_dim: int, jd: int,
                               num_classes: int, iters: int, batch: int,
                               vmem_budget: int = VMEM_BYTES
-                              ) -> tuple[str, int]:
-    """Memoized (mode, block_i) decision for the fused BACKWARD kernel
-    (independent of the forward's: its scratch is larger)."""
+                              ) -> tuple[str, int, str]:
+    """Memoized (mode, block_i, lanes) decision for the fused BACKWARD
+    kernel (independent of the forward's: its scratch is larger)."""
     sched = execplan.plan_votes_routing_bwd(
         num_caps, caps_dim, jd, num_classes, batch=batch, iters=iters,
         vmem_budget=vmem_budget)
-    return sched.mode, sched.block_i
+    return sched.mode, sched.block_i, sched.lanes
 
 
 def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
@@ -154,9 +166,11 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
                   iters: int | None = None, num_classes: int | None = None,
                   mode: str | None = None, block_i: int | None = None,
                   bwd_mode: str | None = None, bwd_block_i: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  lanes: str | None = None, bwd_lanes: str | None = None,
+                  interpret: bool | None = None) -> jax.Array:
     """u: [B, I, C], w: [I, J*D, C] -> v: [B, J*D]: fused votes + routing
-    (u_hat never leaves the chip).  Schedule (``mode``/``block_i``) comes
+    (u_hat never leaves the chip).  Schedule (``mode``/``block_i``/
+    ``lanes``) comes
     from ``plan.op(op_name)`` -- default ``"ClassCaps-Routing"``, the
     final classification layer; deep-stack callers pass the intermediate
     layer's plan-op name (``"ClassCaps-Routing[0]"``, ...) -- or the
@@ -186,12 +200,15 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
             op = plan.op(op_name)
             mode = mode or op.mode
             block_i = block_i or op.block_i
+            lanes = lanes or op.lanes
         else:
-            pmode, pbi = planned_votes_routing(
+            pmode, pbi, planes = planned_votes_routing(
                 u.shape[1], u.shape[2], w.shape[1], num_classes, iters,
                 u.shape[0])
             mode = mode or pmode
             block_i = block_i or pbi
+            lanes = lanes or planes
+    lanes = lanes or "caps"
     if bwd_mode is None or bwd_block_i is None:
         budget = plan.vmem_budget if plan is not None else VMEM_BYTES
         bwd_op = None
@@ -200,9 +217,10 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
         if bwd_op is not None:
             bwd_mode = bwd_mode or bwd_op.mode
             bwd_block_i = bwd_block_i or bwd_op.block_i
+            bwd_lanes = bwd_lanes or bwd_op.lanes
         else:
             try:
-                pbmode, pbbi = planned_votes_routing_bwd(
+                pbmode, pbbi, pblanes = planned_votes_routing_bwd(
                     u.shape[1], u.shape[2], w.shape[1], num_classes, iters,
                     u.shape[0], budget)
             except execplan.PlanError as err:
@@ -218,12 +236,15 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
                     f"will reuse the forward schedule "
                     f"(mode={mode!r}, block_i={block_i}) with a "
                     f"backward VMEM footprint the plan never validated")
-                pbmode, pbbi = mode, block_i
+                pbmode, pbbi, pblanes = mode, block_i, lanes
             bwd_mode = bwd_mode or pbmode
             bwd_block_i = bwd_block_i or pbbi
+            bwd_lanes = bwd_lanes or pblanes
     out = _votes_routing(u, w, iters=iters, num_classes=num_classes,
                          mode=mode, block_i=block_i, bwd_mode=bwd_mode,
-                         bwd_block_i=bwd_block_i, interpret=interpret)
+                         bwd_block_i=bwd_block_i, lanes=lanes,
+                         bwd_lanes=bwd_lanes or lanes,
+                         interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_VOTES_ROUTING, out)
     return out
@@ -251,7 +272,8 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                     mode: str | None = None, block_i: int | None = None,
                     block_k: int | None = None, bwd_mode: str | None = None,
                     bwd_block_i: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    bwd_lanes: str | None = None,
+                    interpret: bool | None = None) -> jax.Array:
     """Pipelined PrimaryCaps conv + votes/routing as ONE kernel: x is the
     Conv1 output [B, H, W, Cin], w_pc/b_pc the PrimaryCaps conv params,
     w_cc [I, J*D, C] the routing weights -> v [B, J*D].  The inter-layer
@@ -276,7 +298,6 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
     kh, kw, cin, n_ch = w_pc.shape
     oh = (x.shape[1] - kh) // stride + 1
     ow = (x.shape[2] - kw) // stride + 1
-    patch_rows = None
     if mode is None or block_i is None or block_k is None:
         if plan is not None:
             if x.shape[0] > plan.batch:
@@ -288,7 +309,6 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
             mode = mode or op.mode
             block_i = block_i or op.block_i
             block_k = block_k or op.block_k
-            patch_rows = op.patch_rows
             cb = (op.block.block_m, op.block.block_k, op.block.block_n)
         else:
             pmode, pbi, pbk, cb = planned_primary_routing(
@@ -307,9 +327,10 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
         if bwd_op is not None:
             bwd_mode = bwd_mode or bwd_op.mode
             bwd_block_i = bwd_block_i or bwd_op.block_i
+            bwd_lanes = bwd_lanes or bwd_op.lanes
         else:
             try:
-                pbmode, pbbi = planned_votes_routing_bwd(
+                pbmode, pbbi, pblanes = planned_votes_routing_bwd(
                     num_caps, caps_dim, jd, num_classes, iters, x.shape[0],
                     budget)
             except execplan.PlanError as err:
@@ -320,39 +341,41 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                     f"call will reuse the forward schedule "
                     f"(mode={mode!r}, block_i={block_i}) with a backward "
                     f"VMEM footprint the plan never validated")
-                pbmode, pbbi = mode, block_i
+                pbmode, pbbi, pblanes = mode, block_i, "caps"
             bwd_mode = bwd_mode or pbmode
             bwd_block_i = bwd_block_i or pbbi
+            bwd_lanes = bwd_lanes or pblanes
     out = _primary_routing(
         x, w_pc, b_pc, w_cc, stride=stride, iters=iters,
         num_classes=num_classes, mode=mode, block_i=block_i,
         block_k=block_k, bwd_mode=bwd_mode, bwd_block_i=bwd_block_i,
-        conv_block_m=cb[0], conv_block_k=cb[1], conv_block_n=cb[2],
-        block_p=patch_rows, interpret=interpret)
+        bwd_lanes=bwd_lanes or "caps", conv_block_m=cb[0], conv_block_k=cb[1], conv_block_n=cb[2],
+        interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_PRIMARY_ROUTING, out)
     return out
 
 
 def _layer_schedule(lay, batch: int, plan) -> tuple[int, int, str, int,
-                                                    str, int]:
+                                                    str, int, str, str]:
     """Resolve one routing layer's (iters, j, mode, block_i, bwd_mode,
-    bwd_block_i) kernel statics from the plan's per-layer OpPlans (or the
-    memoized plan decision), with the same backward-fallback semantics as
-    ``votes_routing``."""
+    bwd_block_i, lanes, bwd_lanes) kernel statics from the plan's
+    per-layer OpPlans (or the memoized plan decision), with the same
+    backward-fallback semantics as ``votes_routing``."""
     if plan is not None:
         op = plan.op(lay.name)
-        mode, block_i = op.mode, op.block_i
+        mode, block_i, lanes = op.mode, op.block_i, op.lanes
     else:
-        mode, block_i = planned_votes_routing(
+        mode, block_i, lanes = planned_votes_routing(
             lay.in_caps, lay.in_dim, lay.jd, lay.num_caps, lay.iters, batch)
     budget = plan.vmem_budget if plan is not None else VMEM_BYTES
     if plan is not None and plan.train:
         bwd_op = plan.op(lay.name + execplan.BWD_SUFFIX)
-        bwd_mode, bwd_block_i = bwd_op.mode, bwd_op.block_i
+        bwd_mode, bwd_block_i, bwd_lanes = (bwd_op.mode, bwd_op.block_i,
+                                            bwd_op.lanes)
     else:
         try:
-            bwd_mode, bwd_block_i = planned_votes_routing_bwd(
+            bwd_mode, bwd_block_i, bwd_lanes = planned_votes_routing_bwd(
                 lay.in_caps, lay.in_dim, lay.jd, lay.num_caps, lay.iters,
                 batch, budget)
         except execplan.PlanError as err:
@@ -362,12 +385,13 @@ def _layer_schedule(lay, batch: int, plan) -> tuple[int, int, str, int,
                 f"differentiating this call will reuse the forward "
                 f"schedule (mode={mode!r}, block_i={block_i}) with a "
                 f"backward VMEM footprint the plan never validated")
-            bwd_mode, bwd_block_i = mode, block_i
-    return (lay.iters, lay.num_caps, mode, block_i, bwd_mode, bwd_block_i)
+            bwd_mode, bwd_block_i, bwd_lanes = mode, block_i, lanes
+    return (lay.iters, lay.num_caps, mode, block_i, bwd_mode, bwd_block_i,
+            lanes, bwd_lanes)
 
 
 def res_caps_segment(x: jax.Array, ws, pairs, *, plan=None,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Reversible residual capsule segment: x [B, I, C] through a maximal
     run of ``ResCapsBlock`` coupling pairs -> [B, I, C].
 
@@ -387,38 +411,39 @@ def res_caps_segment(x: jax.Array, ws, pairs, *, plan=None,
         (lf.num_caps, _layer_schedule(lf, x.shape[0], plan),
          _layer_schedule(lg, x.shape[0], plan)) for lf, lg in pairs)
     out = _res_caps_segment(x, tuple(ws), blocks=blocks,
-                            interpret=interpret)
+                            interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_RES_CAPS_SEGMENT, out)
     return out
 
 
 def squash(x: jax.Array, *, plan=None, block_rows: int | None = None,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool | None = None) -> jax.Array:
     if block_rows is None:
         if plan is not None:
             block_rows = plan.op("PrimaryCaps").block_rows
         else:
             block_rows = 1024
-    out = _squash(x, block_rows=block_rows, interpret=interpret)
+    out = _squash(x, block_rows=block_rows,
+                  interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_SQUASH, out)
     return out
 
 
 def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
-            interpret: bool = True) -> jax.Array:
-    out = _rmsnorm(x, weight, eps=eps, interpret=interpret)
+            interpret: bool | None = None) -> jax.Array:
+    out = _rmsnorm(x, weight, eps=eps, interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_RMSNORM, out)
     return out
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    scale=None, block_q=128, block_k=128, interpret=True):
+                    scale=None, block_q=128, block_k=128, interpret=None):
     out = _flash(q, k, v, causal=causal, window=window, softcap=softcap,
                  scale=scale, block_q=block_q, block_k=block_k,
-                 interpret=interpret)
+                 interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_FLASH_ATTENTION, out)
     return out
@@ -429,4 +454,4 @@ __all__ = ["conv2d", "caps_votes", "routing", "votes_routing",
            "flash_attention",
            "planned_block_i", "planned_conv_blocks",
            "planned_votes_routing", "planned_votes_routing_bwd",
-           "planned_primary_routing", "ref"]
+           "planned_primary_routing", "ref", "should_interpret"]

@@ -8,40 +8,33 @@ PrimaryCaps output ``u [B, I, C]`` still round-tripped HBM between two
 ``pallas_call``s.  This kernel runs the producer AND the consumer as ONE
 ``pallas_call``:
 
-  produce   grid steps ``0 .. k_steps-1``.  The full producer output
-            lives in a ``[B, I_pad, C]`` VMEM scratch (u is the SMALLEST
-            tensor in the pair -- ~I*C floats per batch element -- which
-            is exactly why the paper parks it on-chip).  Each step
-            streams one K tile of the im2col patches and conv weight
-            past it, accumulating ``pre += patches_k @ w_k``; the last
-            K step applies the bias + per-capsule squash epilogue in
-            place.  Patches and the conv weight are read exactly ONCE
-            (a per-i-block recompute would re-stream the 21 MB MNIST
-            conv weight once per i-block -- strictly worse traffic than
-            the unfused pair).
+  produce   grid steps ``0 .. k_steps-1``.  Each step streams one K
+            tile of the im2col patches and conv weight past a resident
+            ``[B, N, P]`` pre-activation scratch (transposed, so the
+            positions lie on lanes); the last K step applies the bias +
+            per-capsule squash epilogue and lays the capsules out in the
+            ``[B, C, I_pad]`` scratch the consumer reads (u is the
+            SMALLEST tensor in the pair -- ~I*C floats per batch element
+            -- which is exactly why the paper parks it on-chip).
+            Patches and the conv weight are read exactly ONCE (a
+            per-i-block recompute would re-stream the 21 MB MNIST conv
+            weight once per i-block -- strictly worse traffic than the
+            unfused pair).
 
-  consume   the remaining grid steps are byte-for-byte the fused
-            ``votes_routing`` schedules, reading u i-blocks from the
-            produce scratch instead of an HBM operand.  The FIRST
-            consume block rides the last produce step (u is fully
-            squashed by in-body program order), so the pair overlaps by
-            one step:
+  consume   the remaining ``(iters + 1) * n_blocks`` grid steps are the
+            fused ``votes_routing`` schedule (resident votes scratch or
+            votes recomputed from re-streamed W tiles), reading u
+            i-blocks from the produce scratch instead of an HBM operand.
+            The FIRST consume block rides the last produce step (u is
+            fully squashed by in-body program order), so the pair
+            overlaps by one step.
 
-            resident  ``k_steps - 1 + n_blocks`` total steps; votes
-                      into a ``[B, I_pad, J*D]`` scratch, all routing
-                      iterations at the last block.
-            streamed  ``k_steps - 1 + (iters+1) * n_blocks`` steps; the
-                      fused s+b pass over re-streamed W tiles (the PR-5
-                      single-stream-per-iteration schedule).
-
-The conv-output -> capsule reshape is layout-free: row ``i = p * groups
-+ g`` of u is exactly channels ``[g*C, (g+1)*C)`` of spatial position
-``p``, so the produce scratch's rows ARE capsule rows and the epilogue
-squashes over the trailing axis directly.  The i axis is zero-padded in
-the SCRATCH (rows ``>= I`` stay at their zero initialisation, are
-skipped by the epilogue, and are inert under the routing reduction --
-the ``votes_routing`` padding argument verbatim, minus the host-side
-copy).
+The capsules land in the scratch group-major (lane ``i' = g*P + p``)
+where the conv's rows are position-major (``i = p*G + g``); routing is a
+sum over capsules, so the wrapper permutes W_cc's rows to match and the
+output is unchanged.  Lanes ``>= I`` stay at their zero initialisation
+and are inert under the routing reduction (the ``votes_routing`` padding
+argument, minus the host-side copy).
 
 **Backward** (``jax.custom_vjp``): recompute-from-patches.  The saved
 residuals are the raw operands ``(x, W_pc, b_pc, W_cc)``; the backward
@@ -49,7 +42,7 @@ replays the producer (im2col + blocked matmul, epilogue recomputed like
 the fused-squash conv backward), feeds the rebuilt u to the routing
 backward kernels (``votes_routing._vr_grad`` -- ``d u_hat`` stays in
 VMEM), pulls the squash VJP, and finishes with the conv backward's
-``matmul_at_b`` / ``matmul_bias_act`` / ``col2im_patches`` kernels.  It
+``matmul_at_b`` / ``matmul_bias_act`` kernels and ``col2im_patches``.  It
 composes exactly the per-op backward OpPlans, so a pipelined training
 plan keeps the per-op backward schedule unchanged.
 """
@@ -65,116 +58,81 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.capsnet import squash
-from repro.kernels.conv_im2col import (col2im_patches, im2col_patches,
-                                       matmul_at_b, matmul_bias_act)
-from repro.kernels.votes_routing import (_routing_iterations, _votes_block,
-                                         _vr_grad, _VRStatics)
+from repro.kernels.conv_im2col import (COMPILER_PARAMS, col2im_patches,
+                                       im2col_patches, matmul_at_b,
+                                       matmul_bias_act)
+from repro.kernels.votes_routing import (LAYOUTS, _CapsLanes, _route_block,
+                                         _rows, _vr_grad, _VRStatics)
 
 MODES = ("resident", "streamed")
 
 
-def _produce_u(t, patches_ref, wpc_ref, bias_ref, u_scr, *, k_steps: int,
-               p_pos: int, groups: int, caps_dim: int, i_dim: int):
+def _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr, *,
+               k_steps: int, groups: int, caps_dim: int, p_pos: int):
     """Produce phase: accumulate one K tile of the im2col matmul into the
-    resident output scratch; the last K step applies bias + squash in
-    place.  Rows ``>= i_dim`` keep their zero initialisation -- the
-    i-axis padding the consume phase relies on."""
+    transposed pre-activation scratch ``[B, N, P]``; the last K step adds
+    the bias, squashes each capsule (channels ``g*C .. (g+1)*C`` of a
+    position) and writes group ``g`` to lanes ``g*P .. (g+1)*P`` of the
+    capsule scratch ``u [B, C, I_pad]``.  Lanes ``>= I`` keep their zero
+    initialisation -- the i-axis padding the consume phase relies on."""
 
     @pl.when(t == 0)
     def _():
+        pre_scr[...] = jnp.zeros_like(pre_scr)
         u_scr[...] = jnp.zeros_like(u_scr)
 
     @pl.when(t < k_steps)
     def _():
-        bsz = patches_ref.shape[0]
-        prod = jnp.einsum("bpk,kn->bpn",
-                          patches_ref[...].astype(jnp.float32),
-                          wpc_ref[...].astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
-        u_scr[:, pl.ds(0, i_dim), :] += prod.reshape(bsz, i_dim, caps_dim)
+        w_t = wpc_ref[...].astype(jnp.float32).T               # [N, bk]
+        for b in range(pre_scr.shape[0]):
+            pre_scr[b] += jax.lax.dot_general(
+                w_t, patches_ref[b].astype(jnp.float32),
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)              # [N, P]
 
         @pl.when(t == k_steps - 1)
         def _():
-            pre = u_scr[:, pl.ds(0, i_dim), :]
-            bias = bias_ref[0].reshape(groups, caps_dim)
-            caps = (pre.reshape(bsz, p_pos, groups, caps_dim)
-                    + bias[None, None])
-            u_scr[:, pl.ds(0, i_dim), :] = squash(caps).reshape(
-                bsz, i_dim, caps_dim)
+            bsz = pre_scr.shape[0]
+            pre = pre_scr[...] + bias_ref[...][None]
+            caps = squash(pre.reshape(bsz, groups, caps_dim, p_pos), axis=2)
+            for g in range(groups):
+                u_scr[:, :, pl.ds(g * p_pos, p_pos)] = caps[:, g]
 
 
-def _pipe_resident_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref,
-                          u_scr, votes_scr, *, k_steps: int, p_pos: int,
-                          groups: int, caps_dim: int, i_dim: int, iters: int,
-                          j: int, d: int, n_blocks: int, block_i: int):
+def _pipe_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref, pre_scr,
+                 u_scr, b_scr, s_scr, v_scr, *votes, k_steps: int,
+                 p_pos: int, groups: int, caps_dim: int, n_passes: int,
+                 n_blocks: int, block_i: int, resident: bool):
+    """Grid ``k_steps - 1 + n_passes * n_blocks``.  The consume steps are
+    ``votes_routing._routing_kernel``'s, reading u from the produce
+    scratch instead of an HBM operand.  The first consume block OVERLAPS
+    the last produce step: u is fully squashed by the time the body
+    reaches it (in-body program order)."""
     t = pl.program_id(0)
-    _produce_u(t, patches_ref, wpc_ref, bias_ref, u_scr, k_steps=k_steps,
-               p_pos=p_pos, groups=groups, caps_dim=caps_dim, i_dim=i_dim)
+    _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr,
+               k_steps=k_steps, groups=groups, caps_dim=caps_dim,
+               p_pos=p_pos)
 
-    # The first consume block OVERLAPS the last produce step: u is fully
-    # squashed by the time the body reaches this point (in-body program
-    # order), so the grid is k_steps - 1 + n_blocks, not k_steps +
-    # n_blocks.
     @pl.when(t >= k_steps - 1)
     def _():
-        ib = t - (k_steps - 1)
-        rows = pl.ds(ib * block_i, block_i)
-        votes_scr[:, rows, :] = _votes_block(u_scr[:, rows, :], wcc_ref[...])
-
-        @pl.when(ib == n_blocks - 1)
-        def _():
-            bsz, i_pad, jd = votes_scr.shape
-            v = _routing_iterations(
-                votes_scr[...].reshape(bsz, i_pad, j, d), iters)
-            o_ref[...] = v.reshape(bsz, j * d).astype(o_ref.dtype)
-
-
-def _pipe_streamed_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref,
-                          u_scr, b_scr, s_scr, v_scr, *, k_steps: int,
-                          p_pos: int, groups: int, caps_dim: int, i_dim: int,
-                          j: int, d: int, n_blocks: int, block_i: int,
-                          n_passes: int):
-    """Consume steps are ``votes_routing._streamed_kernel``'s fused s+b
-    pass verbatim, with the votes block recomputed from the produce
-    scratch instead of an HBM u operand."""
-    t = pl.program_id(0)
-    _produce_u(t, patches_ref, wpc_ref, bias_ref, u_scr, k_steps=k_steps,
-               p_pos=p_pos, groups=groups, caps_dim=caps_dim, i_dim=i_dim)
-
-    @pl.when(t >= k_steps - 1)
-    def _():  # first consume pass overlaps the last produce step
         q = t - (k_steps - 1)
         p = q // n_blocks
         ib = q % n_blocks
-        rows = pl.ds(ib * block_i, block_i)
-        bsz = u_scr.shape[0]
-        uh4 = _votes_block(u_scr[:, rows, :],
-                           wcc_ref[...]).reshape(bsz, block_i, j, d)
+        rows = _rows(ib, block_i)
+        if resident:
+            votes_scr = votes[0]
 
-        @pl.when((p == 0) & (ib == 0))
-        def _():
-            b_scr[...] = jnp.zeros_like(b_scr)
-
-        @pl.when(p > 0)
-        def _():  # iteration p's logits update rides the same W stream
-            v = v_scr[...].reshape(bsz, j, d)
-            b_scr[:, rows, :] += jnp.einsum("bijd,bjd->bij", uh4, v)
-
-        @pl.when(ib == 0)
-        def _():
-            s_scr[...] = jnp.zeros_like(s_scr)
-
-        c = jax.nn.softmax(b_scr[:, rows, :], axis=2)
-        s_scr[...] += jnp.einsum("bij,bijd->bjd", c, uh4).reshape(bsz, j * d)
-
-        @pl.when(ib == n_blocks - 1)
-        def _():
-            v_scr[...] = squash(
-                s_scr[...].reshape(bsz, j, d)).reshape(bsz, j * d)
-
-            @pl.when(p == n_passes - 1)
+            @pl.when(p == 0)
             def _():
-                o_ref[...] = v_scr[...].astype(o_ref.dtype)
+                votes_scr[:, :, :, rows] = _CapsLanes.votes(
+                    u_scr[:, :, rows], wcc_ref[...])
+
+            uh4 = votes_scr[:, :, :, rows]
+        else:
+            uh4 = _CapsLanes.votes(u_scr[:, :, rows], wcc_ref[...])
+        _route_block(_CapsLanes, p, ib, uh4, rows, b_scr, s_scr, v_scr,
+                     o_ref, None, n_passes=n_passes, n_blocks=n_blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +154,7 @@ class _PRStatics(NamedTuple):
     conv_block_k: int
     conv_block_n: int
     interpret: bool
-    block_p: int | None = None   # im2col extraction row block (None = full)
+    bwd_lanes: str = "caps"  # routing backward layout (votes_routing)
 
 
 def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
@@ -211,9 +169,7 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
     j = st.num_classes
     d = jd // j
 
-    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride,
-                             block_p=st.block_p,
-                             interpret=st.interpret)          # [B, P, K]
+    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride)  # [B,P,K]
     wpc2 = w_pc.reshape(kk, n_ch)
     bk = max(1, min(st.block_k, kk))
     if kk % bk:                        # zero-pad K (conv_im2col idiom): a
@@ -225,63 +181,56 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
     block_i = max(1, min(st.block_i, i_dim))
     n_blocks = pl.cdiv(i_dim, block_i)
     i_pad = n_blocks * block_i
-    w_cc_p = (jnp.pad(w_cc, ((0, i_pad - i_dim), (0, 0), (0, 0)))
-              if i_pad != i_dim else w_cc)
-    bias2 = b_pc.reshape(1, n_ch)
-    out_shape = jax.ShapeDtypeStruct((bsz, jd), x.dtype)
-    common = dict(k_steps=k_steps, p_pos=p_pos, groups=groups,
-                  caps_dim=caps_dim, i_dim=i_dim, j=j, d=d,
-                  n_blocks=n_blocks, block_i=block_i)
-
-    # Produce-phase operands park on their final tile after step
-    # k_steps-1 (unchanged block index -> no refetch); W holds its first
-    # i-block until the consume steps start walking it.
-    patch_spec = pl.BlockSpec(
-        (bsz, p_pos, bk), lambda t: (0, 0, jnp.minimum(t, k_steps - 1)))
-    wpc_spec = pl.BlockSpec(
-        (bk, n_ch), lambda t: (jnp.minimum(t, k_steps - 1), 0))
-    bias_spec = pl.BlockSpec((1, n_ch), lambda t: (0, 0))
-    out_spec = pl.BlockSpec((bsz, jd), lambda t: (0, 0))
-
-    if st.mode == "resident":
-        kernel = functools.partial(_pipe_resident_kernel, iters=st.iters,
-                                   **common)
-        wcc_spec = pl.BlockSpec(
-            (block_i, jd, caps_dim),
-            lambda t: (jnp.clip(t - (k_steps - 1), 0, n_blocks - 1), 0, 0))
-        return pl.pallas_call(
-            kernel,
-            grid=(k_steps - 1 + n_blocks,),
-            in_specs=[patch_spec, wpc_spec, bias_spec, wcc_spec],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((bsz, i_pad, caps_dim), jnp.float32),  # u
-                pltpu.VMEM((bsz, i_pad, jd), jnp.float32),        # votes
-            ],
-            interpret=st.interpret,
-        )(patches, wpc2, bias2, w_cc_p)
-
+    # Capsule lanes in the order the produce phase writes them: group-
+    # major (i' = g*P + p), where the conv emits position-major rows
+    # (i = p*G + g).  Routing sums over i, so only W_cc's rows move.
+    w_t = w_cc.reshape(p_pos, groups, jd, caps_dim).transpose(3, 2, 1, 0)
+    w_t = jnp.pad(w_t.reshape(caps_dim, j, d, i_dim),
+                  ((0, 0),) * 3 + ((0, i_pad - i_dim),))
     n_passes = st.iters + 1
-    kernel = functools.partial(_pipe_streamed_kernel, n_passes=n_passes,
-                               **common)
-    wcc_spec = pl.BlockSpec(
-        (block_i, jd, caps_dim),
-        lambda t: (jnp.maximum(t - (k_steps - 1), 0) % n_blocks, 0, 0))
-    return pl.pallas_call(
+    resident = st.mode == "resident"
+    last_k = k_steps - 1
+
+    def consume_block(t):
+        q = jnp.maximum(t - last_k, 0)
+        if resident:     # W_cc parks on its last block after the votes pass
+            return jnp.minimum(q, n_blocks - 1)
+        return q % n_blocks
+
+    scratch = [
+        pltpu.VMEM((bsz, n_ch, p_pos), jnp.float32),       # pre-activation^T
+        pltpu.VMEM((bsz, caps_dim, i_pad), jnp.float32),   # u
+        pltpu.VMEM((bsz, j, i_pad), jnp.float32),          # logits b
+        pltpu.VMEM((bsz, j, d, 1), jnp.float32),           # s accumulator
+        pltpu.VMEM((bsz, j, d, 1), jnp.float32),           # squashed v
+    ]
+    if resident:
+        scratch.append(pltpu.VMEM((bsz, j, d, i_pad), jnp.float32))  # votes
+    kernel = functools.partial(_pipe_kernel, k_steps=k_steps, p_pos=p_pos,
+                               groups=groups, caps_dim=caps_dim,
+                               n_passes=n_passes, n_blocks=n_blocks,
+                               block_i=block_i, resident=resident)
+    # Produce-phase operands park on their final tile after step
+    # k_steps-1 (unchanged block index -> no refetch); W_cc holds its
+    # first i-block until the consume steps start walking it.
+    out = pl.pallas_call(
         kernel,
-        grid=(k_steps - 1 + n_passes * n_blocks,),
-        in_specs=[patch_spec, wpc_spec, bias_spec, wcc_spec],
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bsz, i_pad, caps_dim), jnp.float32),  # u
-            pltpu.VMEM((bsz, i_pad, j), jnp.float32),         # logits b
-            pltpu.VMEM((bsz, jd), jnp.float32),               # s accumulator
-            pltpu.VMEM((bsz, jd), jnp.float32),               # squashed v
+        grid=(last_k + n_passes * n_blocks,),
+        in_specs=[
+            pl.BlockSpec((bsz, p_pos, bk),
+                         lambda t: (0, 0, jnp.minimum(t, last_k))),
+            pl.BlockSpec((bk, n_ch), lambda t: (jnp.minimum(t, last_k), 0)),
+            pl.BlockSpec((n_ch, 1), lambda t: (0, 0)),
+            pl.BlockSpec((caps_dim, j, d, block_i),
+                         lambda t: (0, 0, 0, consume_block(t))),
         ],
+        out_specs=pl.BlockSpec((bsz, j, d, 1), lambda t: (0, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, j, d, 1), x.dtype),
+        scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=st.interpret,
-    )(patches, wpc2, bias2, w_cc_p)
+    )(patches, wpc2, b_pc.reshape(n_ch, 1), w_t)
+    return out.reshape(bsz, jd)
 
 
 def _pr_grad(st: _PRStatics, x, w_pc, b_pc, w_cc, g):
@@ -298,8 +247,7 @@ def _pr_grad(st: _PRStatics, x, w_pc, b_pc, w_cc, g):
     i_dim, jd, caps_dim = w_cc.shape
     groups = n_ch // caps_dim
 
-    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride,
-                             block_p=st.block_p, interpret=st.interpret)
+    patches = im2col_patches(x, kh=kh, kw=kw, stride=st.stride)
     p2 = patches.reshape(m, kk)
     wpc2 = w_pc.reshape(kk, n_ch)
     pre = matmul_bias_act(p2, wpc2, b_pc, block_m=st.conv_block_m,
@@ -312,7 +260,7 @@ def _pr_grad(st: _PRStatics, x, w_pc, b_pc, w_cc, g):
     vr_st = _VRStatics(iters=st.iters, num_classes=st.num_classes,
                        mode=st.bwd_mode, block_i=st.bwd_block_i,
                        bwd_mode=st.bwd_mode, bwd_block_i=st.bwd_block_i,
-                       interpret=st.interpret)
+                       interpret=st.interpret, bwd_lanes=st.bwd_lanes)
     du, dw_cc = _vr_grad(vr_st, u, w_cc, g.astype(jnp.float32))
 
     dpre = pull(du.reshape(m, groups, caps_dim))[0].reshape(m, n_ch)
@@ -326,8 +274,7 @@ def _pr_grad(st: _PRStatics, x, w_pc, b_pc, w_cc, g):
         block_m=st.conv_block_m, block_k=st.conv_block_n,
         block_n=st.conv_block_k, epilogue="none", interpret=st.interpret)
     dx = col2im_patches(dpatches.reshape(bsz, p_pos, kk), kh=kh, kw=kw,
-                        stride=st.stride, h=h, w=w_hw,
-                        block_p=st.block_p, interpret=st.interpret)
+                        stride=st.stride, h=h, w=w_hw)
     return (dx.astype(x.dtype), dw_pc.reshape(w_pc.shape).astype(w_pc.dtype),
             dbias, dw_cc.astype(w_cc.dtype))
 
@@ -353,17 +300,17 @@ _pr_core.defvjp(_pr_core_fwd, _pr_core_bwd)
 
 @functools.partial(jax.jit, static_argnames=(
     "stride", "iters", "num_classes", "mode", "block_i", "block_k",
-    "bwd_mode", "bwd_block_i", "conv_block_m", "conv_block_k",
-    "conv_block_n", "block_p", "interpret"))
+    "bwd_mode", "bwd_block_i", "bwd_lanes", "conv_block_m", "conv_block_k",
+    "conv_block_n", "interpret"))
 def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                          w_cc: jax.Array, *, stride: int = 2, iters: int = 3,
                          num_classes: int = 10, mode: str = "resident",
                          block_i: int = 128, block_k: int = 512,
                          bwd_mode: str | None = None,
                          bwd_block_i: int | None = None,
+                         bwd_lanes: str = "caps",
                          conv_block_m: int = 128, conv_block_k: int = 128,
-                         conv_block_n: int = 128, block_p: int | None = None,
-                         interpret: bool = True) -> jax.Array:
+                         conv_block_n: int = 128, interpret: bool) -> jax.Array:
     """x: [B, H, W, Cin] (Conv1 output), w_pc: [KH, KW, Cin, N] HWIO,
     b_pc: [N], w_cc: [I, J*D, C] -> v: [B, J*D].
 
@@ -378,7 +325,7 @@ def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
 
     Differentiable: the custom VJP replays the producer from patches and
     composes the per-op backward kernels (routing backward per
-    ``bwd_mode``/``bwd_block_i``, conv backward over the
+    ``bwd_mode``/``bwd_block_i``/``bwd_lanes``, conv backward over the
     ``conv_block_*`` tiles).
     """
     i_dim, jd, caps_dim = w_cc.shape
@@ -397,6 +344,9 @@ def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
             f"{oh * ow * (n_ch // caps_dim)}")
     if mode not in MODES:
         raise ValueError(f"unknown mode {mode!r}; choose from {MODES}")
+    if bwd_lanes not in LAYOUTS:
+        raise ValueError(f"unknown bwd_lanes {bwd_lanes!r}; choose from "
+                         f"{LAYOUTS}")
     if iters < 1:
         raise ValueError(f"routing needs iters >= 1, got {iters}")
     bwd_mode = bwd_mode or mode
@@ -406,5 +356,5 @@ def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                     bwd_block_i=max(1, min(bwd_block_i or block_i, i_dim)),
                     conv_block_m=conv_block_m, conv_block_k=conv_block_k,
                     conv_block_n=conv_block_n, interpret=interpret,
-                    block_p=block_p)
+                    bwd_lanes=bwd_lanes)
     return _pr_core(st, x, w_pc, b_pc, w_cc)

@@ -52,7 +52,7 @@ def _routing_kernel(uhat_ref, o_ref, *, iters: int, j: int, d: int):
 @functools.partial(jax.jit,
                    static_argnames=("iters", "num_classes", "interpret"))
 def routing(u_hat: jax.Array, *, iters: int = 3, num_classes: int = 10,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool) -> jax.Array:
     """u_hat: [B, I, J*D] -> v: [B, J*D]; fused dynamic routing."""
     bsz, i_dim, jd = u_hat.shape
     j = num_classes

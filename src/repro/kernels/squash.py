@@ -67,7 +67,7 @@ _squash_core.defvjp(_squash_core_fwd, _squash_core_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def squash(x: jax.Array, *, block_rows: int = 1024,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool) -> jax.Array:
     """x: [..., R, D]; squash along the last axis, blocked over R.
 
     Rows need not divide ``block_rows``: the grid is ``cdiv`` and the

@@ -14,67 +14,50 @@ with the routing state (logits ``b``, couplings ``c``, candidates
 
 The ExecutionPlan (``repro.core.execplan.plan_votes_routing``) chooses
 between two schedules per configuration -- the DESCNet-style
-per-configuration scratchpad decision:
+per-configuration scratchpad decision.  Both run the grid ``(iters + 1,
+num_i_blocks)`` with the routing state in scratch.  Pass ``t`` runs one
+WHOLE routing iteration: while accumulating ``s_t`` from a votes block it
+first folds in the logits update ``b_t = b_{t-1} + <u_hat, v_{t-1}>`` for
+the same rows, against the previous pass's ``v_{t-1}`` held in VMEM
+scratch.
 
-  resident  grid ``(num_i_blocks,)``.  Each step computes one i-block of
-            votes for the whole batch into a ``[B, I_pad, J*D]`` VMEM
-            scratch; the last step runs every routing iteration on-chip.
-            ``W`` and ``u`` are read exactly once.  Requires the full
-            votes tensor to fit VMEM.
+  resident  pass 0 computes each votes block into a VMEM scratch holding
+            the whole votes tensor and later passes read it back: ``W``
+            and ``u`` are read exactly once.  Requires the full votes
+            tensor to fit VMEM.
 
-  streamed  grid ``(iters + 1, num_i_blocks)``.  Only ``u`` (constant
-            index map: fetched once) and the routing state stay resident;
-            votes are recomputed from streamed ``W`` tiles on every pass.
-            Pass ``t`` runs one WHOLE routing iteration per ``W`` stream:
-            while accumulating ``s_t`` from the recomputed votes block it
-            first folds in the logits update ``b_t = b_{t-1} + <u_hat,
-            v_{t-1}>`` for the same rows, against the previous pass's
-            ``v_{t-1}`` held in VMEM scratch -- a one-iteration software
-            pipeline that halves the old separate-s-pass/b-pass traffic.
-            ``W`` is re-read ``iters + 1`` times -- the price of making
-            num_primary >> VMEM configurations feasible at all.  The
-            unfused two-pass schedule survives as ``mode="streamed-2pass"``
-            (never plan-chosen): the oracle the fused pass is
-            property-tested against.
+  streamed  every pass recomputes its votes block from a re-streamed
+            ``W`` tile, so ``W`` is read ``iters + 1`` times -- the price
+            of making num_primary >> VMEM configurations feasible at all.
 
-Both schedules zero-pad the capsule axis up to a multiple of ``block_i``
-(the ``conv_im2col`` K-axis idiom): a clamped ragged tail block would
-double-count rows under the i-reduction, while zero rows contribute
-nothing to ``s``, leave their logits at the uniform initialisation, and
-never perturb the real capsules.
+Every kernel step holds one votes block in registers, never the whole
+votes tensor.  The plan also picks which capsule axis lies on the
+128-lane axis (``lanes``, see ``_CapsLanes`` / ``_ClassLanes``):
+
+  "caps"     the input capsules I (u ``[B, C, I_pad]``, W ``[C, J, D,
+             I_pad]``, logits ``[B, J, I_pad]``): dense for the narrow
+             output layers of the classification heads, but ``block_i``
+             is a multiple of 128 or all of I and every class vector
+             ``[B, J, D, 1]`` pads one lane out to 128.
+
+  "classes"  the output capsules J (u ``[B, I_pad, C]`` streamed one
+             i-block per step like W, W ``[C, D, I_pad, J]``, logits
+             ``[B, I_pad, J]``, class vectors ``[B, D, 1, J]``): dense for
+             wide layers (a ResCaps half routes 1024 capsules into 1024),
+             and ``block_i`` only needs to be a multiple of 8, so one W
+             tile stays small however wide J*D is.
 
 **Backward** (``jax.custom_vjp``): the cotangent of the votes, ``d u_hat``
--- as large as ``u_hat`` itself -- never touches HBM either.  Both
-backward kernels recompute the routing iterations from the saved ``(u,
-W)`` residuals entirely in VMEM scratch, honoring the jnp reference's
-``stop_gradient(u_hat)`` convention (the logits updates and every s-sum
-but the last iteration's are u_hat-constant under ``jax.grad``):
-
-  resident  grid ``(2, num_i_blocks)``.  Pass 0 rebuilds the votes into
-            the same ``[B, I_pad, J*D]`` scratch the forward used and, at
-            the last i-block, replays every routing iteration on-chip and
-            overwrites the scratch with ``d u_hat`` in place (the exact
-            ``jax.vjp`` of the reference replay).  Pass 1 contracts each
-            ``d u_hat`` i-block against the streamed ``W``/``u`` tiles
-            into ``du`` / ``dW`` block outputs.
-
-  streamed  grid ``(iters + 4, num_i_blocks)``.  Passes ``0..T`` replay
-            the forward with the SAME fused s+b pass as the forward
-            kernel (one W stream per replayed iteration) over a ROLLING
-            pair of logits slabs (the stop-gradient convention means only
-            ``b_{T-1}`` / ``b_T`` are ever consumed again, so slot
-            ``t % 2`` suffices); pass ``T+1`` seeds ``db_T`` from the
-            output cotangent; pass ``T+2`` accumulates ``dv_{T-1} =
-            sum_i u_hat . db_T`` and squash-vjps it into ``ds_{T-1}``;
-            the final pass emits ``du``/``dW`` per i-block from
-            ``d u_hat = c_T (x) ds_T + c_{T-1} (x) ds_{T-1}`` without
-            ever materializing it beyond one i-block.  There is NO deep
-            reverse recurrence: with the logits updates u_hat-constant,
-            ``db_t`` for ``t < T`` feeds nothing -- the backward is
-            exactly one seed + one reverse pass, regardless of the
-            iteration count.  The unfused replay survives as
-            ``bwd_mode="streamed-2pass"`` (grid ``(2*iters + 4, ...)``),
-            the oracle for the fused replay's gradients.
+-- as large as ``u_hat`` itself -- never touches HBM either.  The
+backward kernel (grid ``(iters + 4, num_i_blocks)``, see
+``_routing_bwd_kernel``) recomputes the routing iterations from the saved
+``(u, W)`` residuals entirely in VMEM scratch, honoring the jnp
+reference's ``stop_gradient(u_hat)`` convention (the logits updates and
+every s-sum but the last iteration's are u_hat-constant under
+``jax.grad``): one forward replay, one seed pass, ONE reverse pass and an
+emit pass, regardless of the iteration count.  Resident keeps the
+rebuilt votes in scratch (``W`` read twice); streamed recomputes them
+from ``W`` on every pass (``iters + 4`` reads).
 """
 
 from __future__ import annotations
@@ -88,316 +71,445 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.capsnet import squash
+from repro.core.execplan import LAYOUTS
+from repro.core.planner import VMEM_LIMIT_BYTES
 
 MODES = ("resident", "streamed")        # plan-chooseable schedules
-ORACLE_MODE = "streamed-2pass"          # unfused streamed oracle (tests)
-ALL_MODES = MODES + (ORACLE_MODE,)
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _votes_block(u, w):
-    """u: [B, TI, C], w: [TI, N, C] -> u_hat block [B, TI, N] (fp32)."""
-    return jnp.einsum("bic,inc->bin", u.astype(jnp.float32),
-                      w.astype(jnp.float32),
-                      preferred_element_type=jnp.float32)
+# ---------------------------------------------------------------------------
+# Kernel layouts
+# ---------------------------------------------------------------------------
+
+class _CapsLanes:
+    """Input capsules I on the lanes.
+
+    The capsule axis I is the only long axis of a classification head
+    (1152 -> 10 x 16 on MNIST), so every buffer is dense under the (8,
+    128) tiling (C and D sit on sublanes, B and J lead), where the [B, I,
+    C] / [I, J*D, C] layouts would pad C=8 out to 128 lanes.  u stays
+    resident for the run (constant block index); votes blocks are [B, J,
+    D, TI], logits [B, J, I_pad], class vectors [B, J, D, 1]."""
+
+    name = "caps"
+    class_axis = 1          # J in logits / couplings blocks [B, J, TI]
+    dim_axis = 2            # D in votes [B, J, D, TI] / vectors [B, J, D, 1]
+
+    @staticmethod
+    def to_kernel(u, w, j: int, i_pad: int):
+        """u [B, I, C] -> [B, C, I_pad]; w [I, J*D, C] -> [C, J, D, I_pad].
+        I is zero-padded up to a multiple of ``block_i``: a clamped ragged
+        tail block would double-count rows under the i-reduction, while
+        zero capsules add nothing to ``s``, keep uniform logits, and never
+        perturb the real capsules."""
+        _, i_dim, c = u.shape
+        jd = w.shape[1]
+        pad = (0, i_pad - i_dim)
+        ut = jnp.pad(u.transpose(0, 2, 1), ((0, 0), (0, 0), pad))
+        wt = jnp.pad(w.transpose(2, 1, 0).reshape(c, j, jd // j, i_dim),
+                     ((0, 0), (0, 0), (0, 0), pad))
+        return ut, wt
+
+    @staticmethod
+    def u_spec(bsz, c, i_pad, block_i, i_index):
+        del block_i, i_index                    # resident: fetched once
+        return pl.BlockSpec((bsz, c, i_pad), lambda p, ib: (0, 0, 0))
+
+    @staticmethod
+    def w_spec(c, j, d, block_i, i_index):
+        return pl.BlockSpec((c, j, d, block_i),
+                            lambda p, ib: (0, 0, 0, i_index(p, ib)))
+
+    @staticmethod
+    def u_block(u_ref, rows):
+        return u_ref[:, :, rows]
+
+    @staticmethod
+    def vec_shape(bsz, j, d):
+        return (bsz, j, d, 1)
+
+    @staticmethod
+    def to_vec(x, j: int):
+        """[B, J*D] -> [B, J, D, 1]."""
+        return x.reshape(x.shape[0], j, x.shape[1] // j, 1)
+
+    @staticmethod
+    def from_vec(v):
+        return v.reshape(v.shape[0], -1)
+
+    @staticmethod
+    def logits_shape(bsz, j, i_pad):
+        return (bsz, j, i_pad)
+
+    @staticmethod
+    def votes_shape(bsz, j, d, i_pad):
+        return (bsz, j, d, i_pad)
+
+    @staticmethod
+    def b_idx(rows):
+        return (slice(None), slice(None), rows)
+
+    @staticmethod
+    def votes_idx(rows):
+        return (slice(None), slice(None), slice(None), rows)
+
+    @staticmethod
+    def votes(u, w):
+        """u [B, C, TI], w [C, J, D, TI] -> votes [B, J, D, TI] (fp32).
+
+        The contraction is only C deep and W differs per capsule, so
+        there is no matmul shape here: C broadcast multiply-adds on the
+        vector unit."""
+        bsz, c, ti = u.shape
+        u = u.astype(jnp.float32)
+        w = w.astype(jnp.float32)
+        acc = u[:, 0:1, :].reshape(bsz, 1, 1, ti) * w[0][None]
+        for k in range(1, c):
+            acc = acc + u[:, k:k + 1, :].reshape(bsz, 1, 1, ti) * w[k][None]
+        return acc
+
+    @staticmethod
+    def spread(c):
+        """Couplings [B, J, TI] onto the votes layout [B, J, 1, TI]."""
+        return c[:, :, None, :]
+
+    @staticmethod
+    def weighted_sum(c, uh):
+        """sum_i c[b, j, i] * uh[b, j, d, i] -> [B, J, D, 1]."""
+        return jnp.sum(_CapsLanes.spread(c) * uh, axis=3, keepdims=True)
+
+    @staticmethod
+    def agreement(uh, v):
+        """<u_hat, v> over the class dim: [B, J, D, TI] x [B, J, D, 1] ->
+        [B, J, TI]."""
+        return jnp.sum(uh * v, axis=2)
+
+    @staticmethod
+    def grad_specs(bsz, c, j, d, block_i, e_index):
+        return [pl.BlockSpec((bsz, c, block_i),
+                             lambda p, ib: (0, 0, e_index(p, ib))),
+                pl.BlockSpec((c, j, d, block_i),
+                             lambda p, ib: (0, 0, 0, e_index(p, ib)))]
+
+    @staticmethod
+    def grad_shapes(bsz, c, j, d, i_pad):
+        return [(bsz, c, i_pad), (c, j, d, i_pad)]
+
+    @staticmethod
+    def emit(duh, w_ref, u, du_ref, dw_ref):
+        """du [B, C, TI] and dW [C, J, D, TI] from d u_hat [B, J, D, TI]."""
+        w = w_ref[...].astype(jnp.float32)
+        u = u.astype(jnp.float32)
+        bsz, c, ti = u.shape
+        for k in range(c):
+            du_ref[:, k:k + 1, :] = jnp.sum(
+                jnp.sum(duh * w[k][None], axis=2, keepdims=True),
+                axis=1).astype(du_ref.dtype)                  # [B, 1, TI]
+            dw_ref[k] = jnp.sum(
+                duh * u[:, k:k + 1, :].reshape(bsz, 1, 1, ti),
+                axis=0).astype(dw_ref.dtype)                  # [J, D, TI]
+
+    @staticmethod
+    def from_grads(du, dw, i_dim: int):
+        c = du.shape[1]
+        du = du.transpose(0, 2, 1)[:, :i_dim]
+        dw = dw.reshape(c, -1, dw.shape[-1]).transpose(2, 1, 0)[:i_dim]
+        return du, dw
 
 
-def _routing_iterations(uh4, iters: int):
-    """All routing iterations on resident votes uh4 [B, I, J, D] -> v."""
-    bsz, i_dim, j, _ = uh4.shape
+class _ClassLanes:
+    """Output capsules J on the lanes.
 
-    def iteration(_, b):
-        c = jax.nn.softmax(b, axis=2)                 # couplings  [B, I, J]
-        v = squash(jnp.einsum("bij,bijd->bjd", c, uh4))
-        return b + jnp.einsum("bijd,bjd->bij", uh4, v)
+    For a wide routing layer (J*D = 8192 on a ResCaps half) one
+    128-capsule W tile of the caps-on-lanes layout is tens of MiB and
+    every class vector pads 128x; with J on the lanes the class vectors
+    [B, D, 1, J] and logits [B, I_pad, J] are dense, I sits on sublanes
+    (``block_i`` a multiple of 8), and a W tile [C, D, TI, J] stays a few
+    MiB.  u keeps its natural [B, I, C] layout and streams one i-block
+    per step alongside W, so a capsule's C components are lane columns
+    that broadcast across the J lanes."""
 
-    b = jax.lax.fori_loop(0, iters, iteration,
-                          jnp.zeros((bsz, i_dim, j), jnp.float32))
-    c = jax.nn.softmax(b, axis=2)
-    return squash(jnp.einsum("bij,bijd->bjd", c, uh4))  # [B, J, D]
+    name = "classes"
+    class_axis = 2          # J in logits / couplings blocks [B, TI, J]
+    dim_axis = 1            # D in votes [B, D, TI, J] / vectors [B, D, 1, J]
+
+    @staticmethod
+    def to_kernel(u, w, j: int, i_pad: int):
+        """u [B, I, C] -> [B, I_pad, C]; w [I, J*D, C] -> [C, D, I_pad, J]
+        (zero capsules pad I, as in the caps-on-lanes layout)."""
+        _, i_dim, c = u.shape
+        jd = w.shape[1]
+        pad = (0, i_pad - i_dim)
+        ut = jnp.pad(u, ((0, 0), pad, (0, 0)))
+        wt = jnp.pad(w.reshape(i_dim, j, jd // j, c).transpose(3, 2, 0, 1),
+                     ((0, 0), (0, 0), pad, (0, 0)))
+        return ut, wt
+
+    @staticmethod
+    def u_spec(bsz, c, i_pad, block_i, i_index):
+        del i_pad
+        return pl.BlockSpec((bsz, block_i, c),
+                            lambda p, ib: (0, i_index(p, ib), 0))
+
+    @staticmethod
+    def w_spec(c, j, d, block_i, i_index):
+        return pl.BlockSpec((c, d, block_i, j),
+                            lambda p, ib: (0, 0, i_index(p, ib), 0))
+
+    @staticmethod
+    def u_block(u_ref, rows):
+        del rows                                # the block IS the i-tile
+        return u_ref[...]
+
+    @staticmethod
+    def vec_shape(bsz, j, d):
+        return (bsz, d, 1, j)
+
+    @staticmethod
+    def to_vec(x, j: int):
+        """[B, J*D] -> [B, D, 1, J]."""
+        bsz, jd = x.shape
+        return x.reshape(bsz, j, jd // j).transpose(0, 2, 1)[:, :, None, :]
+
+    @staticmethod
+    def from_vec(v):
+        bsz, d, _, j = v.shape
+        return v.reshape(bsz, d, j).transpose(0, 2, 1).reshape(bsz, -1)
+
+    @staticmethod
+    def logits_shape(bsz, j, i_pad):
+        return (bsz, i_pad, j)
+
+    @staticmethod
+    def votes_shape(bsz, j, d, i_pad):
+        return (bsz, d, i_pad, j)
+
+    @staticmethod
+    def b_idx(rows):
+        return (slice(None), rows, slice(None))
+
+    @staticmethod
+    def votes_idx(rows):
+        return (slice(None), slice(None), rows, slice(None))
+
+    @staticmethod
+    def votes(u, w):
+        """u [B, TI, C], w [C, D, TI, J] -> votes [B, D, TI, J] (fp32):
+        C broadcast multiply-adds, each u lane column spread over J."""
+        c = u.shape[2]
+        u = u.astype(jnp.float32)
+        w = w.astype(jnp.float32)
+        acc = _ClassLanes.u_col(u, 0) * w[0][None]
+        for k in range(1, c):
+            acc = acc + _ClassLanes.u_col(u, k) * w[k][None]
+        return acc
+
+    @staticmethod
+    def u_col(u, k: int):
+        """Component ``k`` of a u block [B, TI, C] as [B, 1, TI, 1]."""
+        bsz, ti, _ = u.shape
+        return jax.lax.slice_in_dim(u, k, k + 1, axis=2).reshape(
+            bsz, 1, ti, 1)
+
+    @staticmethod
+    def spread(c):
+        """Couplings [B, TI, J] onto the votes layout [B, 1, TI, J]."""
+        return c.reshape(c.shape[0], 1, *c.shape[1:])
+
+    @staticmethod
+    def weighted_sum(c, uh):
+        """sum_i c[b, i, j] * uh[b, d, i, j] -> [B, D, 1, J]."""
+        return jnp.sum(_ClassLanes.spread(c) * uh, axis=2, keepdims=True)
+
+    @staticmethod
+    def agreement(uh, v):
+        """<u_hat, v> over the class dim: [B, D, TI, J] x [B, D, 1, J] ->
+        [B, TI, J]."""
+        return jnp.sum(uh * v, axis=1)
+
+    @staticmethod
+    def grad_specs(bsz, c, j, d, block_i, e_index):
+        return [pl.BlockSpec((c, bsz, block_i, 1),
+                             lambda p, ib: (0, 0, e_index(p, ib), 0)),
+                pl.BlockSpec((c, d, block_i, j),
+                             lambda p, ib: (0, 0, e_index(p, ib), 0))]
+
+    @staticmethod
+    def grad_shapes(bsz, c, j, d, i_pad):
+        return [(c, bsz, i_pad, 1), (c, d, i_pad, j)]
+
+    @staticmethod
+    def emit(duh, w_ref, u, du_ref, dw_ref):
+        """du [C, B, TI, 1] and dW [C, D, TI, J] from d u_hat [B, D, TI,
+        J]: per capsule component, a lane reduction and a batch sum."""
+        w = w_ref[...].astype(jnp.float32)
+        u = u.astype(jnp.float32)
+        for k in range(u.shape[2]):
+            du_ref[k] = jnp.sum(
+                jnp.sum(duh * w[k][None], axis=1), axis=-1,
+                keepdims=True).astype(du_ref.dtype)           # [B, TI, 1]
+            dw_ref[k] = jnp.sum(duh * _ClassLanes.u_col(u, k),
+                                axis=0).astype(dw_ref.dtype)  # [D, TI, J]
+
+    @staticmethod
+    def from_grads(du, dw, i_dim: int):
+        c, _, i_pad, _ = dw.shape
+        du = du.reshape(c, -1, i_pad).transpose(1, 2, 0)[:, :i_dim]
+        dw = dw.transpose(2, 3, 1, 0)[:i_dim]
+        return du, dw.reshape(dw.shape[0], -1, c)
 
 
-def _resident_kernel(u_ref, w_ref, *refs, iters: int, j: int,
-                     d: int, n_blocks: int, block_i: int,
-                     residual: bool = False):
-    r_ref = refs[0] if residual else None   # residual-add epilogue operand
-    o_ref, votes_scr = refs[-2], refs[-1]
-    ib = pl.program_id(0)
-    votes_scr[:, pl.ds(ib * block_i, block_i), :] = _votes_block(
-        u_ref[...], w_ref[...])
-
-    @pl.when(ib == n_blocks - 1)
-    def _():
-        bsz, i_pad, jd = votes_scr.shape
-        v = _routing_iterations(votes_scr[...].reshape(bsz, i_pad, j, d),
-                                iters)
-        out = v.reshape(bsz, j * d)
-        if residual:
-            out = out + r_ref[...].astype(jnp.float32)
-        o_ref[...] = out.astype(o_ref.dtype)
+_LAYOUT = {"caps": _CapsLanes, "classes": _ClassLanes}
 
 
-def _streamed_kernel(u_ref, w_ref, *refs, iters: int,
-                     j: int, d: int, n_blocks: int, block_i: int,
-                     n_passes: int, residual: bool = False):
-    """Fused s+b pass: iteration ``t`` streams ``W`` exactly once.
+def _couplings(lay, b):
+    """Routing couplings: softmax of a logits block over the classes."""
+    return jax.nn.softmax(b, axis=lay.class_axis)
 
-    Before accumulating ``s_t`` from the recomputed votes block, the same
-    block first applies the logits update ``b_t[rows] = b_{t-1}[rows] +
-    <u_hat, v_{t-1}>`` against the previous pass's ``v`` in scratch -- a
-    one-iteration software pipeline (pass 0 starts from the zero logits,
-    so its update is skipped).  ``n_passes = iters + 1``: the last pass
-    is the final readout.
-    """
-    del iters  # folded into n_passes = iters + 1
-    r_ref = refs[0] if residual else None   # residual-add epilogue operand
-    o_ref, b_scr, s_scr, v_scr = refs[-4], refs[-3], refs[-2], refs[-1]
-    t = pl.program_id(0)
-    ib = pl.program_id(1)
-    rows = pl.ds(ib * block_i, block_i)
-    bsz = u_ref.shape[0]
-    uh4 = _votes_block(u_ref[:, rows, :],
-                       w_ref[...]).reshape(bsz, block_i, j, d)
 
-    @pl.when((t == 0) & (ib == 0))
+def _squash_caps(lay, s):
+    """Squash class capsules over their D axis."""
+    return squash(s, axis=lay.dim_axis)
+
+
+def _rows(ib, block_i: int):
+    return pl.ds(pl.multiple_of(ib * block_i, block_i), block_i)
+
+
+def _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
+                 *, n_passes: int, n_blocks: int):
+    """One (pass, i-block) step of the fused single-stream routing.
+
+    Pass ``t`` runs one WHOLE routing iteration: before accumulating
+    ``s_t`` from this block's votes it folds in the logits update ``b_t =
+    b_{t-1} + <u_hat, v_{t-1}>`` for the same rows against the previous
+    pass's ``v`` in scratch (pass 0 starts from zero logits).  The last
+    pass is the readout; ``r_ref`` (optional) is a residual added to it
+    just before the store -- ``v_scr`` itself stays pure v."""
+    b_rows = lay.b_idx(rows)
+
+    @pl.when((p == 0) & (ib == 0))
     def _():
         b_scr[...] = jnp.zeros_like(b_scr)
 
-    @pl.when(t > 0)
-    def _():  # fold iteration t's logits update into the same W stream
-        v = v_scr[...].reshape(bsz, j, d)
-        b_scr[:, rows, :] += jnp.einsum("bijd,bjd->bij", uh4, v)
+    @pl.when(p > 0)
+    def _():
+        b_scr[b_rows] += lay.agreement(uh4, v_scr[...])
 
     @pl.when(ib == 0)
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    c = jax.nn.softmax(b_scr[:, rows, :], axis=2)
-    s_scr[...] += jnp.einsum("bij,bijd->bjd", c, uh4).reshape(bsz, j * d)
+    s_scr[...] += lay.weighted_sum(_couplings(lay, b_scr[b_rows]), uh4)
 
     @pl.when(ib == n_blocks - 1)
     def _():
-        v_scr[...] = squash(s_scr[...].reshape(bsz, j, d)).reshape(bsz, j * d)
+        v_scr[...] = _squash_caps(lay, s_scr[...])
 
-        @pl.when(t == n_passes - 1)
+        @pl.when(p == n_passes - 1)
         def _():
             out = v_scr[...]
-            if residual:       # epilogue only: v_scr itself stays pure v
+            if r_ref is not None:
                 out = out + r_ref[...].astype(jnp.float32)
             o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _streamed_2pass_kernel(u_ref, w_ref, *refs,
-                           iters: int, j: int, d: int, n_blocks: int,
-                           block_i: int, n_passes: int,
-                           residual: bool = False):
-    """Unfused streamed schedule (``mode="streamed-2pass"``): one s-pass
-    plus one b-pass per iteration, ``W`` re-read ``2*iters + 1`` times.
-    Never plan-chosen -- kept as the oracle the fused pass is tested
-    against."""
-    del iters  # folded into n_passes = 2*iters + 1
-    r_ref = refs[0] if residual else None   # residual-add epilogue operand
-    o_ref, b_scr, s_scr, v_scr = refs[-4], refs[-3], refs[-2], refs[-1]
+def _routing_kernel(u_ref, w_ref, *refs, lanes: str, n_passes: int,
+                    n_blocks: int, block_i: int, resident: bool,
+                    residual: bool):
+    """Fused votes + routing, grid ``(iters + 1, n_blocks)``.
+
+    Resident: pass 0 computes each votes block into the votes scratch and
+    every later pass reads it back, so ``W`` is fetched once (its block
+    index parks after pass 0).  Streamed: every pass recomputes the votes
+    block from a re-streamed ``W`` tile."""
+    lay = _LAYOUT[lanes]
+    r_ref = refs[0] if residual else None
+    o_ref = refs[1 if residual else 0]
+    b_scr, s_scr, v_scr = refs[-4:-1] if resident else refs[-3:]
     p = pl.program_id(0)
     ib = pl.program_id(1)
-    row0 = ib * block_i
-    bsz = u_ref.shape[0]
-    uh4 = _votes_block(u_ref[:, pl.ds(row0, block_i), :],
-                       w_ref[...]).reshape(bsz, block_i, j, d)
+    rows = _rows(ib, block_i)
+    if resident:
+        votes_scr = refs[-1]
 
-    @pl.when((p == 0) & (ib == 0))
-    def _():
-        b_scr[...] = jnp.zeros_like(b_scr)
-
-    @pl.when(p % 2 == 0)
-    def _():  # s-pass: accumulate s over i-blocks, squash at the last one
-        @pl.when(ib == 0)
+        @pl.when(p == 0)
         def _():
-            s_scr[...] = jnp.zeros_like(s_scr)
+            votes_scr[lay.votes_idx(rows)] = lay.votes(
+                lay.u_block(u_ref, rows), w_ref[...])
 
-        c = jax.nn.softmax(b_scr[:, pl.ds(row0, block_i), :], axis=2)
-        s_scr[...] += jnp.einsum("bij,bijd->bjd", c, uh4).reshape(bsz, j * d)
-
-        @pl.when(ib == n_blocks - 1)
-        def _():
-            v_scr[...] = squash(
-                s_scr[...].reshape(bsz, j, d)).reshape(bsz, j * d)
-
-            @pl.when(p == n_passes - 1)
-            def _():
-                out = v_scr[...]
-                if residual:   # epilogue only: v_scr itself stays pure v
-                    out = out + r_ref[...].astype(jnp.float32)
-                o_ref[...] = out.astype(o_ref.dtype)
-
-    @pl.when(p % 2 == 1)
-    def _():  # b-pass: logits update from the recomputed votes + resident v
-        v = v_scr[...].reshape(bsz, j, d)
-        b_scr[:, pl.ds(row0, block_i), :] += jnp.einsum(
-            "bijd,bjd->bij", uh4, v)
+        uh4 = votes_scr[lay.votes_idx(rows)]
+    else:
+        uh4 = lay.votes(lay.u_block(u_ref, rows), w_ref[...])
+    _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
+                 n_passes=n_passes, n_blocks=n_blocks)
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels: d u_hat stays in VMEM scratch, like u_hat itself
+# Backward kernel: d u_hat stays in VMEM, like u_hat itself
 # ---------------------------------------------------------------------------
 
-def _routing_ref_sg(uh4, *, iters: int):
-    """Gradient-faithful replay of ``capsnet.routing_by_agreement``.
-
-    Values match ``_routing_iterations``; under ``jax.vjp`` it honors the
-    reference's ``stop_gradient(u_hat)`` convention: the logits update is
-    always u_hat-constant, and the s-sum carries u_hat gradient only on
-    the LAST body iteration (plus the final readout).
-    """
-    uh_ng = jax.lax.stop_gradient(uh4)
-    b = jnp.zeros(uh4.shape[:3], jnp.float32)
-    for it in range(iters):
-        c = jax.nn.softmax(b, axis=2)
-        u_used = uh4 if it == iters - 1 else uh_ng
-        v = squash(jnp.einsum("bij,bijd->bjd", c, u_used))
-        b = b + jnp.einsum("bijd,bjd->bij", uh_ng, v)
-    c = jax.nn.softmax(b, axis=2)
-    return squash(jnp.einsum("bij,bijd->bjd", c, uh4))
+def _softmax_bwd(lay, c, dc):
+    """VJP of the class softmax given its OUTPUT c (a logits block)."""
+    return c * (dc - jnp.sum(c * dc, axis=lay.class_axis, keepdims=True))
 
 
-def _softmax_bwd(c, dc):
-    """VJP of softmax over the class axis given its OUTPUT c."""
-    return c * (dc - jnp.sum(c * dc, axis=2, keepdims=True))
-
-
-def _squash_bwd(s, dv):
-    """VJP of the reference squash at pre-activation s."""
-    _, pull = jax.vjp(squash, s)
+def _squash_bwd(lay, s, dv):
+    """VJP of the capsule squash at pre-activation s."""
+    _, pull = jax.vjp(functools.partial(_squash_caps, lay), s)
     return pull(dv)[0]
 
 
-def _resident_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, votes_scr, *,
-                         iters: int, j: int, d: int, n_blocks: int,
-                         block_i: int):
-    p = pl.program_id(0)
-    ib = pl.program_id(1)
+def _routing_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr, s2_scr,
+                        acc_scr, v_scr, *votes, lanes: str, iters: int,
+                        n_blocks: int, block_i: int, resident: bool):
+    """Grid ``(iters + 4, n_blocks)``.  Passes ``0..T`` replay the forward
+    (the same fused s+b pass) over a ROLLING pair of logits slabs: the
+    reference's ``stop_gradient(u_hat)`` convention means only ``b_{T-1}``
+    / ``b_T`` are ever consumed again, so slot ``t % 2`` suffices.  Pass
+    ``T+1`` seeds ``ds_T`` from the output cotangent (over ``s_T`` in the
+    s pair, which nothing reads again; ``ds_{T-1}`` likewise replaces
+    ``s_{T-1}``); pass ``T+2``
+    rebuilds ``db_T`` block by block (so no logits-sized ``db`` slab is
+    held), accumulates ``dv_{T-1} = sum_i u_hat . db_T`` and squash-vjps
+    it into ``ds_{T-1}``; pass ``T+3`` emits du / dW per i-block from
+    ``d u_hat = c_T (x) ds_T + c_{T-1} (x) ds_{T-1}``, never materialized
+    beyond one i-block.  There is NO deep reverse recurrence: with the
+    logits updates u_hat-constant, ``db_t`` for ``t < T`` feeds nothing.
 
-    @pl.when(p == 0)
-    def _():  # rebuild the votes, then overwrite them with d u_hat in place
-        votes_scr[:, pl.ds(ib * block_i, block_i), :] = _votes_block(
-            u_ref[...], w_ref[...])
-
-        @pl.when(ib == n_blocks - 1)
-        def _():
-            bsz, i_pad, jd = votes_scr.shape
-            uh4 = votes_scr[...].reshape(bsz, i_pad, j, d)
-            _, pull = jax.vjp(
-                functools.partial(_routing_ref_sg, iters=iters), uh4)
-            duh = pull(g_ref[...].astype(jnp.float32).reshape(bsz, j, d))[0]
-            votes_scr[...] = duh.reshape(bsz, i_pad, jd)
-
-    @pl.when(p == 1)
-    def _():  # contract each d u_hat i-block against the streamed tiles
-        duh = votes_scr[:, pl.ds(ib * block_i, block_i), :]
-        du_ref[...] = jnp.einsum(
-            "bin,inc->bic", duh, w_ref[...].astype(jnp.float32)
-        ).astype(du_ref.dtype)
-        dw_ref[...] = jnp.einsum(
-            "bin,bic->inc", duh, u_ref[...].astype(jnp.float32)
-        ).astype(dw_ref.dtype)
-
-
-def _streamed_bwd_tail(p, ib, first_pass, rows, uh4, u_blk, w_ref, g_ref,
-                       du_ref, dw_ref, b2_scr, s2_scr, db_scr, ds_last_scr,
-                       ds_prev_scr, acc_scr, *, slot_last: int,
-                       slot_prev: int, j: int, d: int, n_blocks: int,
-                       block_i: int):
-    """Seed / reverse / emit passes shared by BOTH streamed backward
-    replays (fused and the 2-pass oracle) -- only the index of the first
-    tail pass differs between them.  The three blocks are the
-    gradient-critical core of the streamed backward, so they exist once."""
-    bsz = u_blk.shape[0]
-
-    # ---- seed (first_pass): ds_T from the cotangent, db_T ----
-    @pl.when(p == first_pass)
-    def _():
-        @pl.when(ib == 0)
-        def _():
-            ds = _squash_bwd(
-                s2_scr[pl.ds(slot_last, 1)][0].reshape(bsz, j, d),
-                g_ref[...].astype(jnp.float32).reshape(bsz, j, d))
-            ds_last_scr[...] = ds.reshape(bsz, j * d)
-
-        ds = ds_last_scr[...].reshape(bsz, j, d)
-        dc = jnp.einsum("bijd,bjd->bij", uh4, ds)
-        c = jax.nn.softmax(b2_scr[pl.ds(slot_last, 1), :, rows, :][0],
-                           axis=2)
-        db_scr[:, rows, :] = _softmax_bwd(c, dc)
-
-    # ---- one reverse pass (+1): dv_{T-1} = sum_i u_hat . db_T ----
-    @pl.when(p == first_pass + 1)
-    def _():
-        @pl.when(ib == 0)
-        def _():
-            acc_scr[...] = jnp.zeros_like(acc_scr)
-
-        acc_scr[...] += jnp.einsum("bijd,bij->bjd", uh4,
-                                   db_scr[:, rows, :]).reshape(bsz, j * d)
-
-        @pl.when(ib == n_blocks - 1)
-        def _():
-            ds = _squash_bwd(s2_scr[pl.ds(slot_prev, 1)][0].reshape(bsz, j, d),
-                             acc_scr[...].reshape(bsz, j, d))
-            ds_prev_scr[...] = ds.reshape(bsz, j * d)
-
-    # ---- emit (+2): d u_hat one i-block at a time -> du, dW ----
-    @pl.when(p == first_pass + 2)
-    def _():
-        c_last = jax.nn.softmax(
-            b2_scr[pl.ds(slot_last, 1), :, rows, :][0], axis=2)
-        c_prev = jax.nn.softmax(
-            b2_scr[pl.ds(slot_prev, 1), :, rows, :][0], axis=2)
-        ds_last = ds_last_scr[...].reshape(bsz, j, d)
-        ds_prev = ds_prev_scr[...].reshape(bsz, j, d)
-        duh = (c_last[..., None] * ds_last[:, None]
-               + c_prev[..., None] * ds_prev[:, None]).reshape(
-                   bsz, block_i, j * d)
-        du_ref[...] = jnp.einsum(
-            "bin,inc->bic", duh, w_ref[...].astype(jnp.float32)
-        ).astype(du_ref.dtype)
-        dw_ref[...] = jnp.einsum(
-            "bin,bic->inc", duh, u_blk.astype(jnp.float32)
-        ).astype(dw_ref.dtype)
-
-
-def _streamed_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr,
-                         s2_scr, db_scr, ds_last_scr, ds_prev_scr, acc_scr,
-                         v_scr, *, iters: int, j: int, d: int,
-                         n_blocks: int, block_i: int):
+    Resident rebuilds the votes once into scratch (pass 0) and reads them
+    back, so ``W`` crosses HBM twice (rebuild + emit); streamed recomputes
+    the votes block from a re-streamed ``W`` tile on every pass."""
+    lay = _LAYOUT[lanes]
     t_total = iters
     p = pl.program_id(0)
     ib = pl.program_id(1)
-    row0 = ib * block_i
-    rows = pl.ds(row0, block_i)
-    bsz = u_ref.shape[0]
-    u_blk = u_ref[:, rows, :]
-    uh4 = _votes_block(u_blk, w_ref[...]).reshape(bsz, block_i, j, d)
+    rows = _rows(ib, block_i)
+    b_rows = lay.b_idx(rows)
+    u_blk = lay.u_block(u_ref, rows)
+    if resident:
+        votes_scr = votes[0]
 
-    # Only b_{T-1}/b_T and s_{T-1}/s_T are ever consumed again (the
-    # stop-gradient convention kills the deeper reverse chain), so the
-    # replay keeps a rolling PAIR of slabs indexed by t % 2: pass t
-    # overwrites slot t % 2 = b_{t-2}, which is already dead.
+        @pl.when(p == 0)
+        def _():
+            votes_scr[lay.votes_idx(rows)] = lay.votes(u_blk, w_ref[...])
+
+        uh4 = votes_scr[lay.votes_idx(rows)]
+    else:
+        uh4 = lay.votes(u_blk, w_ref[...])
     slot_last = t_total % 2
     slot_prev = (t_total - 1) % 2
 
-    # ---- fused forward replay (passes 0 .. T): one W stream per
-    # iteration, the logits update folded into the s-pass exactly like
-    # the forward kernel -- b_t = b_{t-1} + <u_hat, v_{t-1}> lands in
-    # slot t % 2 before the same rows feed iteration t's softmax ----
+    # ---- forward replay (passes 0 .. T) ----
     @pl.when((p == 0) & (ib == 0))
     def _():
-        b2_scr[pl.ds(0, 1)] = jnp.zeros_like(b2_scr[pl.ds(0, 1)])
+        b2_scr[0] = jnp.zeros_like(b2_scr[0])
 
     @pl.when((p >= 1) & (p <= t_total))
-    def _():  # iteration p's logits update rides this pass's W stream
-        b_prev = b2_scr[pl.ds((p - 1) % 2, 1), :, rows, :][0]
-        v = v_scr[...].reshape(bsz, j, d)
-        b2_scr[pl.ds(p % 2, 1), :, rows, :] = (
-            b_prev + jnp.einsum("bijd,bjd->bij", uh4, v))[None]
+    def _():  # iteration p's logits update rides this pass
+        b2_scr[(p % 2,) + b_rows] = (b2_scr[((p - 1) % 2,) + b_rows]
+                                     + lay.agreement(uh4, v_scr[...]))
 
     @pl.when(p <= t_total)
     def _():  # s-pass of iteration p (p == T is the final readout)
@@ -405,81 +517,44 @@ def _streamed_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr,
         def _():
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        c = jax.nn.softmax(b2_scr[pl.ds(p % 2, 1), :, rows, :][0], axis=2)
-        acc_scr[...] += jnp.einsum("bij,bijd->bjd", c, uh4).reshape(bsz,
-                                                                    j * d)
+        acc_scr[...] += lay.weighted_sum(
+            _couplings(lay, b2_scr[(p % 2,) + b_rows]), uh4)
 
         @pl.when(ib == n_blocks - 1)
         def _():
-            s2_scr[pl.ds(p % 2, 1)] = acc_scr[...][None]
-            v_scr[...] = squash(
-                acc_scr[...].reshape(bsz, j, d)).reshape(bsz, j * d)
+            s2_scr[p % 2] = acc_scr[...]
+            v_scr[...] = _squash_caps(lay, acc_scr[...])
 
-    # ---- seed / reverse / emit (passes T+1 .. T+3) ----
-    _streamed_bwd_tail(p, ib, t_total + 1, rows, uh4, u_blk, w_ref, g_ref,
-                       du_ref, dw_ref, b2_scr, s2_scr, db_scr, ds_last_scr,
-                       ds_prev_scr, acc_scr, slot_last=slot_last,
-                       slot_prev=slot_prev, j=j, d=d, n_blocks=n_blocks,
-                       block_i=block_i)
-
-
-def _streamed_2pass_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr,
-                               s2_scr, db_scr, ds_last_scr, ds_prev_scr,
-                               acc_scr, v_scr, *, iters: int, j: int, d: int,
-                               n_blocks: int, block_i: int):
-    """Unfused streamed backward (``bwd_mode="streamed-2pass"``): the
-    forward replay runs separate s- and b-passes (grid ``(2*iters + 4,
-    num_i_blocks)``).  Never plan-chosen -- the oracle the fused replay's
-    gradients are tested against."""
-    t_total = iters
-    p = pl.program_id(0)
-    ib = pl.program_id(1)
-    row0 = ib * block_i
-    rows = pl.ds(row0, block_i)
-    bsz = u_ref.shape[0]
-    u_blk = u_ref[:, rows, :]
-    uh4 = _votes_block(u_blk, w_ref[...]).reshape(bsz, block_i, j, d)
-
-    slot_last = t_total % 2
-    slot_prev = (t_total - 1) % 2
-
-    # ---- forward replay (passes 0 .. 2T) ----
-    t_fwd = p // 2
-
-    @pl.when((p == 0) & (ib == 0))
+    # ---- seed (T+1): ds_T from the cotangent, over s_T ----
+    @pl.when((p == t_total + 1) & (ib == 0))
     def _():
-        b2_scr[pl.ds(0, 1)] = jnp.zeros_like(b2_scr[pl.ds(0, 1)])
+        s2_scr[slot_last] = _squash_bwd(lay, s2_scr[slot_last],
+                                        g_ref[...].astype(jnp.float32))
 
-    @pl.when((p <= 2 * t_total) & (p % 2 == 0))
-    def _():  # s-pass of iteration t_fwd (t_fwd == T is the final readout)
+    # ---- one reverse pass (T+2): dv_{T-1} = sum_i u_hat . db_T ----
+    @pl.when(p == t_total + 2)
+    def _():
         @pl.when(ib == 0)
         def _():
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        c = jax.nn.softmax(b2_scr[pl.ds(t_fwd % 2, 1), :, rows, :][0],
-                           axis=2)
-        acc_scr[...] += jnp.einsum("bij,bijd->bjd", c, uh4).reshape(bsz,
-                                                                    j * d)
+        db = _softmax_bwd(lay, _couplings(lay, b2_scr[(slot_last,) + b_rows]),
+                          lay.agreement(uh4, s2_scr[slot_last]))
+        acc_scr[...] += lay.weighted_sum(db, uh4)
 
         @pl.when(ib == n_blocks - 1)
-        def _():
-            s2_scr[pl.ds(t_fwd % 2, 1)] = acc_scr[...][None]
-            v_scr[...] = squash(
-                acc_scr[...].reshape(bsz, j, d)).reshape(bsz, j * d)
+        def _():  # ds_{T-1}, over s_{T-1}
+            s2_scr[slot_prev] = _squash_bwd(lay, s2_scr[slot_prev],
+                                            acc_scr[...])
 
-    @pl.when((p <= 2 * t_total) & (p % 2 == 1))
-    def _():  # b-pass: b_{t+1} = b_t + <u_hat, v_t>, into the other slot
-        b_blk = b2_scr[pl.ds(t_fwd % 2, 1), :, rows, :][0]
-        v = v_scr[...].reshape(bsz, j, d)
-        b2_scr[pl.ds((t_fwd + 1) % 2, 1), :, rows, :] = (
-            b_blk + jnp.einsum("bijd,bjd->bij", uh4, v))[None]
-
-    # ---- seed / reverse / emit (passes 2T+1 .. 2T+3) ----
-    _streamed_bwd_tail(p, ib, 2 * t_total + 1, rows, uh4, u_blk, w_ref,
-                       g_ref, du_ref, dw_ref, b2_scr, s2_scr, db_scr,
-                       ds_last_scr, ds_prev_scr, acc_scr,
-                       slot_last=slot_last, slot_prev=slot_prev, j=j, d=d,
-                       n_blocks=n_blocks, block_i=block_i)
+    # ---- emit (T+3): d u_hat one i-block at a time -> du, dW ----
+    @pl.when(p == t_total + 3)
+    def _():
+        c_last = _couplings(lay, b2_scr[(slot_last,) + b_rows])
+        c_prev = _couplings(lay, b2_scr[(slot_prev,) + b_rows])
+        duh = (lay.spread(c_last) * s2_scr[slot_last]
+               + lay.spread(c_prev) * s2_scr[slot_prev])
+        lay.emit(duh, w_ref, u_blk, du_ref, dw_ref)
 
 
 # ---------------------------------------------------------------------------
@@ -496,16 +571,8 @@ class _VRStatics(NamedTuple):
     bwd_mode: str
     bwd_block_i: int
     interpret: bool
-
-
-def _padded(u, w, block_i: int):
-    bsz, i_dim, c = u.shape
-    n_blocks = pl.cdiv(i_dim, block_i)
-    i_pad = n_blocks * block_i
-    if i_pad != i_dim:                     # zero-pad the reduction axis: a
-        u = jnp.pad(u, ((0, 0), (0, i_pad - i_dim), (0, 0)))   # clamped tail
-        w = jnp.pad(w, ((0, i_pad - i_dim), (0, 0), (0, 0)))   # would double-
-    return u, w, n_blocks, i_pad                               # count rows
+    lanes: str = "caps"
+    bwd_lanes: str = "caps"
 
 
 def _vr_apply(st: _VRStatics, u, w, r=None):
@@ -513,139 +580,113 @@ def _vr_apply(st: _VRStatics, u, w, r=None):
     the routed output just before the store -- the ResCapsBlock coupling
     epilogue; it rides the kernel's output block, never a separate pass."""
     bsz, i_dim, c = u.shape
-    _, jd, _ = w.shape
+    jd = w.shape[1]
     j = st.num_classes
     d = jd // j
-    u, w, n_blocks, i_pad = _padded(u, w, st.block_i)
-    out_shape = jax.ShapeDtypeStruct((bsz, jd), u.dtype)
+    lay = _LAYOUT[st.lanes]
+    block_i = st.block_i
+    n_blocks = pl.cdiv(i_dim, block_i)
+    i_pad = n_blocks * block_i
+    ut, wt = lay.to_kernel(u, w, j, i_pad)
+    resident = st.mode == "resident"
     residual = r is not None
-    operands = (u, w, r) if residual else (u, w)
-
-    if st.mode == "resident":
-        kernel = functools.partial(_resident_kernel, iters=st.iters, j=j,
-                                   d=d, n_blocks=n_blocks,
-                                   block_i=st.block_i, residual=residual)
-        in_specs = [
-            pl.BlockSpec((bsz, st.block_i, c), lambda ib: (0, ib, 0)),
-            pl.BlockSpec((st.block_i, jd, c), lambda ib: (ib, 0, 0)),
-        ]
-        if residual:
-            in_specs.append(pl.BlockSpec((bsz, jd), lambda ib: (0, 0)))
-        return pl.pallas_call(
-            kernel,
-            grid=(n_blocks,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bsz, jd), lambda ib: (0, 0)),
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((bsz, i_pad, jd), jnp.float32)],
-            interpret=st.interpret,
-        )(*operands)
-
-    if st.mode == ORACLE_MODE:          # unfused oracle: s+b passes split
-        n_passes = 2 * st.iters + 1
-        body = _streamed_2pass_kernel
-    else:                               # fused: one W stream per iteration
-        n_passes = st.iters + 1
-        body = _streamed_kernel
-    kernel = functools.partial(body, iters=st.iters, j=j, d=d,
-                               n_blocks=n_blocks, block_i=st.block_i,
-                               n_passes=n_passes, residual=residual)
-    in_specs = [
-        # u: constant index map -> fetched once, resident for the run
-        pl.BlockSpec((bsz, i_pad, c), lambda p, ib: (0, 0, 0)),
-        # W: re-streamed every pass (the votes are recomputed on-chip)
-        pl.BlockSpec((st.block_i, jd, c), lambda p, ib: (ib, 0, 0)),
-    ]
+    n_passes = st.iters + 1
+    vec_shape = lay.vec_shape(bsz, j, d)
+    vec = pl.BlockSpec(vec_shape, lambda p, ib: (0, 0, 0, 0))
+    if resident:      # W (and a streamed u) park after the votes pass
+        def i_index(p, ib):
+            return jnp.where(p == 0, ib, n_blocks - 1)
+    else:             # re-streamed every pass
+        def i_index(p, ib):
+            return ib
+    in_specs = [lay.u_spec(bsz, c, i_pad, block_i, i_index),
+                lay.w_spec(c, j, d, block_i, i_index)]
+    operands = [ut, wt]
     if residual:
-        in_specs.append(pl.BlockSpec((bsz, jd), lambda p, ib: (0, 0)))
-    return pl.pallas_call(
+        in_specs.append(vec)
+        operands.append(lay.to_vec(r, j))
+    scratch = [pltpu.VMEM(lay.logits_shape(bsz, j, i_pad), jnp.float32),
+               pltpu.VMEM(vec_shape, jnp.float32),       # s accumulator
+               pltpu.VMEM(vec_shape, jnp.float32)]       # squashed v
+    if resident:
+        scratch.append(pltpu.VMEM(lay.votes_shape(bsz, j, d, i_pad),
+                                  jnp.float32))
+    kernel = functools.partial(_routing_kernel, lanes=st.lanes,
+                               n_passes=n_passes, n_blocks=n_blocks,
+                               block_i=block_i, resident=resident,
+                               residual=residual)
+    out = pl.pallas_call(
         kernel,
         grid=(n_passes, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bsz, jd), lambda p, ib: (0, 0)),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bsz, i_pad, j), jnp.float32),   # logits b
-            pltpu.VMEM((bsz, jd), jnp.float32),         # s accumulator
-            pltpu.VMEM((bsz, jd), jnp.float32),         # squashed v
-        ],
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct(vec_shape, u.dtype),
+        scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=st.interpret,
     )(*operands)
+    return lay.from_vec(out)
 
 
 def _vr_grad(st: _VRStatics, u, w, g):
     """Backward dispatch: returns (du, dw) via the mode's Pallas kernel."""
     bsz, i_dim, c = u.shape
-    _, jd, _ = w.shape
+    jd = w.shape[1]
     j = st.num_classes
     d = jd // j
+    lay = _LAYOUT[st.bwd_lanes]
     block_i = max(1, min(st.bwd_block_i, i_dim))
-    u_p, w_p, n_blocks, i_pad = _padded(u, w, block_i)
-    out_shapes = [jax.ShapeDtypeStruct((bsz, i_pad, c), u.dtype),
-                  jax.ShapeDtypeStruct((i_pad, jd, c), w.dtype)]
-
-    def _emit_only_out_specs(last_p):
-        # du/dW are written ONLY on the final emit pass.  Pallas shuttles
-        # whatever block the index map names through VMEM on every grid
-        # step, so an unpredicated ``ib`` map paid one full du + dW sweep
-        # per replay pass (the static auditor measured n_passes x the
-        # modeled output traffic); pinned to block 0 until the emit pass,
-        # each output block crosses HBM exactly once.
-        du = pl.BlockSpec(
-            (bsz, block_i, c),
-            lambda p, ib: (0, jnp.where(p == last_p, ib, 0), 0))
-        dw = pl.BlockSpec(
-            (block_i, jd, c),
-            lambda p, ib: (jnp.where(p == last_p, ib, 0), 0, 0))
-        return [du, dw]
-
-    if st.bwd_mode == "resident":
-        kernel = functools.partial(_resident_bwd_kernel, iters=st.iters,
-                                   j=j, d=d, n_blocks=n_blocks,
-                                   block_i=block_i)
-        du, dw = pl.pallas_call(
-            kernel,
-            grid=(2, n_blocks),
-            in_specs=[
-                pl.BlockSpec((bsz, block_i, c), lambda p, ib: (0, ib, 0)),
-                pl.BlockSpec((block_i, jd, c), lambda p, ib: (ib, 0, 0)),
-                pl.BlockSpec((bsz, jd), lambda p, ib: (0, 0)),
-            ],
-            out_specs=_emit_only_out_specs(1),
-            out_shape=out_shapes,
-            scratch_shapes=[pltpu.VMEM((bsz, i_pad, jd), jnp.float32)],
-            interpret=st.interpret,
-        )(u_p, w_p, g)
+    n_blocks = pl.cdiv(i_dim, block_i)
+    i_pad = n_blocks * block_i
+    ut, wt = lay.to_kernel(u, w, j, i_pad)
+    resident = st.bwd_mode == "resident"
+    n_passes = st.iters + 4
+    last = n_passes - 1
+    if resident:      # W read by the votes rebuild and the emit pass only
+        def i_index(p, ib):
+            return jnp.where((p == 0) | (p == last), ib, n_blocks - 1)
     else:
-        t = st.iters
-        if st.bwd_mode == ORACLE_MODE:  # unfused replay: 2T+1 fwd passes
-            body, n_passes = _streamed_2pass_bwd_kernel, 2 * t + 4
-        else:                           # fused replay: T+1 fwd passes
-            body, n_passes = _streamed_bwd_kernel, t + 4
-        kernel = functools.partial(body, iters=t, j=j, d=d,
-                                   n_blocks=n_blocks, block_i=block_i)
-        du, dw = pl.pallas_call(
-            kernel,
-            grid=(n_passes, n_blocks),
-            in_specs=[
-                pl.BlockSpec((bsz, i_pad, c), lambda p, ib: (0, 0, 0)),
-                pl.BlockSpec((block_i, jd, c), lambda p, ib: (ib, 0, 0)),
-                pl.BlockSpec((bsz, jd), lambda p, ib: (0, 0)),
-            ],
-            out_specs=_emit_only_out_specs(n_passes - 1),
-            out_shape=out_shapes,
-            scratch_shapes=[
-                pltpu.VMEM((2, bsz, i_pad, j), jnp.float32),  # b: rolling pair
-                pltpu.VMEM((2, bsz, jd), jnp.float32),        # s_{T-1}, s_T
-                pltpu.VMEM((bsz, i_pad, j), jnp.float32),     # db_T
-                pltpu.VMEM((bsz, jd), jnp.float32),           # ds_T
-                pltpu.VMEM((bsz, jd), jnp.float32),           # ds_{T-1}
-                pltpu.VMEM((bsz, jd), jnp.float32),           # s/dv acc
-                pltpu.VMEM((bsz, jd), jnp.float32),           # v
-            ],
-            interpret=st.interpret,
-        )(u_p, w_p, g)
-    return du[:, :i_dim, :], dw[:i_dim]
+        def i_index(p, ib):
+            return ib
+
+    # du/dW are written ONLY on the emit pass.  Pallas shuttles whatever
+    # block the index map names through VMEM on every grid step, so each
+    # output block is pinned to block 0 until the emit pass and crosses
+    # HBM exactly once.
+    def e_index(p, ib):
+        return jnp.where(p == last, ib, 0)
+
+    vec = lay.vec_shape(bsz, j, d)
+    logits = lay.logits_shape(bsz, j, i_pad)
+    scratch = [
+        pltpu.VMEM((2,) + logits, jnp.float32),        # b: rolling pair
+        pltpu.VMEM((2,) + vec, jnp.float32),           # s_{T-1}, s_T -> ds
+        pltpu.VMEM(vec, jnp.float32),                  # s/dv accumulator
+        pltpu.VMEM(vec, jnp.float32),                  # v
+    ]
+    if resident:
+        scratch.append(pltpu.VMEM(lay.votes_shape(bsz, j, d, i_pad),
+                                  jnp.float32))
+    kernel = functools.partial(_routing_bwd_kernel, lanes=st.bwd_lanes,
+                               iters=st.iters, n_blocks=n_blocks,
+                               block_i=block_i, resident=resident)
+    du_shape, dw_shape = lay.grad_shapes(bsz, c, j, d, i_pad)
+    du, dw = pl.pallas_call(
+        kernel,
+        grid=(n_passes, n_blocks),
+        in_specs=[
+            lay.u_spec(bsz, c, i_pad, block_i, i_index),
+            lay.w_spec(c, j, d, block_i, i_index),
+            pl.BlockSpec(vec, lambda p, ib: (0, 0, 0, 0)),
+        ],
+        out_specs=lay.grad_specs(bsz, c, j, d, block_i, e_index),
+        out_shape=[jax.ShapeDtypeStruct(du_shape, u.dtype),
+                   jax.ShapeDtypeStruct(dw_shape, w.dtype)],
+        scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
+        interpret=st.interpret,
+    )(ut, wt, lay.to_vec(g, j))
+    return lay.from_grads(du, dw, i_dim)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -760,27 +801,35 @@ def _res_segment_bwd(blocks, res, g):
 _res_segment.defvjp(_res_segment_fwd, _res_segment_bwd)
 
 
-def _seg_statics(stat, i_dim: int, interpret: bool) -> _VRStatics:
-    iters, j, mode, block_i, bwd_mode, bwd_block_i = stat
-    if mode not in ALL_MODES or bwd_mode not in ALL_MODES:
+def _check_schedule(mode: str, bwd_mode: str, lanes: str,
+                    bwd_lanes: str) -> None:
+    if mode not in MODES or bwd_mode not in MODES:
         raise ValueError(f"unknown mode {mode!r}/{bwd_mode!r}; "
-                         f"choose from {ALL_MODES}")
+                         f"choose from {MODES}")
+    if lanes not in LAYOUTS or bwd_lanes not in LAYOUTS:
+        raise ValueError(f"unknown lanes {lanes!r}/{bwd_lanes!r}; "
+                         f"choose from {LAYOUTS}")
+
+
+def _seg_statics(stat, i_dim: int, interpret: bool) -> _VRStatics:
+    iters, j, mode, block_i, bwd_mode, bwd_block_i, lanes, bwd_lanes = stat
+    _check_schedule(mode, bwd_mode, lanes, bwd_lanes)
     return _VRStatics(iters=iters, num_classes=j, mode=mode,
                       block_i=max(1, min(block_i, i_dim)),
                       bwd_mode=bwd_mode,
                       bwd_block_i=max(1, min(bwd_block_i, i_dim)),
-                      interpret=interpret)
+                      interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def res_caps_segment(x: jax.Array, ws, *, blocks,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """x: [B, I, C] through a run of reversible ResCapsBlocks -> [B, I, C].
 
     ``blocks`` is a tuple of ``(i1, stats_f, stats_g)`` per block, where
     ``i1`` is the coupling split point and each ``stats`` is the half's
-    ``(iters, num_out_caps, mode, block_i, bwd_mode, bwd_block_i)``
-    schedule (from its plan op; see ``repro.kernels.ops`` for the
+    ``(iters, num_out_caps, mode, block_i, bwd_mode, bwd_block_i, lanes,
+    bwd_lanes)`` schedule (from its plan op; see ``repro.kernels.ops`` for the
     plan-aware wrapper).  ``ws`` are the flat per-half weights, F then G
     per block: ``wf [I-i1, i1*C, C]``, ``wg [i1, (I-i1)*C, C]``.
 
@@ -811,24 +860,23 @@ def res_caps_segment(x: jax.Array, ws, *, blocks,
 
 @functools.partial(jax.jit, static_argnames=(
     "iters", "num_classes", "mode", "block_i", "bwd_mode", "bwd_block_i",
-    "interpret"))
+    "lanes", "bwd_lanes", "interpret"))
 def votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
                   num_classes: int = 10, mode: str = "resident",
                   block_i: int = 128, bwd_mode: str | None = None,
-                  bwd_block_i: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  bwd_block_i: int | None = None, lanes: str = "caps",
+                  bwd_lanes: str | None = None,
+                  interpret: bool) -> jax.Array:
     """u: [B, I, C], w: [I, J*D, C] -> v: [B, J*D]; votes + full routing.
 
-    ``mode``/``block_i`` come from the ExecutionPlan
+    ``mode``/``block_i``/``lanes`` come from the ExecutionPlan
     (``plan.op("ClassCaps-Routing")``); see ``repro.kernels.ops`` for the
     plan-aware wrapper.  The split ``caps_votes`` -> ``routing`` pair
-    remains available as the oracle/fallback path, and
-    ``mode="streamed-2pass"`` / ``bwd_mode="streamed-2pass"`` run the
-    unfused streamed schedule (2*iters+1 / 2*iters+4 W passes) -- never
-    plan-chosen, kept as the oracle for the fused s+b pass.
+    remains available as the oracle/fallback path.
 
     Differentiable: ``jax.grad`` runs the mode's backward Pallas kernel
-    (``bwd_mode``/``bwd_block_i``, defaulting to the forward schedule --
+    (``bwd_mode``/``bwd_block_i``/``bwd_lanes``, defaulting to the
+    forward schedule --
     the plan chooses them independently because the backward's scratch is
     larger), recomputing the routing iterations from the saved ``(u, W)``
     residuals so neither ``u_hat`` nor its cotangent touches HBM.
@@ -838,17 +886,14 @@ def votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
     j = num_classes
     if jd % j:
         raise ValueError(f"votes dim {jd} not divisible by classes {j}")
-    if mode not in ALL_MODES:
-        raise ValueError(f"unknown mode {mode!r}; choose from {ALL_MODES}")
     if iters < 1:
         raise ValueError(f"routing needs iters >= 1, got {iters}")
     bwd_mode = bwd_mode or mode
-    if bwd_mode not in ALL_MODES:
-        raise ValueError(
-            f"unknown bwd_mode {bwd_mode!r}; choose from {ALL_MODES}")
+    bwd_lanes = bwd_lanes or lanes
+    _check_schedule(mode, bwd_mode, lanes, bwd_lanes)
     st = _VRStatics(iters=iters, num_classes=num_classes, mode=mode,
                     block_i=max(1, min(block_i, i_dim)),
                     bwd_mode=bwd_mode,
                     bwd_block_i=max(1, min(bwd_block_i or block_i, i_dim)),
-                    interpret=interpret)
+                    interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes)
     return _vr_core(st, u, w)

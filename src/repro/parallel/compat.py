@@ -1,14 +1,5 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-The repo targets the jax documented in CI; these helpers keep it running on
-the adjacent releases too:
-
-  * ``jax.shard_map`` (new) vs ``jax.experimental.shard_map.shard_map``
-    (<= 0.4.x), whose replication-check kwarg also renamed
-    ``check_rep`` -> ``check_vma``;
-  * ``jax.make_mesh(..., axis_types=...)`` / ``jax.sharding.AxisType``,
-    absent on <= 0.4.x where every mesh axis is implicitly Auto.
-"""
+"""The jax sharding calls the serving and training paths make, in one
+place: ``jax.shard_map`` and ``jax.make_mesh`` as jax 0.9 spells them."""
 
 from __future__ import annotations
 
@@ -16,24 +7,12 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking disabled, any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where AxisType exists, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return (axis_type.Auto,) * n if axis_type is not None else None
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axis_names):
-    """An all-Auto mesh on any jax version."""
-    types = auto_axis_types(len(shape))
-    if types is not None:
-        return jax.make_mesh(shape, axis_names, axis_types=types)
-    return jax.make_mesh(shape, axis_names)
+    """A mesh whose axes are all ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

@@ -146,7 +146,8 @@ class CapsuleEngine:
 
     def __init__(self, params, cfg: CapsNetConfig = CapsNetConfig(), *,
                  slots: int = 8, backend: str = "jnp",
-                 interpret: bool = True, plan: ExecutionPlan | None = None,
+                 interpret: bool | None = None,
+                 plan: ExecutionPlan | None = None,
                  n_shards: int | None = None,
                  max_queue: int | None = None, admission: str = "reject",
                  max_retries: int = 2, retry_backoff_ticks: int = 1,

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import capsnet
 from repro.core.capsnet import CapsNetConfig
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.execplan import compile_plan
 from repro.train.data import DataConfig, mnist_batch
 from repro.train.harness import FaultTolerantLoop
@@ -60,7 +61,7 @@ class CapsLoopConfig:
     keep: int = 3
     log_every: int = 5
     backend: str = "pallas"
-    interpret: bool = True
+    interpret: bool | None = None     # None: compile on a TPU, else interpret
     max_nan_skips: int = 5            # bounds CONSECUTIVE non-finite steps
     straggler_factor: float | None = None   # step-time multiple that flags
     heartbeat_path: str | None = None
@@ -204,6 +205,7 @@ def main(argv: list[str] | None = None) -> int:
                      f"(CapsuleNet archs: {registry.CAPSNET_ARCHS})")
     else:
         cfg = CONFIGS[args.config]
+    enable_compile_cache()
     loop = CapsTrainLoop(cfg, CapsLoopConfig(
         total_steps=args.steps, batch=args.batch, lr=args.lr,
         optimizer=args.optimizer, ckpt_every=args.ckpt_every,

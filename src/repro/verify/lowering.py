@@ -17,8 +17,9 @@ computed from what the lowering actually says:
   (double-buffered when the operand's block index varies over the grid,
   single-buffered when it is constant -- the Pallas pipeline only
   prefetches blocks that change) plus output tiles (accumulator
-  semantics: one buffer) plus every scratch allocation.  An op lowering
-  to several sequential calls takes the max.
+  semantics: one buffer) plus every scratch allocation, each padded to
+  the (8, 128) tiling of its two minor dims as Mosaic allocates it.  An
+  op lowering to several sequential calls takes the max.
 * **HBM traffic**: per operand, ``fetches x block_bytes`` where
   ``fetches`` counts block-index *transitions* over the grid iteration
   order (last grid axis fastest) -- so a streamed W re-fetched every
@@ -45,12 +46,13 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.core import analysis, execplan
 from repro.core.capsnet import CapsNetConfig
 from repro.core.execplan import (BWD_SUFFIX, PIPE_NAME, ExecutionPlan,
                                  OpPlan)
+from repro.core.planner import tile_padded
 
 _SDS = jax.ShapeDtypeStruct
 
@@ -110,7 +112,7 @@ def _index_walk(block_mapping, grid: tuple[int, ...]) -> tuple[int, int]:
     cj = block_mapping.index_map_jaxpr
 
     def f(*idx):
-        return jcore.eval_jaxpr(cj.jaxpr, cj.consts, *idx)
+        return jax.core.eval_jaxpr(cj.jaxpr, cj.consts, *idx)
 
     outs = jax.vmap(f)(*(jnp.asarray(steps[:, k], jnp.int32)
                          for k in range(steps.shape[1])))
@@ -121,11 +123,21 @@ def _index_walk(block_mapping, grid: tuple[int, ...]) -> tuple[int, int]:
     return fetches, distinct
 
 
+def _block_dims(block_mapping) -> tuple[int, ...]:
+    """Block shape as ints (``pl.Blocked`` dims carry ``block_size``;
+    squeezed dims count 1)."""
+    return tuple(1 if d is None else int(getattr(d, "block_size", d))
+                 for d in block_mapping.block_shape)
+
+
 def _block_bytes(block_mapping) -> int:
-    shape = tuple(1 if d is None else int(d)
-                  for d in block_mapping.block_shape)
-    dtype = np.dtype(block_mapping.array_shape_dtype.dtype)
-    return math.prod(shape) * dtype.itemsize
+    dtype = np.dtype(block_mapping.array_aval.dtype)
+    return math.prod(_block_dims(block_mapping)) * dtype.itemsize
+
+
+def _vmem_block_bytes(block_mapping) -> int:
+    dtype = np.dtype(block_mapping.array_aval.dtype)
+    return tile_padded(_block_dims(block_mapping)) * dtype.itemsize
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,24 +186,23 @@ def trace_pallas_eqn(eqn) -> CallTrace:
         # Outputs live in ONE accumulator buffer (revisited K-steps must
         # accumulate in place).
         buffers = 2 if (role == "in" and distinct > 1) else 1
-        vmem += buffers * bb
+        vmem += buffers * _vmem_block_bytes(bm)
         hbm += fetches * bb
         operands.append(OperandTrace(
             role=role,
-            block_shape=tuple(1 if d is None else int(d)
-                              for d in bm.block_shape),
-            array_shape=tuple(bm.array_shape_dtype.shape),
-            dtype=str(np.dtype(bm.array_shape_dtype.dtype)),
+            block_shape=_block_dims(bm),
+            array_shape=tuple(bm.array_aval.shape),
+            dtype=str(np.dtype(bm.array_aval.dtype)),
             fetches=fetches, distinct=distinct, block_bytes=bb,
             buffers=buffers, traffic_bytes=fetches * bb))
     scratch = []
     scratch_bytes = 0
     for var in eqn.params["jaxpr"].invars[len(bms):]:
         aval = getattr(var.aval, "inner_aval", var.aval)
-        nbytes = math.prod(aval.shape) * np.dtype(aval.dtype).itemsize
+        nbytes = tile_padded(aval.shape) * np.dtype(aval.dtype).itemsize
         scratch_bytes += nbytes
         scratch.append((tuple(aval.shape), str(np.dtype(aval.dtype))))
-    name = getattr(eqn.params.get("name_and_src_info"), "name",
+    name = getattr(eqn.params["jaxpr"].debug_info, "func_name",
                    None) or "pallas_call"
     return CallTrace(kernel=str(name), grid=grid, operands=tuple(operands),
                      scratch_shapes=tuple(scratch),
@@ -244,7 +255,7 @@ def _trace_conv_fwd(plan: ExecutionPlan, op: OpPlan):
                              block_k=op.block.block_k,
                              block_n=op.block.block_n,
                              epilogue=epilogue, squash_dim=squash_dim,
-                             block_p=op.patch_rows)
+                             interpret=True)
 
     calls, outer = trace_lowering(fn, x, w, b)
     if op.name == "PrimaryCaps" and not op.fuses_squash:
@@ -264,7 +275,7 @@ def _trace_fused_fwd(plan: ExecutionPlan, op: OpPlan):
     st = vr._VRStatics(iters=lay.iters, num_classes=lay.num_caps,
                        mode=op.mode, block_i=op.block_i,
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
-                       interpret=True)
+                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes)
     u = _SDS((plan.batch, lay.in_caps, lay.in_dim), jnp.float32)
     w = _SDS((lay.in_caps, lay.jd, lay.in_dim), jnp.float32)
     if lay.residual:
@@ -280,24 +291,25 @@ def _trace_fused_bwd(plan: ExecutionPlan, op: OpPlan):
     st = vr._VRStatics(iters=lay.iters, num_classes=lay.num_caps,
                        mode=op.mode, block_i=op.block_i,
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
-                       interpret=True)
+                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes)
     u = _SDS((plan.batch, lay.in_caps, lay.in_dim), jnp.float32)
     w = _SDS((lay.in_caps, lay.jd, lay.in_dim), jnp.float32)
     g = _SDS((plan.batch, lay.jd), jnp.float32)
     calls, outer = trace_lowering(
         lambda uv, wv, gv: vr._vr_grad(st, uv, wv, gv), u, w, g)
     if lay.residual:
-        # Reversible inversion replays this coupling half FORWARD with
-        # the forward op's schedule before the VJP proper; the plan's
-        # backward entry models max(vmem) / summed traffic over both.
+        # Reversible inversion replays this coupling half FORWARD (no
+        # residual epilogue) with the forward op's schedule before the
+        # VJP proper; the plan's backward entry models max(vmem) /
+        # summed traffic over both.
         fwd_op = plan.op(lay.name)
         fst = vr._VRStatics(iters=lay.iters, num_classes=lay.num_caps,
                             mode=fwd_op.mode, block_i=fwd_op.block_i,
                             bwd_mode=fwd_op.mode, bwd_block_i=fwd_op.block_i,
-                            interpret=True)
-        r = _SDS((plan.batch, lay.jd), jnp.float32)
+                            interpret=True, lanes=fwd_op.lanes,
+                            bwd_lanes=fwd_op.lanes)
         fcalls, fouter = trace_lowering(
-            lambda uv, wv, rv: vr._vr_apply(fst, uv, wv, rv), u, w, r)
+            lambda uv, wv: vr._vr_apply(fst, uv, wv), u, w)
         calls, outer = calls + fcalls, outer + fouter
     return calls, outer
 
@@ -312,8 +324,7 @@ def _trace_pipe_fwd(plan: ExecutionPlan, op: OpPlan):
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
                        conv_block_m=op.block.block_m,
                        conv_block_k=op.block.block_k,
-                       conv_block_n=op.block.block_n, interpret=True,
-                       block_p=op.patch_rows)
+                       conv_block_n=op.block.block_n, interpret=True)
     x = _SDS((plan.batch, dims.conv1_out, dims.conv1_out, dims.pc_cin),
              jnp.float32)
     w_pc = _SDS((plan.cfg.pc_kernel, plan.cfg.pc_kernel, dims.pc_cin,
@@ -341,8 +352,7 @@ def _trace_conv_bwd(plan: ExecutionPlan, op: OpPlan):
     st = conv._ConvStatics(stride=stride, block_m=op.block.block_m,
                            block_k=op.block.block_k,
                            block_n=op.block.block_n, epilogue=epilogue,
-                           squash_dim=squash_dim, interpret=True,
-                           block_p=op.patch_rows)
+                           squash_dim=squash_dim, interpret=True)
     kh, kw = w.shape[0], w.shape[1]
     oh = (x.shape[1] - kh) // stride + 1
     ow = (x.shape[2] - kw) // stride + 1
@@ -397,13 +407,8 @@ class PlanAudit:
 
 # Fused/pipelined kernel bodies and the grid position of their streamed
 # W operand (the one whose derived fetch count IS the pass count).
-_W_OPERAND = {
-    "_resident_kernel": 1, "_streamed_kernel": 1,
-    "_streamed_2pass_kernel": 1,
-    "_resident_bwd_kernel": 1, "_streamed_bwd_kernel": 1,
-    "_streamed_2pass_bwd_kernel": 1,
-    "_pipe_resident_kernel": 3, "_pipe_streamed_kernel": 3,
-}
+_W_OPERAND = {"_routing_kernel": 1, "_routing_bwd_kernel": 1,
+              "_pipe_kernel": 3}
 
 
 def _main_call(calls: tuple[CallTrace, ...], op: OpPlan) -> CallTrace | None:
@@ -437,6 +442,37 @@ def _i_pad(i_dim: int, block_i: int) -> int:
     return math.ceil(i_dim / max(block_i, 1)) * max(block_i, 1)
 
 
+def _xla_extraction_bytes(plan: ExecutionPlan, op: OpPlan, outer) -> int:
+    """HBM bytes of a forward patch extraction that XLA runs outside any
+    kernel (channel axes narrower than the lane tiling): the image read
+    and the patch matrix written, counted when the traced program
+    materializes that matrix."""
+    dims = analysis.dims_from_config(plan.cfg)
+    if op.name == "Conv1":
+        hw, cin, k, out = (dims.in_hw, dims.conv1_cin, dims.conv1_k,
+                           dims.conv1_out)
+    elif op.name in ("PrimaryCaps", PIPE_NAME):
+        hw, cin, k, out = (dims.conv1_out, dims.pc_cin, dims.pc_k,
+                           dims.pc_out)
+    else:
+        return 0
+    patch = (plan.batch, out * out, k * k * cin)
+    if not any(tuple(getattr(v.aval, "shape", ())) == patch
+               for eqn in outer for v in eqn.outvars):
+        return 0
+    return plan.batch * (hw * hw * cin + out * out * k * k * cin) * 4
+
+
+def _relayout_bytes(outer) -> int:
+    """HBM bytes of the transposes the wrappers run outside any kernel
+    (the routing kernels' lane layouts, transposed weights): each reads
+    its operand and writes its result once."""
+    def nbytes(v):
+        return math.prod(v.aval.shape) * np.dtype(v.aval.dtype).itemsize
+    return sum(nbytes(eqn.invars[0]) + nbytes(eqn.outvars[0])
+               for eqn in outer if eqn.primitive.name == "transpose")
+
+
 def audit_op(plan: ExecutionPlan, op: OpPlan) -> OpAudit:
     """Trace one op's lowering and diff it against its plan entry."""
     tracers = {
@@ -468,7 +504,9 @@ def audit_op(plan: ExecutionPlan, op: OpPlan) -> OpAudit:
                 f"(x{contract.vmem_over_factor} slack)")))
 
     if op.hbm_bytes is not None:
-        derived_hbm = sum(c.hbm_bytes for c in calls)
+        derived_hbm = (sum(c.hbm_bytes for c in calls)
+                       + _xla_extraction_bytes(plan, op, outer)
+                       + _relayout_bytes(outer))
         rel = abs(derived_hbm - op.hbm_bytes) / max(op.hbm_bytes, 1.0)
         checks.append(Check(
             name="hbm-traffic", ok=rel <= contract.hbm_rtol,
@@ -498,23 +536,31 @@ def audit_op(plan: ExecutionPlan, op: OpPlan) -> OpAudit:
                         )))
 
     batch = plan.batch
+
+    def _uhat_shapes(lay, pad):
+        # [B, I, J*D] as the model sees it, [B, J, D, I] / [B, D, I, J]
+        # as the kernel layouts hold it, unpadded and padded.
+        return {shape for i in (lay.in_caps, pad)
+                for shape in ((batch, i, lay.jd),
+                              (batch, lay.num_caps, lay.caps_dim, i),
+                              (batch, lay.caps_dim, i, lay.num_caps))}
+
     if op.uhat_hbm_bytes == 0.0 and op.kernel != "primary_routing":
         lay = _layer_for(plan, op.name)
         pad = _i_pad(lay.in_caps, op.block_i or lay.in_caps)
-        forbidden = {(batch, lay.in_caps, lay.jd), (batch, pad, lay.jd)}
         allowed = {(batch, lay.in_caps, lay.in_dim),
                    (batch, pad, lay.in_dim)}
-        checks.append(_shape_check(outer, forbidden, allowed,
+        checks.append(_shape_check(outer, _uhat_shapes(lay, pad), allowed,
                                    "uhat-never-in-hbm"))
     if op.kernel == "primary_routing":
         lay = plan.cfg.routing_stack()[0]
         pad = _i_pad(lay.in_caps, op.block_i or lay.in_caps)
-        forbidden = {(batch, lay.in_caps, lay.jd), (batch, pad, lay.jd)}
-        checks.append(_shape_check(outer, forbidden, set(),
+        checks.append(_shape_check(outer, _uhat_shapes(lay, pad), set(),
                                    "uhat-never-in-hbm"))
         if op.intermediate_hbm_bytes == 0.0:
-            forb_u = {(batch, lay.in_caps, lay.in_dim),
-                      (batch, pad, lay.in_dim)}
+            forb_u = {shape for i in (lay.in_caps, pad)
+                      for shape in ((batch, i, lay.in_dim),
+                                    (batch, lay.in_dim, i))}
             checks.append(_shape_check(outer, forb_u, set(),
                                        "u-never-in-hbm"))
 

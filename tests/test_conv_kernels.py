@@ -31,6 +31,7 @@ def _conv_ref(x, w, b, stride):
         (3, 14, 5, 7, 20, 2),      # strided, K=175 forces zero-padding
         (1, 9, 4, 2, 12, 3),       # stride > kernel overlap, tiny channels
         (2, 28, 9, 1, 32, 1),      # MNIST Conv1 shape (narrow)
+        (2, 11, 3, 128, 16, 2),    # lane-dense channels: Pallas extraction
     ])
 def test_conv_im2col_matches_lax(batch, hw, k, cin, cout, stride):
     x = jax.random.uniform(KEY, (batch, hw, hw, cin))
@@ -38,7 +39,7 @@ def test_conv_im2col_matches_lax(batch, hw, k, cin, cout, stride):
     b = 0.1 * jax.random.normal(KEY, (cout,))
     want = _conv_ref(x, w, b, stride)
     got = conv2d_im2col(x, w, b, stride=stride,
-                        block_m=8, block_k=16, block_n=8)
+                        block_m=8, block_k=16, block_n=8, interpret=True)
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -50,7 +51,7 @@ def test_conv_relu_epilogue():
     b = jnp.linspace(-0.5, 0.5, 16)
     want = jnp.maximum(_conv_ref(x, w, b, 1), 0.0)
     got = conv2d_im2col(x, w, b, stride=1, epilogue="relu",
-                        block_m=16, block_k=8, block_n=8)
+                        block_m=16, block_k=8, block_n=8, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -65,7 +66,7 @@ def test_conv_squash_epilogue_matches_unfused():
     pre = _conv_ref(x, w, b, 2)
     want = squash(pre.reshape(*pre.shape[:-1], 24 // pd, pd)).reshape(pre.shape)
     got = conv2d_im2col(x, w, b, stride=2, epilogue="squash", squash_dim=pd,
-                        block_m=8, block_k=16, block_n=8)
+                        block_m=8, block_k=16, block_n=8, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -75,23 +76,28 @@ def test_squash_epilogue_rejects_misaligned_tile():
     w = jax.random.normal(KEY, (3, 3, 2, 12))
     b = jnp.zeros((12,))
     with pytest.raises(ValueError):
-        conv2d_im2col(x, w, b, epilogue="squash", squash_dim=5, block_n=8)
+        conv2d_im2col(x, w, b, epilogue="squash", squash_dim=5, block_n=8,
+                      interpret=True)
     with pytest.raises(ValueError):            # default squash_dim=0: clear
-        conv2d_im2col(x, w, b, epilogue="squash")  # error, not ZeroDivision
+        conv2d_im2col(x, w, b, epilogue="squash",  # error, not ZeroDivision
+                      interpret=True)
 
 
 def test_unknown_epilogue_rejected():
     with pytest.raises(ValueError):
         matmul_bias_act(jnp.ones((4, 4)), jnp.ones((4, 4)), jnp.ones((4,)),
-                        epilogue="gelu")
+                        epilogue="gelu", interpret=True)
 
 
-def test_patches_match_manual_extraction():
-    """Patch column order is (kh, kw, c)-major -- what w.reshape expects."""
-    b, hw, k, c, stride = 2, 7, 3, 2, 2
+@pytest.mark.parametrize("c,stride", [(2, 2), (128, 2), (128, 1)])
+def test_patches_match_manual_extraction(c, stride):
+    """Patch column order is (kh, kw, c)-major -- what w.reshape expects,
+    for narrow and lane-wide channel axes, strided or not."""
+    b, hw, k = 2, 7, 3
     x = np.asarray(jax.random.uniform(KEY, (b, hw, hw, c)))
     oh = (hw - k) // stride + 1
-    got = np.asarray(im2col_patches(jnp.asarray(x), kh=k, kw=k, stride=stride))
+    got = np.asarray(im2col_patches(jnp.asarray(x), kh=k, kw=k,
+                                    stride=stride))
     assert got.shape == (b, oh * oh, k * k * c)
     for bi in range(b):
         for i in range(oh):

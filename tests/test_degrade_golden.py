@@ -16,42 +16,46 @@ from repro.configs.registry import CAPSNET_ARCHS, get_config
 from repro.core.execplan import (PlanError, VMEM_BYTES, compile_plan,
                                  degrade_plan)
 
-# (arch, requested batch, budget fraction) -> exact concession tuple.
+# (arch, requested batch, budget fraction) -> exact concession tuple, or
+# None where no batch fits: capsnet-cifar10's ResCaps halves need 9.7 MB
+# at batch 1 (a 4 MiB logits slab and two 2 MiB 8-capsule W tiles), over
+# half the budget.
 GOLDEN = {
     ("capsnet-mnist", 4, 1.0): (),
     ("capsnet-mnist", 4, 0.5): (),
     ("capsnet-mnist", 4, 0.25): (
-        "PrimaryCaps-Routing: block_i 128 -> 4",
+        "PrimaryCaps-Routing: resident -> streamed",
+        "PrimaryCaps-Routing: block_k 256 -> 128",
     ),
     ("capsnet-mnist", 4, 0.125): (
+        "batch 4 -> 1",
+        "pipelined PrimaryCaps-Routing pair -> per-op (inter-layer u "
+        "round-trips HBM again)",
         "Conv1: conv tiles (1024,128,256) -> (256,128,256)",
-        "PrimaryCaps-Routing: resident -> streamed",
-        "PrimaryCaps-Routing: block_i 128 -> 64",
     ),
     ("capsnet-cifar10", 2, 1.0): (),
-    ("capsnet-cifar10", 2, 0.5): (
+    ("capsnet-cifar10", 2, 0.625): (
         "batch 2 -> 1",
         "PrimaryCaps: conv tiles (128,256,256) -> (64,256,256)",
-        "ClassCaps-Routing[0]: block_i 8 -> 4",
-        "ClassCaps-Routing[1]: block_i 8 -> 4",
-        "ClassCaps-Routing[2]: block_i 8 -> 4",
-        "ClassCaps-Routing[3]: block_i 8 -> 4",
-        "ClassCaps-Routing[4]: block_i 8 -> 4",
-        "ClassCaps-Routing[5]: block_i 8 -> 4",
         "ClassCaps-Routing: block_i 2048 -> 512",
     ),
+    ("capsnet-cifar10", 2, 0.5): None,
     ("capsnet-svhn", 4, 1.0): (),
     ("capsnet-svhn", 4, 0.5): (
-        "PrimaryCaps-Routing: block_i 256 -> 64",
+        "pipelined PrimaryCaps-Routing pair -> per-op (inter-layer u "
+        "round-trips HBM again)",
     ),
     ("capsnet-svhn", 4, 0.25): (
-        "PrimaryCaps-Routing: block_i 256 -> 16",
+        "batch 4 -> 3",
+        "pipelined PrimaryCaps-Routing pair -> per-op (inter-layer u "
+        "round-trips HBM again)",
+        "Conv1: conv tiles (512,256,256) -> (1024,256,256)",
     ),
     ("capsnet-svhn", 4, 0.125): (
-        "batch 4 -> 2",
+        "batch 4 -> 1",
+        "pipelined PrimaryCaps-Routing pair -> per-op (inter-layer u "
+        "round-trips HBM again)",
         "Conv1: conv tiles (512,256,256) -> (256,256,256)",
-        "PrimaryCaps-Routing: block_i 256 -> 2",
-        "PrimaryCaps-Routing: conv tiles (256,256,256) -> (128,256,256)",
     ),
 }
 
@@ -60,6 +64,11 @@ GOLDEN = {
                          ids=lambda v: str(v))
 def test_concession_sequence_golden(arch, batch, frac):
     cfg = get_config(arch)
+    if GOLDEN[(arch, batch, frac)] is None:
+        with pytest.raises(PlanError, match="batch >= 1"):
+            degrade_plan(cfg, int(VMEM_BYTES * frac), batch=batch,
+                         pipeline=True)
+        return
     plan, rep = degrade_plan(cfg, int(VMEM_BYTES * frac), batch=batch,
                              pipeline=True)
     assert rep.concessions == GOLDEN[(arch, batch, frac)]
@@ -84,6 +93,8 @@ def test_batch_concession_is_reported_first():
     # Whenever batch is conceded it must lead the sequence -- operators
     # grep degradation logs for the throughput hit first.
     for (arch, batch, frac), gold in GOLDEN.items():
+        if gold is None:
+            continue
         batch_notes = [c for c in gold if c.startswith("batch ")]
         if batch_notes:
             assert gold[0] == batch_notes[0], (arch, frac)

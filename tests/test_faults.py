@@ -155,20 +155,20 @@ def test_degrade_plan_full_budget_is_golden():
 
 
 def test_degrade_plan_forces_streamed_schedule():
-    plan, rep = degrade_plan(CFG, batch=16, vmem_budget=200_000,
+    plan, rep = degrade_plan(CFG, batch=16, vmem_budget=4_000_000,
                              pipeline=True)
     assert rep.degraded and rep.batch == 16
     assert any("resident -> streamed" in c for c in rep.concessions)
     modes = {op.name: op.mode for op in plan.ops}
     assert modes["PrimaryCaps-Routing"] == "streamed"
-    assert all(op.vmem_bytes <= 200_000 for op in plan.ops)
+    assert all(op.vmem_bytes <= 4_000_000 for op in plan.ops)
 
 
 def test_degrade_plan_reduces_batch():
     """On the full MNIST config the pipelined pair's resident ``u`` scales
     with batch, so a tight budget walks down to a smaller feasible batch
     (the last rung before the breaker) and says so."""
-    plan, rep = degrade_plan(CapsNetConfig(), batch=8, vmem_budget=600_000,
+    plan, rep = degrade_plan(CapsNetConfig(), batch=8, vmem_budget=4_000_000,
                              pipeline=True)
     assert rep.requested_batch == 8
     assert rep.batch < 8
@@ -181,14 +181,14 @@ def test_degrade_plan_exhaustion_raises_planerror():
         degrade_plan(CFG, batch=4, vmem_budget=60_000)
     # min_batch floors the walk-down even when smaller batches would fit
     with pytest.raises(PlanError, match="batch >= 8"):
-        degrade_plan(CapsNetConfig(), batch=8, vmem_budget=600_000,
+        degrade_plan(CapsNetConfig(), batch=8, vmem_budget=4_000_000,
                      pipeline=True, min_batch=8)
 
 
 def test_degraded_plan_output_parity():
     """A degraded plan changes the schedule, never the math."""
     imgs = _images(2)
-    plan, rep = degrade_plan(CFG, batch=2, vmem_budget=200_000,
+    plan, rep = degrade_plan(CFG, batch=2, vmem_budget=600_000,
                              pipeline=True)
     assert rep.degraded
     got = np.asarray(capsnet.forward(PARAMS, imgs, CFG, backend="pallas",
@@ -294,7 +294,7 @@ def test_engine_plan_swap_clears_quarantine():
             FaultSpec(site=faults.SITE_ENGINE_FORWARD, kind="nan_output",
                       at=0, times=1),
             FaultSpec(site=faults.SITE_ENGINE_TICK, kind="vmem_shrink",
-                      at=1, times=1, factor=0.012)):
+                      at=1, times=1, factor=0.036)):
         engine.run()
     s = _assert_terminal(engine)
     assert s["error"] == 2           # quarantine_after=1: both lanes, tick 0
@@ -379,7 +379,7 @@ def test_engine_sharded_vmem_shrink_one_retrace():
         engine.submit(CapsRequest(rid=i, image=imgs[i]))
     with faults.inject(FaultSpec(site=faults.SITE_ENGINE_TICK,
                                  kind="vmem_shrink", at=1, times=2,
-                                 factor=0.012)):
+                                 factor=0.036)):
         engine.run()
     s = _assert_terminal(engine)
     assert s["ok"] == 6 and s["replans"] == 1
@@ -416,7 +416,7 @@ def test_engine_vmem_shrink_swaps_degraded_plan():
     assert engine._forward_traces == 0
     with faults.inject(FaultSpec(site=faults.SITE_ENGINE_TICK,
                                  kind="vmem_shrink", at=1, times=2,
-                                 factor=0.012)):
+                                 factor=0.036)):
         engine.run()
     s = _assert_terminal(engine)
     assert s["ok"] == 6

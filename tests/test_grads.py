@@ -60,19 +60,21 @@ def test_matmul_at_b_matches_einsum_with_ragged_reduction():
     k1, k2 = jax.random.split(KEY)
     a = jax.random.normal(k1, (45, 13))          # M=45 ragged vs block_m=16
     b = jax.random.normal(k2, (45, 21))
-    got = matmul_at_b(a, b, block_m=16, block_k=8, block_n=8)
+    got = matmul_at_b(a, b, block_m=16, block_k=8, block_n=8, interpret=True)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(a.T @ b), rtol=1e-5, atol=1e-6)
 
 
-def test_col2im_is_adjoint_of_im2col():
-    """<col2im(dp), x> == <dp, im2col(x)>: the scatter kernel is the
-    exact transpose of the strided patch extraction."""
+@pytest.mark.parametrize("c", [3, 128])
+def test_col2im_is_adjoint_of_im2col(c):
+    """<col2im(dp), x> == <dp, im2col(x)>: the scatter is the exact
+    transpose of the patch extraction, for narrow and lane-wide channel
+    axes."""
     k1, k2 = jax.random.split(KEY)
     for stride in (1, 2):
-        x = jax.random.normal(k1, (2, 9, 9, 3))
+        x = jax.random.normal(k1, (2, 9, 9, c))
         oh = (9 - 3) // stride + 1
-        dp = jax.random.normal(k2, (2, oh * oh, 3 * 3 * 3))
+        dp = jax.random.normal(k2, (2, oh * oh, 3 * 3 * c))
         patches = im2col_patches(x, kh=3, kw=3, stride=stride)
         dx = col2im_patches(dp, kh=3, kw=3, stride=stride, h=9, w=9)
         lhs = float(jnp.sum(dx * x))
@@ -93,7 +95,7 @@ def test_conv_grad_matches_lax_conv_reference(epilogue, squash_dim, stride):
     def f_pal(x, w, bias):
         out = conv2d_im2col(x, w, bias, stride=stride, block_m=16,
                             block_k=8, block_n=8, epilogue=epilogue,
-                            squash_dim=squash_dim)
+                            squash_dim=squash_dim, interpret=True)
         return jnp.sum(out * dy)
 
     def f_ref(x, w, bias):
@@ -182,18 +184,18 @@ def test_votes_routing_grad_property(i, bi, bwd_mode):
     (2, 100, 8, 10, 16, 32, 3),      # ragged final i-block + batch>1
     (2, 27, 4, 4, 8, 8, 1),          # odd non-power-of-two capsule count
 ], ids=["even", "ragged", "nonpow2"])
-def test_streamed_fused_bwd_matches_2pass_oracle(b, i, c, j, d, bi, iters):
-    """The fused replay (iters+4 W passes) produces the SAME gradients as
-    the unfused 2-pass replay oracle (2*iters+4 passes) -- and both match
-    the jnp reference."""
+def test_streamed_bwd_matches_resident_bwd(b, i, c, j, d, bi, iters):
+    """The streamed replay (votes recomputed from W on each of the
+    iters+4 passes) produces the SAME gradients as the resident replay
+    (votes rebuilt once into scratch) -- and both match the jnp
+    reference."""
     u, w, k3 = _uv(b, i, c, j * d, seed=50 + i + iters)
     dv = jax.random.normal(k3, (b, j, d))
     fused, want = _vr_grad_pair(u, w, dv, iters=iters, j=j, d=d,
                                 mode="streamed", bwd_mode="streamed",
                                 bi=bi, bwd_bi=max(bi // 2, 1))
     oracle, _ = _vr_grad_pair(u, w, dv, iters=iters, j=j, d=d,
-                              mode="streamed-2pass",
-                              bwd_mode="streamed-2pass",
+                              mode="streamed", bwd_mode="resident",
                               bi=bi, bwd_bi=max(bi // 2, 1))
     for g_f, g_o, g_r in zip(fused, oracle, want):
         np.testing.assert_allclose(np.asarray(g_f), np.asarray(g_o),
@@ -219,7 +221,8 @@ def test_grad_through_planless_wrapper():
     want = jax.grad(loss_ref, argnums=(0, 1))(u, w)
     for g, r in zip(got, want):
         assert _rel(g, r) <= TOL
-    mode, bi = ops.planned_votes_routing_bwd(150, 8, 80, 10, 3, 2)
+    mode, bi, lanes = ops.planned_votes_routing_bwd(150, 8, 80, 10, 3, 2)
+    assert lanes == "caps"
     assert mode in ("resident", "streamed") and 1 <= bi <= 150
 
 
@@ -245,17 +248,19 @@ def test_total_loss_grad_parity(cfg, batch):
 
 
 def test_budget_flip_to_streamed_keeps_grad_parity():
-    """A VMEM budget under the resident floors flips BOTH the forward and
-    the backward to streamed -- and the gradients still match the jnp
-    reference (the mode-flip case of the parity matrix)."""
-    budget = 300_000
+    """A VMEM budget under the backward's resident floor flips the
+    backward to streamed while the forward stays resident -- and the
+    gradients still match the jnp reference (the mode-flip case of the
+    parity matrix)."""
+    budget = 1_600_000
     dims_i, c = NONPOW2.num_primary, NONPOW2.primary_dim
     jd = NONPOW2.num_classes * NONPOW2.class_dim
-    assert execplan._fused_resident_vmem(2, dims_i, 1, c, jd, 10) > budget
+    bi = execplan._min_block_i(dims_i)
+    assert execplan._fused_resident_vmem(2, dims_i, bi, c, jd, 10) <= budget
     assert execplan._fused_resident_bwd_vmem(
-        2, dims_i, 1, c, jd, 10, NONPOW2.routing_iters) > budget
+        2, dims_i, bi, c, jd, 10, NONPOW2.routing_iters) > budget
     plan = compile_plan(NONPOW2, batch=2, vmem_budget=budget, train=True)
-    assert plan.op(FUSED_NAME).mode == "streamed"
+    assert plan.op(FUSED_NAME).mode == "resident"
     assert plan.op(FUSED_NAME + BWD_SUFFIX).mode == "streamed"
 
     params = capsnet.init_params(KEY, NONPOW2)
@@ -298,7 +303,9 @@ def test_backward_plan_reports_zero_uhat_traffic():
     fused = votes_routing_bwd_hbm_bytes(8, cfg.num_primary, cfg.primary_dim,
                                         jd, mode=bwd.mode,
                                         iters=cfg.routing_iters)
-    assert bwd.hbm_bytes == fused
+    relayout = execplan.lane_relayout_hbm_bytes(8, cfg.num_primary,
+                                                cfg.primary_dim, jd)
+    assert bwd.hbm_bytes == fused + 2 * relayout   # inputs in, grads out
     spilled, uhat = spilled_votes_routing_bwd_hbm_bytes(
         8, cfg.num_primary, cfg.primary_dim, jd)
     # u_hat is written+read and its cotangent round-trips the same way
@@ -322,8 +329,8 @@ def test_forward_only_backward_fallback_warns_once():
     dims = analysis.dims_from_config(NONPOW2)
     jd = dims.num_classes * dims.class_dim
     floor = execplan._fused_streamed_bwd_vmem(
-        2, dims.num_primary, 1, dims.primary_dim, jd, dims.num_classes,
-        dims.routing_iters)
+        2, dims.num_primary, execplan._min_block_i(dims.num_primary),
+        dims.primary_dim, jd, dims.num_classes, dims.routing_iters)
     plan = compile_plan(NONPOW2, batch=2, vmem_budget=floor - 1)
     u, w, _ = _uv(2, dims.num_primary, dims.primary_dim, jd, seed=77)
     _warn_bwd_fallback_once.cache_clear()
@@ -352,9 +359,8 @@ def test_backward_traffic_model_counts_fused_passes():
     res = votes_routing_bwd_hbm_bytes(2, cfg.num_primary, cfg.primary_dim,
                                       jd, mode="resident", iters=3)
     w_sweep = cfg.num_primary * jd * cfg.primary_dim * execplan.ELEM_BYTES
-    u_bytes = 2 * cfg.num_primary * cfg.primary_dim * execplan.ELEM_BYTES
-    # streamed - resident = (iters+4-2) W sweeps minus one fewer u pass
-    assert stre - res == (3 + 4 - 2) * w_sweep - u_bytes
+    # streamed - resident = (iters+4-2) W sweeps; u is read once by both
+    assert stre - res == (3 + 4 - 2) * w_sweep
 
 
 def test_smallest_backward_infeasible_budget_raises_at_source():
@@ -364,12 +370,13 @@ def test_smallest_backward_infeasible_budget_raises_at_source():
     from repro.core import analysis
     dims = analysis.dims_from_config(NONPOW2)
     jd = dims.num_classes * dims.class_dim
+    bi = execplan._min_block_i(dims.num_primary)
     floor = execplan._fused_streamed_bwd_vmem(
-        2, dims.num_primary, 1, dims.primary_dim, jd, dims.num_classes,
+        2, dims.num_primary, bi, dims.primary_dim, jd, dims.num_classes,
         dims.routing_iters)
     # one byte under the backward floor: the forward still plans...
     fwd_plan = compile_plan(NONPOW2, batch=2, vmem_budget=floor - 1)
-    assert fwd_plan.op(FUSED_NAME).mode == "streamed"
+    assert fwd_plan.op(FUSED_NAME).mode in ("resident", "streamed")
     # ...but the training plan fails with the named boundary
     with pytest.raises(PlanError) as exc:
         compile_plan(NONPOW2, batch=2, vmem_budget=floor - 1, train=True)
@@ -377,21 +384,21 @@ def test_smallest_backward_infeasible_budget_raises_at_source():
     assert FUSED_NAME + BWD_SUFFIX in msg
     assert "batch=2" in msg
     assert "largest feasible batch is 1" in msg
-    # at the floor itself the backward plans (streamed block_i=1)
+    # at the floor itself the backward plans (smallest streamed i-tile)
     at_floor = compile_plan(NONPOW2, batch=2, vmem_budget=floor, train=True)
     bwd = at_floor.op(FUSED_NAME + BWD_SUFFIX)
-    assert bwd.mode == "streamed" and bwd.block_i == 1
+    assert bwd.mode == "streamed" and bwd.block_i == bi
 
 
 def test_plan_votes_routing_bwd_prefers_resident_when_roomy():
     sched = plan_votes_routing_bwd(600, 4, 80, 10, batch=2, iters=3)
     assert sched.mode == "resident" and sched.n_passes == 2
     tight = plan_votes_routing_bwd(600, 4, 80, 10, batch=2, iters=3,
-                                   vmem_budget=400_000)
+                                   vmem_budget=1_600_000)
     # fused replay: one W stream per replayed iteration + readout, then
     # seed / reverse / emit -- NOT the old 2-pass replay's 2*iters+4
     assert tight.mode == "streamed" and tight.n_passes == 3 + 4
-    assert tight.vmem_bytes <= 400_000
+    assert tight.vmem_bytes <= 1_600_000
 
 
 def test_train_false_plan_unchanged():

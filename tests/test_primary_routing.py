@@ -15,6 +15,7 @@ from repro.core.execplan import (FUSED_NAME, PIPE_NAME, BWD_SUFFIX,
                                  plan_primary_routing,
                                  primary_intermediate_hbm_bytes,
                                  primary_routing_hbm_bytes)
+from repro.core.planner import LANES
 from repro.kernels import ops
 
 KEY = jax.random.PRNGKey(0)
@@ -177,11 +178,13 @@ def test_budget_forces_perop_fallback():
     falls back to the per-op pair (which still fits -- its phases never
     coexist), and the unfused path keeps executing."""
     a = _pipe_args(SMOKE, 64)
+    bi, bk = execplan._min_block_i(a["num_caps"]), min(a["k_in"], LANES)
     floor = execplan._pipe_streamed_vmem(
-        a["batch"], a["p_pos"], a["n_ch"], 1, a["num_caps"], 1,
+        a["batch"], a["p_pos"], a["n_ch"], bk, a["num_caps"], bi,
         a["caps_dim"], a["jd"], a["j"])
     budget = floor - 1
-    with pytest.raises(PlanError, match="streamed block_i=1, block_k=1"):
+    with pytest.raises(PlanError,
+                       match=f"streamed block_i={bi}, block_k={bk}"):
         plan_primary_routing(
             a["p_pos"], a["k_in"], a["n_ch"], a["num_caps"], a["caps_dim"],
             a["jd"], a["j"], batch=a["batch"], vmem_budget=budget)
@@ -242,7 +245,9 @@ def test_train_plan_keeps_perop_backward():
                      "PrimaryCaps" + BWD_SUFFIX, "Conv1" + BWD_SUFFIX]
     pc_bwd = plan.op("PrimaryCaps" + BWD_SUFFIX)
     patches = pc_bwd.workload.m * pc_bwd.workload.k * execplan.ELEM_BYTES
-    assert pc_bwd.hbm_bytes == 3 * pc_bwd.block.hbm_bytes + 2 * patches
+    relayout = 2 * pc_bwd.workload.k * pc_bwd.workload.n * execplan.ELEM_BYTES
+    assert pc_bwd.hbm_bytes == (3 * pc_bwd.block.hbm_bytes + 2 * patches
+                                + relayout)
 
 
 # ---------------------------------------------------------------------------
@@ -267,8 +272,10 @@ def test_pipelined_plan_zero_intermediate_and_lower_total():
     # the modeled pipelined traffic is the plan's own number
     a = _pipe_args(cfg, 8)
     dims = analysis.dims_from_config(cfg)
-    extract = execplan.conv_extract_hbm_bytes(
+    extract = (execplan.conv_extract_hbm_bytes(
         dims.conv1_out, dims.pc_cin, dims.pc_k, dims.pc_out, batch=8)
+        + execplan.lane_relayout_hbm_bytes(0, a["num_caps"], a["caps_dim"],
+                                           a["jd"]))
     assert op.hbm_bytes == primary_routing_hbm_bytes(
         8, a["p_pos"], a["k_in"], a["n_ch"], a["num_caps"], a["caps_dim"],
         a["jd"], pipe.op(PIPE_NAME).mode == "streamed"

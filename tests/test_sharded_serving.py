@@ -90,7 +90,7 @@ SUBPROCESS_SRC = textwrap.dedent("""
     # -- vmem_shrink under sharding: one replan, ONE mesh-wide re-trace -
     with faults.inject(FaultSpec(site=faults.SITE_ENGINE_TICK,
                                  kind="vmem_shrink", at=1, times=2,
-                                 factor=0.012)):
+                                 factor=0.06)):
         eng = CapsuleEngine(PARAMS, CFG, slots=8, backend="pallas",
                             n_shards=2)
         serve(eng)

@@ -46,9 +46,9 @@ class TestCleanAudit:
         assert "votes_routing_bwd" in kernels
 
     def test_degraded_budget_audits_clean(self):
-        # The quarter-budget rung forces blocked im2col extraction
-        # (patch_rows) and streamed routing -- the lowering must still
-        # match the degraded model.
+        # The quarter-budget rung forces a streamed pipelined pair with a
+        # smaller produce K tile -- the lowering must still match the
+        # degraded model.
         plan, _rep = execplan.degrade_plan(
             get_config("capsnet-mnist"), execplan.VMEM_BYTES // 4,
             batch=4, pipeline=True)

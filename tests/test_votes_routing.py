@@ -4,9 +4,11 @@ counts, batch>1, both schedules), the plan's resident-vs-streamed
 decision, PlanError boundaries, and the modeled u_hat HBM savings."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.registry import get_config, get_smoke_config
 from repro.core import capsnet, execplan
 from repro.core.capsnet import CapsNetConfig
 from repro.core.execplan import (FUSED_NAME, PlanError, compile_plan,
@@ -86,7 +88,8 @@ def test_fused_planless_wrapper_picks_schedule():
                        3).reshape(2, 80)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
-    mode, bi = ops.planned_votes_routing(150, 8, 80, 10, 3, 2)
+    mode, bi, lanes = ops.planned_votes_routing(150, 8, 80, 10, 3, 2)
+    assert lanes == "caps"
     assert mode == "resident"               # MNIST-scale votes fit VMEM
     assert 1 <= bi <= 150
 
@@ -96,25 +99,32 @@ def test_fused_planless_wrapper_picks_schedule():
 # only when even streamed block_i=1 cannot fit
 # ---------------------------------------------------------------------------
 
+# Between NONPOW2's streamed and resident footprints at batch 2.
+TIGHT = 1_000_000
+
+
 def test_small_budget_flips_plan_to_streamed():
     args = dict(batch=2, iters=3)
     roomy = plan_votes_routing(600, 4, 80, 10, **args)
     assert roomy.mode == "resident" and roomy.n_passes == 1
-    tight = plan_votes_routing(600, 4, 80, 10, vmem_budget=150_000, **args)
+    tight = plan_votes_routing(600, 4, 80, 10, vmem_budget=TIGHT, **args)
     # fused s+b pass: W streams once per iteration + the final readout,
     # NOT the old 2-pass schedule's 2*iters+1
     assert tight.mode == "streamed" and tight.n_passes == 3 + 1
-    assert tight.vmem_bytes <= 150_000
-    # the flip is forced: no resident i-tile fits this budget
-    assert execplan._fused_resident_vmem(2, 600, 1, 4, 80, 10) > 150_000
+    assert tight.vmem_bytes <= TIGHT
+    # the flip is forced: not even the smallest lane-legal resident
+    # i-tile fits this budget
+    bi = execplan._min_block_i(600)
+    assert execplan._fused_resident_vmem(2, 600, bi, 4, 80, 10) > TIGHT
 
 
 def test_plan_error_only_when_streamed_block1_unfit():
-    floor = execplan._fused_streamed_vmem(2, 600, 1, 4, 80, 10)
+    bi = execplan._min_block_i(600)
+    floor = execplan._fused_streamed_vmem(2, 600, bi, 4, 80, 10)
     at_floor = plan_votes_routing(600, 4, 80, 10, batch=2,
                                   vmem_budget=floor)
-    assert at_floor.mode == "streamed" and at_floor.block_i == 1
-    with pytest.raises(PlanError, match="streamed block_i=1"):
+    assert at_floor.mode == "streamed" and at_floor.block_i == bi
+    with pytest.raises(PlanError, match=f"streamed block_i={bi}"):
         plan_votes_routing(600, 4, 80, 10, batch=2, vmem_budget=floor - 1)
 
 
@@ -122,15 +132,16 @@ def test_streamed_plan_executes_config_old_path_could_not():
     """num_primary >> budget: the votes (and the old resident-only routing
     state) exceed VMEM, so the pre-fusion path raised; the streamed
     schedule compiles AND matches the jnp reference end to end."""
-    budget = 150_000
+    budget = TIGHT
     plan = compile_plan(NONPOW2, batch=2, vmem_budget=budget)
     fused = plan.op(FUSED_NAME)
     assert fused.mode == "streamed"
     assert fused.vmem_bytes <= budget
-    # the old path's floor: votes resident per batch element
-    dims_votes = NONPOW2.num_primary * NONPOW2.num_classes \
-        * NONPOW2.class_dim * execplan.ELEM_BYTES
-    assert dims_votes > budget
+    # the resident floor: the whole votes tensor in VMEM
+    assert execplan._fused_resident_vmem(
+        2, NONPOW2.num_primary, execplan._min_block_i(NONPOW2.num_primary),
+        NONPOW2.primary_dim, NONPOW2.num_classes * NONPOW2.class_dim,
+        NONPOW2.num_classes) > budget
     params = capsnet.init_params(KEY, NONPOW2)
     imgs = jax.random.uniform(KEY, (2, 15, 15, 1))
     want = capsnet.forward(params, imgs, NONPOW2)
@@ -165,7 +176,7 @@ def test_fused_modes_agree_on_same_network():
 
 
 # ---------------------------------------------------------------------------
-# Fused s+b streamed pass vs the 2-pass oracle (mode="streamed-2pass")
+# Fused s+b streamed pass vs the resident schedule and the reference
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("b,i,c,j,d,bi,iters", [
@@ -175,33 +186,33 @@ def test_fused_modes_agree_on_same_network():
     (2, 27, 4, 4, 8, 8, 1),          # odd non-power-of-two capsule count
     (2, 96, 8, 5, 8, 32, 5),         # deeper iteration count
 ])
-def test_fused_streamed_pass_matches_2pass_oracle(b, i, c, j, d, bi, iters):
+def test_fused_streamed_pass_matches_resident_and_reference(b, i, c, j, d,
+                                                            bi, iters):
     """The one-iteration software pipeline (b-update folded into the
-    s-accumulation stream) is numerically identical to the unfused
-    schedule that streams W separately for each."""
+    s-accumulation stream, votes recomputed per pass) matches the
+    resident schedule (votes computed once) and the jnp reference."""
     u, w = _uv(b, i, c, j * d, seed=i + iters)
     fused = ops.votes_routing(u, w, iters=iters, num_classes=j,
                               mode="streamed", block_i=bi)
-    oracle = ops.votes_routing(u, w, iters=iters, num_classes=j,
-                               mode="streamed-2pass", block_i=bi)
+    resident = ops.votes_routing(u, w, iters=iters, num_classes=j,
+                                 mode="resident", block_i=bi)
     want = ref.routing(ref.caps_votes(u, w).reshape(b, i, j, d),
                        iters).reshape(b, j * d)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(oracle),
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(resident),
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
 
-def test_oracle_mode_never_plan_chosen():
-    """The 2-pass schedule exists only as a test oracle: every plan mode
-    is resident or streamed, and validate() rejects the oracle name."""
-    from repro.kernels.votes_routing import ALL_MODES, MODES, ORACLE_MODE
-    assert ORACLE_MODE not in MODES and ORACLE_MODE in ALL_MODES
-    plan = compile_plan(NONPOW2, batch=2, vmem_budget=150_000)
+def test_plan_modes_are_the_kernel_modes():
+    """Every plan mode is one the kernel runs (resident or streamed), and
+    validate() rejects any other name."""
+    from repro.kernels.votes_routing import MODES
+    plan = compile_plan(NONPOW2, batch=2, vmem_budget=TIGHT)
     assert plan.op(FUSED_NAME).mode in MODES
     import dataclasses
     bad = dataclasses.replace(
-        plan, ops=tuple(dataclasses.replace(op, mode=ORACLE_MODE)
+        plan, ops=tuple(dataclasses.replace(op, mode="streamed-2pass")
                         if op.name == FUSED_NAME else op
                         for op in plan.ops))
     with pytest.raises(PlanError, match="unknown mode"):
@@ -213,22 +224,25 @@ def test_streamed_w_traffic_halved_vs_2pass():
     modeled per-forward savings is exactly iters W sweeps."""
     iters = 3
     tight = plan_votes_routing(600, 4, 80, 10, batch=2, iters=iters,
-                               vmem_budget=150_000)
+                               vmem_budget=TIGHT)
     fused_bytes = votes_routing_hbm_bytes(2, 600, 4, 80, tight.n_passes)
     oracle_bytes = votes_routing_hbm_bytes(2, 600, 4, 80, 2 * iters + 1)
     w_sweep = 600 * 80 * 4 * execplan.ELEM_BYTES
     assert tight.n_passes == iters + 1
     assert oracle_bytes - fused_bytes == iters * w_sweep
     # the plan's streamed ClassCaps-Routing entry models the fused count
-    # at the lowering's padded i-grid (W rows pad to the block_i tiles)
-    plan = compile_plan(NONPOW2, batch=2, vmem_budget=150_000)
+    # at the lowering's padded i-grid (W rows pad to the block_i tiles),
+    # plus the wrapper's relayout of u and W onto lanes
+    plan = compile_plan(NONPOW2, batch=2, vmem_budget=TIGHT)
     fused_op = plan.op(FUSED_NAME)
     assert fused_op.mode == "streamed"
     assert fused_op.uhat_hbm_bytes == 0
     jd = NONPOW2.num_classes * NONPOW2.class_dim
     assert fused_op.hbm_bytes == votes_routing_hbm_bytes(
         2, NONPOW2.num_primary, NONPOW2.primary_dim, jd,
-        NONPOW2.routing_iters + 1, block_i=fused_op.block_i)
+        NONPOW2.routing_iters + 1, block_i=fused_op.block_i
+    ) + execplan.lane_relayout_hbm_bytes(2, NONPOW2.num_primary,
+                                         NONPOW2.primary_dim, jd)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +260,8 @@ def test_plan_reports_zero_uhat_traffic_and_savings():
     assert uhat == 2 * 8 * 1152 * 160 * execplan.ELEM_BYTES
     assert fused.mode == "resident"
     fused_total = votes_routing_hbm_bytes(*dims, n_passes=1)
-    assert fused.hbm_bytes == fused_total
+    relayout = execplan.lane_relayout_hbm_bytes(*dims)
+    assert fused.hbm_bytes == fused_total + relayout
     assert split_total - fused_total == uhat    # savings == the round-trip
 
 
@@ -275,3 +290,97 @@ def test_plan_caches_are_bounded():
     assert ops.planned_block_i.cache_info().maxsize == 64
     assert ops.planned_votes_routing.cache_info().maxsize == 64
     assert ops.planned_conv_blocks.cache_info().maxsize == 64
+
+
+# ---------------------------------------------------------------------------
+# Output capsules on the lanes: the layout wide routing layers plan
+# ---------------------------------------------------------------------------
+
+def _routing_ref(u, w, j):
+    b, i, _ = u.shape
+    uh = jnp.einsum("bic,inc->bin", u, w).reshape(b, i, j, -1)
+    return capsnet.routing_by_agreement(uh, 3).reshape(b, -1)
+
+
+@pytest.mark.parametrize("mode", ["resident", "streamed"])
+@pytest.mark.parametrize("b,i,j,d,bi", [
+    (1, 64, 32, 8, 8),            # divisible i-blocks
+    (2, 100, 16, 4, 16),          # ragged final i-block (100 % 16)
+    (3, 27, 10, 8, 27),           # one block over a non-multiple-of-8 I
+])
+def test_class_lanes_matches_reference_fwd_and_grad(mode, b, i, j, d, bi):
+    u, w = _uv(b, i, 4, j * d, seed=i + j)
+    dv = jax.random.normal(jax.random.fold_in(KEY, 99), (b, j * d))
+
+    def fused(u, w):
+        return ops.votes_routing(u, w, iters=3, num_classes=j, mode=mode,
+                                 block_i=bi, lanes="classes")
+
+    np.testing.assert_allclose(np.asarray(fused(u, w)),
+                               np.asarray(_routing_ref(u, w, j)),
+                               rtol=1e-5, atol=1e-6)
+    got = jax.grad(lambda u, w: jnp.sum(fused(u, w) * dv), (0, 1))(u, w)
+    want = jax.grad(lambda u, w: jnp.sum(_routing_ref(u, w, j) * dv),
+                    (0, 1))(u, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_wide_layers_plan_class_lanes_at_published_widths():
+    """capsnet-cifar10's ResCaps halves (1024 -> 1024 x 8D) have no
+    caps-on-lanes schedule (one 128-capsule W tile is 32 MiB); they plan
+    with the classes on the lanes, forward and backward, while the
+    narrow classification head and MNIST keep the caps layout."""
+    plan = compile_plan(get_config("capsnet-cifar10"), batch=1, train=True)
+    lanes = {op.name: op.lanes for op in plan.ops if op.lanes}
+    assert lanes.pop(FUSED_NAME) == "caps"
+    assert lanes.pop(FUSED_NAME + "-bwd") == "caps"
+    assert set(lanes.values()) == {"classes"} and len(lanes) == 12
+    for op in plan.ops:
+        if op.lanes == "classes":
+            assert op.block_i % 8 == 0 and op.vmem_bytes <= VMEM_BYTES
+    mnist = compile_plan(CapsNetConfig(), batch=8, train=True)
+    assert {op.lanes for op in mnist.ops if op.lanes} == {"caps"}
+
+
+def test_class_lanes_is_the_fallback_under_the_caps_floor():
+    """SVHN's plain 2048 -> 64 x 8D layer at a quarter budget: the
+    caps-on-lanes streamed floor does not fit, the classes layout does."""
+    budget = VMEM_BYTES // 4
+    caps_floor = execplan._fused_streamed_vmem(1, 2048, 128, 8, 512, 64)
+    assert caps_floor > budget
+    sched = plan_votes_routing(2048, 8, 512, 64, batch=1,
+                               vmem_budget=budget)
+    assert sched.lanes == "classes" and sched.mode == "streamed"
+    assert sched.vmem_bytes <= budget and sched.block_i % 8 == 0
+
+
+def test_class_lanes_plan_runs_end_to_end():
+    """A budget that flips the ResCaps halves of a deep stack onto the
+    class-lanes layout changes the schedule, never the math: forward
+    and gradients match the jnp reference through the plan."""
+    cfg = get_smoke_config("capsnet-cifar10")
+    plan = compile_plan(cfg, batch=2, vmem_budget=900_000, train=True)
+    assert any(op.lanes == "classes" for op in plan.ops)
+    params = capsnet.init_params(KEY, cfg)
+    imgs = jax.random.uniform(KEY, (2, cfg.image_hw, cfg.image_hw,
+                                    cfg.in_channels))
+    labels = jnp.array([1, 7])
+    got = capsnet.forward(params, imgs, cfg, backend="pallas", plan=plan)
+    want = capsnet.forward(params, imgs, cfg)
+    np.testing.assert_allclose(np.asarray(got["lengths"]),
+                               np.asarray(want["lengths"]),
+                               rtol=1e-4, atol=1e-5)
+
+    def loss(p, backend):
+        kw = dict(plan=plan) if backend == "pallas" else {}
+        return capsnet.total_loss(p, imgs, labels, cfg, backend=backend,
+                                  **kw)[0]
+
+    g_got = jax.grad(loss)(params, "pallas")
+    g_want = jax.grad(loss)(params, "jnp")
+    for a, r in zip(jax.tree_util.tree_leaves(g_got),
+                    jax.tree_util.tree_leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=1e-4, atol=1e-5)
