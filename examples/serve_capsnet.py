@@ -107,7 +107,7 @@ def main() -> None:
     for r in done[:8]:
         print(f"req {r.rid:3d}: pred={r.pred} "
               f"latency={1e3 * r.latency_s:7.2f} ms "
-              f"queued {r.queue_ticks} ticks")
+              f"queued {1e3 * (r.admitted_s - r.submitted_s):7.2f} ms")
     print(f"throughput {s['requests_per_s']:8.1f} req/s   "
           f"occupancy {s['occupancy']:.2f}   "
           f"mean latency {s['mean_latency_ms']:.2f} ms")

@@ -105,7 +105,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro.core import capsnet, execplan, faults
+from repro.core import capsnet, execplan, faults, spans
 from repro.core.capsnet import CapsNetConfig
 from repro.core.execplan import ExecutionPlan, PlanError, compile_plan
 from repro.core.planner import VMEM_BYTES
@@ -128,7 +128,7 @@ class CapsRequest:
     deadline_s: float | None = None    # submit-relative expiry (None: never)
     submitted_s: float | None = None
     finished_s: float | None = None
-    queue_ticks: int = 0               # ticks spent waiting for a slot
+    admitted_s: float | None = None    # when it left the queue for a slot
     retries: int = 0                   # non-finite-output retries consumed
     status: str = "pending"            # -> ok | timeout | error | shed
     lengths: np.ndarray | None = None  # [num_classes] capsule lengths
@@ -341,6 +341,9 @@ class CapsuleEngine:
                 continue
             if self.active[s] is None and self.queue:
                 req = self.queue.popleft()
+                req.admitted_s = self._now()
+                spans.mark("caps.request.queue", req.submitted_s,
+                           req.admitted_s, rid=req.rid)
                 self._batch[s] = req.image        # shape-checked in submit()
                 self._dirty.add(s)
                 self.active[s] = req
@@ -467,8 +470,6 @@ class CapsuleEngine:
 
     # -- main loop -------------------------------------------------------
     def _end_tick(self, act_count: int, poisoned: bool = False) -> None:
-        for waiting in self.queue:
-            waiting.queue_ticks += 1
         self.ticks += 1
         self._occupancy += act_count
         self._clean_streak = 0 if poisoned else self._clean_streak + 1
@@ -477,129 +478,137 @@ class CapsuleEngine:
     def step(self) -> int:
         """One engine tick: fault reactions, deadline sweep, admit, then
         classify all dispatchable slots.  Returns the number of requests
-        that reached ``ok`` this tick."""
-        if self._started_s is None:
-            self._started_s = self._now()
-        self._sweep_deadlines(self._now())
-        self._maybe_lift_quarantine()
-        self._admit()
-        # Tick faults land AFTER admission (slot_corrupt must see the
-        # rows resident this tick) and BEFORE dispatch (a vmem_shrink
-        # replan swaps the plan at the tick boundary, never mid-forward).
-        if faults.enabled():
-            self._apply_tick_faults(self.ticks)
-        if self._stall_pending:
-            # Injected stall: the tick passes with no dispatch (run()'s
-            # zero-progress detection is the guardrail).
-            self._stall_pending = False
-            self._end_tick(0)
-            return 0
-        if self.queue and len(self.quarantined) == self.slots:
-            # Every lane is quarantined: the backlog can never be served.
-            # Shed it (terminal status) instead of spinning until the
-            # stall detector fires.
-            while self.queue:
-                self._finish(self.queue.popleft(), "shed")
-        act = [s for s in range(self.slots)
-               if self.active[s] is not None
-               and self._backoff_until[s] <= self.ticks]
-        if not act:
-            if any(a is not None for a in self.active) or self.queue:
-                self._end_tick(0)        # backed-off slots need time to pass
-            return 0
-        if self._dirty:
-            self._upload_dirty()
-        # Fixed-size index: the active slots, padded by repeating the
-        # first (result rows not named in ``pos`` are ignored).  Under a
-        # mesh the index is built PER SHARD in shard-local coordinates
-        # (shard_map hands each device its own [slots_per_shard] block),
-        # and ``pos`` maps slot -> global result row either way.
-        pos: dict[int, int] = {}
-        if self.mesh is None:
-            idx = np.full(self.slots, act[0], np.int32)
-            idx[:len(act)] = act
-            pos = {s: i for i, s in enumerate(act)}
-        else:
-            sps = self.slots_per_shard
-            idx = np.zeros(self.slots, np.int32)
-            for shard in range(self.n_shards):
-                base = shard * sps
-                local = [s for s in act if base <= s < base + sps]
-                idx[base:base + sps] = (local[0] - base) if local else 0
-                for k, s in enumerate(local):
-                    idx[base + k] = s - base
-                    pos[s] = base + k
-        try:
-            if faults.enabled() and faults.poll(
-                    faults.SITE_ENGINE_FORWARD, index=self.ticks,
-                    kinds=("plan_error",)):
-                raise PlanError(
-                    f"injected plan_error at {faults.SITE_ENGINE_FORWARD} "
-                    f"(tick {self.ticks})")
-            lengths, preds = jax.device_get(
-                self._forward(self.params, self._batch_dev, jnp.asarray(idx)))
-            self._breaker_fails = 0
-        except Exception:
-            # One forward failure loses one tick, never the engine:
-            # consecutive failures trip the breaker onto the reference
-            # backend (re-traced once) and the engine keeps serving.
-            self._counters["forward_failures"] += 1
-            self._breaker_fails += 1
-            if self._breaker_fails >= self.breaker_after:
-                self._trip_breaker()
-            self._end_tick(0)
-            return 0
-        if faults.enabled():
-            for spec in faults.poll(faults.SITE_ENGINE_FORWARD,
-                                    index=self.ticks,
-                                    kinds=("nan_output", "inf_output")):
-                fill = np.nan if spec.kind == "nan_output" else np.inf
-                lengths = np.full_like(lengths, fill)
-        done = 0
-        poisoned_tick = False
-        for s in act:
-            req = self.active[s]
-            row = lengths[pos[s]]
-            shard = self._shard_of(s)
-            if not np.all(np.isfinite(row)):
-                poisoned_tick = True
-                self._counters["poisoned"] += 1
-                self._poison_streak[s] += 1
-                if self._poison_streak[s] >= self.quarantine_after:
-                    # K consecutive poisoned results through one lane:
-                    # the slot is quarantined (probation may lift it
-                    # later), the request errors out.
-                    self.quarantined.add(s)
-                    self._finish(req, "error", shard)
+        that reached ``ok`` this tick.  Under a profiler trace the tick
+        and its phases are ``caps.tick*`` spans (``repro.core.spans``)."""
+        with spans.span("caps.tick"):
+            if self._started_s is None:
+                self._started_s = self._now()
+            with spans.span("caps.tick.admit"):
+                self._sweep_deadlines(self._now())
+                self._maybe_lift_quarantine()
+                self._admit()
+            # Tick faults land AFTER admission (slot_corrupt must see the
+            # rows resident this tick) and BEFORE dispatch (a vmem_shrink
+            # replan swaps the plan at the tick boundary, never mid-forward).
+            if faults.enabled():
+                self._apply_tick_faults(self.ticks)
+            if self._stall_pending:
+                # Injected stall: the tick passes with no dispatch (run()'s
+                # zero-progress detection is the guardrail).
+                self._stall_pending = False
+                self._end_tick(0)
+                return 0
+            if self.queue and len(self.quarantined) == self.slots:
+                # Every lane is quarantined: the backlog can never be served.
+                # Shed it (terminal status) instead of spinning until the
+                # stall detector fires.
+                while self.queue:
+                    self._finish(self.queue.popleft(), "shed")
+            act = [s for s in range(self.slots)
+                   if self.active[s] is not None
+                   and self._backoff_until[s] <= self.ticks]
+            if not act:
+                if any(a is not None for a in self.active) or self.queue:
+                    self._end_tick(0)        # backed-off slots need time to pass
+                return 0
+            with spans.span("caps.tick.upload"):
+                if self._dirty:
+                    self._upload_dirty()
+            # Fixed-size index: the active slots, padded by repeating the
+            # first (result rows not named in ``pos`` are ignored).  Under a
+            # mesh the index is built PER SHARD in shard-local coordinates
+            # (shard_map hands each device its own [slots_per_shard] block),
+            # and ``pos`` maps slot -> global result row either way.
+            pos: dict[int, int] = {}
+            if self.mesh is None:
+                idx = np.full(self.slots, act[0], np.int32)
+                idx[:len(act)] = act
+                pos = {s: i for i, s in enumerate(act)}
+            else:
+                sps = self.slots_per_shard
+                idx = np.zeros(self.slots, np.int32)
+                for shard in range(self.n_shards):
+                    base = shard * sps
+                    local = [s for s in act if base <= s < base + sps]
+                    idx[base:base + sps] = (local[0] - base) if local else 0
+                    for k, s in enumerate(local):
+                        idx[base + k] = s - base
+                        pos[s] = base + k
+            try:
+                if faults.enabled() and faults.poll(
+                        faults.SITE_ENGINE_FORWARD, index=self.ticks,
+                        kinds=("plan_error",)):
+                    raise PlanError(
+                        f"injected plan_error at {faults.SITE_ENGINE_FORWARD} "
+                        f"(tick {self.ticks})")
+                with spans.span("caps.tick.dispatch"):
+                    out = self._forward(self.params, self._batch_dev,
+                                        jnp.asarray(idx))
+                with spans.span("caps.tick.fetch"):
+                    lengths, preds = jax.device_get(out)
+                self._breaker_fails = 0
+            except Exception:
+                # One forward failure loses one tick, never the engine:
+                # consecutive failures trip the breaker onto the reference
+                # backend (re-traced once) and the engine keeps serving.
+                self._counters["forward_failures"] += 1
+                self._breaker_fails += 1
+                if self._breaker_fails >= self.breaker_after:
+                    self._trip_breaker()
+                self._end_tick(0)
+                return 0
+            if faults.enabled():
+                for spec in faults.poll(faults.SITE_ENGINE_FORWARD,
+                                        index=self.ticks,
+                                        kinds=("nan_output", "inf_output")):
+                    fill = np.nan if spec.kind == "nan_output" else np.inf
+                    lengths = np.full_like(lengths, fill)
+            with spans.span("caps.tick.finish"):
+                done = 0
+                poisoned_tick = False
+                for s in act:
+                    req = self.active[s]
+                    row = lengths[pos[s]]
+                    shard = self._shard_of(s)
+                    if not np.all(np.isfinite(row)):
+                        poisoned_tick = True
+                        self._counters["poisoned"] += 1
+                        self._poison_streak[s] += 1
+                        if self._poison_streak[s] >= self.quarantine_after:
+                            # K consecutive poisoned results through one lane:
+                            # the slot is quarantined (probation may lift it
+                            # later), the request errors out.
+                            self.quarantined.add(s)
+                            self._finish(req, "error", shard)
+                            self._clear_slot(s)
+                        elif self._expired(req):
+                            # The deadline passed while the slot sat in retry
+                            # backoff: terminate as timeout instead of burning
+                            # another dispatch on a dead request.
+                            self._finish(req, "timeout", shard)
+                            self._clear_slot(s)
+                        elif req.retries < self.max_retries:
+                            req.retries += 1
+                            self._counters["retries"] += 1
+                            # Backoff grows with the retry count; the clean host
+                            # image is re-uploaded (heals device corruption).
+                            self._backoff_until[s] = (self.ticks + 1
+                                                      + self.retry_backoff_ticks
+                                                      * req.retries)
+                            self._batch[s] = req.image
+                            self._dirty.add(s)
+                        else:
+                            self._finish(req, "error", shard)
+                            self._clear_slot(s)
+                        continue
+                    self._poison_streak[s] = 0
+                    req.lengths = row
+                    req.pred = int(preds[pos[s]])
+                    self._finish(req, "ok", shard)
                     self._clear_slot(s)
-                elif self._expired(req):
-                    # The deadline passed while the slot sat in retry
-                    # backoff: terminate as timeout instead of burning
-                    # another dispatch on a dead request.
-                    self._finish(req, "timeout", shard)
-                    self._clear_slot(s)
-                elif req.retries < self.max_retries:
-                    req.retries += 1
-                    self._counters["retries"] += 1
-                    # Backoff grows with the retry count; the clean host
-                    # image is re-uploaded (heals device corruption).
-                    self._backoff_until[s] = (self.ticks + 1
-                                              + self.retry_backoff_ticks
-                                              * req.retries)
-                    self._batch[s] = req.image
-                    self._dirty.add(s)
-                else:
-                    self._finish(req, "error", shard)
-                    self._clear_slot(s)
-                continue
-            self._poison_streak[s] = 0
-            req.lengths = row
-            req.pred = int(preds[pos[s]])
-            self._finish(req, "ok", shard)
-            self._clear_slot(s)
-            done += 1
-        self._end_tick(len(act), poisoned=poisoned_tick)
-        return done
+                    done += 1
+                self._end_tick(len(act), poisoned=poisoned_tick)
+            return done
 
     def run(self, max_ticks: int | None = None) -> list[CapsRequest]:
         """Drive ticks until every request is terminal.  ``max_ticks``

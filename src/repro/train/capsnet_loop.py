@@ -34,7 +34,7 @@ import dataclasses
 import jax
 import numpy as np
 
-from repro.core import capsnet
+from repro.core import capsnet, spans
 from repro.core.capsnet import CapsNetConfig
 from repro.core.compile_cache import enable_compile_cache
 from repro.core.execplan import compile_plan
@@ -149,14 +149,15 @@ class CapsTrainLoop(FaultTolerantLoop):
                            channels=self.cfg.in_channels)
 
     def _run_step(self, state: dict, batch) -> tuple[dict, dict]:
-        if "opt" in state:
-            params, opt, metrics = self._step_fn(
-                state["params"], state["opt"],
-                batch["images"], batch["labels"])
-            return {"params": params, "opt": opt}, metrics
-        params, metrics = self._step_fn(state["params"], batch["images"],
-                                        batch["labels"])
-        return {"params": params}, metrics
+        with spans.span("caps.train.dispatch"):
+            if "opt" in state:
+                params, opt, metrics = self._step_fn(
+                    state["params"], state["opt"],
+                    batch["images"], batch["labels"])
+                return {"params": params, "opt": opt}, metrics
+            params, metrics = self._step_fn(state["params"], batch["images"],
+                                            batch["labels"])
+            return {"params": params}, metrics
 
     def _extra_record(self, metrics: dict) -> dict:
         rec = {"accuracy": float(jax.device_get(metrics["accuracy"]))}
