@@ -7,12 +7,14 @@ normal entry points, with every Pallas kernel compiled by Mosaic.
 The serve phase runs ``CapsuleEngine(backend="pallas")`` on its default
 (pipelined) plan at capsnet-mnist's published widths and checks every
 request's capsule lengths against the jnp reference at "highest" matmul
-precision.  The train phase takes ``CapsTrainLoop`` steps with SGD and
-checks the first loss against the same reference.  The wide-layer phase
-runs one capsnet-cifar10 ResCaps half at published widths (1024 capsules
-routed into 1024 x 8D) on its train plan's schedule -- the routing
-kernels with the output capsules on the lanes -- forward and gradient
-against the jnp reference.  ``--chips 4`` runs
+precision; before it, the patches phase checks that ``im2col_patches``
+equals the plain extraction bit for bit on the chip at capsnet-mnist's
+Conv1 and PrimaryCaps shapes.  The train phase takes ``CapsTrainLoop``
+steps with SGD and checks the first loss against the same reference.
+The wide-layer phase runs one capsnet-cifar10 ResCaps half at published
+widths (1024 capsules routed into 1024 x 8D) on its train plan's
+schedule -- the routing kernels with the output capsules on the lanes --
+forward and gradient against the jnp reference.  ``--chips 4`` runs
 only the sharded engine (``n_shards=4``) against a one-device engine on
 the same requests.  Params and images are random, made from ``--seed``.
 
@@ -161,6 +163,31 @@ def serve_phase(cfg, params, images, *, slots: int = SERVE_SLOTS,
     worst = compare_lengths(results, reference_lengths(params, images, cfg))
     log(f"serve max |lengths - reference| = {worst:.3e}")
     return worst
+
+
+def patches_phase(cfg, seed: int, batch: int = TRAIN_BATCH) -> None:
+    """``im2col_patches`` vs ``_patches_xla``, bit for bit, at Conv1's and
+    PrimaryCaps' input shapes: each channel width's formulation as XLA
+    compiles it for the chip."""
+    import jax
+    import numpy as np
+    from repro.kernels.conv_im2col import _patches_xla, im2col_patches
+    c1 = cfg.conv1_out
+    layers = {"Conv1": ((batch, cfg.image_hw, cfg.image_hw,
+                         cfg.in_channels), cfg.conv1_kernel, 1),
+              "PrimaryCaps": ((batch, c1, c1, cfg.conv1_channels),
+                              cfg.pc_kernel, cfg.pc_stride)}
+    key = jax.random.PRNGKey(seed)
+    for name, (shape, k, stride) in layers.items():
+        x = jax.random.normal(key, shape)
+        got, want = jax.device_get((
+            im2col_patches(x, kh=k, kw=k, stride=stride),
+            jax.jit(_patches_xla, static_argnums=(1, 2, 3))(x, k, k, stride)))
+        same = got.shape == want.shape and np.array_equal(
+            got.view(np.uint32), want.view(np.uint32))
+        check(same, f"{name} patches {shape} k{k} s{stride} differ from the "
+                    f"plain extraction on the chip")
+        log(f"patches {name} {shape} k{k} s{stride}: bitwise equal")
 
 
 def check_no_kernel_warnings(caught) -> None:
@@ -359,6 +386,7 @@ def main(argv: list[str] | None = None) -> int:
         if args.chips == SHARDS:
             sharded_phase(cfg, params, images)
         else:
+            patches_phase(cfg, args.seed)
             serve_phase(cfg, params, images)
             train_phase(cfg, args.seed)
             wide_layer_phase(args.seed)

@@ -6,7 +6,14 @@ sizes the on-chip memories from that schedule.  These kernels are the TPU
 translation, in two stages:
 
   1. ``im2col_patches``: patch extraction by XLA into the
-     [B, OH*OW, KH*KW*C] patch matrix in HBM.
+     [B, OH*OW, KH*KW*C] patch matrix in HBM.  No tap is written as a
+     width-C lane piece, and none through a [..., KH*KW, C] intermediate
+     that a relayout must then turn into [B, P, K].  The formulation
+     follows the channel width C: a C that is not a multiple of 128
+     (Conv1's C=1, or 3) goes by pixel rows, each row's (W, C) pair
+     merged onto the lanes and cut into KW*C-wide windows; a lane-dense C
+     (PrimaryCaps' C=256) splits the stride phases once and concatenates
+     each tap's unit-stride [B, P, C] slab on the lanes.
 
   2. ``matmul_bias_act`` (Pallas): blocked [M, K] x [K, N] matmul over the plan's
      ``block_m/k/n`` grid tiles with a fused epilogue (bias + ReLU for
@@ -48,13 +55,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.capsnet import SQUASH_EPS
 from repro.core.capsnet import squash as squash_reference
-from repro.core.planner import VMEM_LIMIT_BYTES
+from repro.core.planner import LANES, VMEM_LIMIT_BYTES
 
 EPILOGUES = ("none", "relu", "squash")
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _patches_xla(x: jax.Array, kh: int, kw: int, stride: int) -> jax.Array:
+    """The plain extraction: every tap sliced, stacked, reshaped.  Kept as
+    the reference of ``im2col_patches`` and as the function whose VJP is
+    ``col2im_patches``."""
     b, h, w, c = x.shape
     oh = (h - kh) // stride + 1
     ow = (w - kw) // stride + 1
@@ -62,6 +72,42 @@ def _patches_xla(x: jax.Array, kh: int, kw: int, stride: int) -> jax.Array:
               j:j + (ow - 1) * stride + 1:stride, :]
             for i in range(kh) for j in range(kw)]
     return jnp.stack(taps, axis=3).reshape(b, oh * ow, kh * kw * c)
+
+
+def _patches_by_rows(x: jax.Array, kh: int, kw: int,
+                     stride: int) -> jax.Array:
+    """Narrow channels: each pixel row's (W, C) pair goes onto the lanes.
+    The KW taps of kernel row ``i`` at output column ``q`` are then one
+    contiguous KW*C-lane window of that row, from lane ``q*stride*C``."""
+    b, h, w, c = x.shape
+    oh = (h - kh) // stride + 1
+    ow = (w - kw) // stride + 1
+    rows = x.reshape(b, h, w * c)
+    per_row = []
+    for i in range(kh):
+        r = rows[:, i:i + (oh - 1) * stride + 1:stride]           # [B,OH,W*C]
+        per_row.append(jnp.stack(
+            [r[:, :, q * stride * c:(q * stride + kw) * c]
+             for q in range(ow)], axis=2))                         # [B,OH,OW,KW*C]
+    return jnp.concatenate(per_row, axis=-1).reshape(b, oh * ow, kh * kw * c)
+
+
+def _patches_by_slabs(x: jax.Array, kh: int, kw: int,
+                      stride: int) -> jax.Array:
+    """Lane-dense channels: split the stride phases once, so every tap is
+    a unit-stride [B, P, C] slab, and concatenate the slabs on the lanes
+    -- the patch matrix is born in its [B, P, K] layout."""
+    b, h, w, c = x.shape
+    s = stride
+    oh = (h - kh) // s + 1
+    ow = (w - kw) // s + 1
+    hp, wp = -(-h // s) * s, -(-w // s) * s
+    x = jnp.pad(x, ((0, 0), (0, hp - h), (0, wp - w), (0, 0)))
+    phases = x.reshape(b, hp // s, s, wp // s, s, c).transpose(2, 4, 0, 1, 3, 5)
+    taps = [phases[i % s, j % s, :, i // s:i // s + oh, j // s:j // s + ow]
+            .reshape(b, oh * ow, c)
+            for i in range(kh) for j in range(kw)]
+    return jnp.concatenate(taps, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("kh", "kw", "stride"))
@@ -73,9 +119,13 @@ def im2col_patches(x: jax.Array, *, kh: int, kw: int,
     ``w.reshape(KH*KW*C, Cout)`` of an HWIO weight tensor.  XLA extracts
     the patches: the matrix crosses HBM once either way (the matmul
     streams it in K tiles), and strided tap windows are not a Mosaic
-    layout.
+    layout.  Both formulations only move data, so the patches equal
+    ``_patches_xla``'s bit for bit; the channel width picks the one whose
+    writes are tile-dense (module docstring, stage 1).
     """
-    return _patches_xla(x, kh, kw, stride)
+    if x.shape[-1] % LANES:
+        return _patches_by_rows(x, kh, kw, stride)
+    return _patches_by_slabs(x, kh, kw, stride)
 
 
 def _matmul_kernel(p_ref, w_ref, b_ref, o_ref, *, k_steps: int,

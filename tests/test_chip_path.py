@@ -99,6 +99,22 @@ def test_chip_smoke_phases_at_tiny_size(chip_smoke, tmp_path, monkeypatch):
                                   want_batch=8) <= chip_smoke.LOSS_RTOL
 
 
+def test_chip_smoke_patches_phase_at_tiny_size(chip_smoke, monkeypatch):
+    """The bitwise patch check passes on the real formulations and fails
+    on patches that differ from the plain extraction in one entry."""
+    from repro.kernels import conv_im2col
+    cfg = capsnet_mnist.smoke_config()
+    chip_smoke.patches_phase(cfg, 0, batch=2)
+    real = conv_im2col.im2col_patches
+
+    def off_by_one_entry(x, **kw):
+        return real(x, **kw).at[0, 0, 0].add(1.0)
+
+    monkeypatch.setattr(conv_im2col, "im2col_patches", off_by_one_entry)
+    with pytest.raises(chip_smoke.SmokeFailure, match="patches"):
+        chip_smoke.patches_phase(cfg, 0, batch=2)
+
+
 def test_chip_smoke_wide_layer_phase_at_tiny_size(chip_smoke):
     """The wide-layer phase on a smoke-width ResCaps stack whose budget
     puts the output capsules on the lanes, kernels interpreted."""
