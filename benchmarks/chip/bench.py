@@ -166,6 +166,7 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
     try:
         cell = spec.load_cell(args.workload)
+        program.capsnet_config(cell.sizes)   # refuses a key it cannot take
         devices = tpu_devices(cell.chips)
     except (spec.SpecError, NoChip) as e:
         print(f"error: {e}", file=sys.stderr)
