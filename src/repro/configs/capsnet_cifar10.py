@@ -1,12 +1,14 @@
-"""CapsuleNet on CIFAR-10: a DEEP residual capsule stack.
+"""A DEEP residual capsule stack at CIFAR-10's 32x32x3 input.
 
-The 32x32x3 variant the CapsuleNet literature scales to (Sabour et al.
-report 10.6% CIFAR-10 error with an ensemble; MoCapsNet-style residual
-routing blocks are what make *depth* affordable).  Three reversible
-``ResCapsBlock``s sit between PrimaryCaps and ClassCaps, so this config
-exercises the layer-graph plan compiler: per-layer fused megakernel ops,
-per-instance PMU phases, and the flat-in-depth reversible backward.
-Selectable via ``--arch capsnet-cifar10``.
+This repository's own network, published nowhere: Sabour et al.'s
+Conv1 and PrimaryCaps widths on full 32x32x3 images, with three
+reversible MoCapsNet-style ``ResCapsBlock``s between PrimaryCaps and
+ClassCaps (residual routing blocks are what make *depth* affordable).
+It exercises the layer-graph plan compiler: per-layer fused megakernel
+ops, per-instance PMU phases, and the flat-in-depth reversible backward.
+Sabour et al.'s own CIFAR-10 network (24x24x3 patches, 64 primary
+capsule types, a none-of-the-above routing category) is
+``capsnet-cifar10-sabour``.  Selectable via ``--arch capsnet-cifar10``.
 """
 
 from repro.core.capsnet import CapsNetConfig, ResCapsBlock

@@ -18,6 +18,7 @@ _MODULES = {
     "capsnet-mnist": "capsnet_mnist",
     "capsnet-cifar10": "capsnet_cifar10",
     "capsnet-svhn": "capsnet_svhn",
+    "capsnet-cifar10-sabour": "capsnet_cifar10_sabour",
 }
 
 # Short aliases accepted on the CLI (underscore spellings included, so
@@ -29,6 +30,7 @@ _ALIASES = {
     "capsnet_mnist": "capsnet-mnist",
     "capsnet_cifar10": "capsnet-cifar10",
     "capsnet_svhn": "capsnet-svhn",
+    "capsnet_cifar10_sabour": "capsnet-cifar10-sabour",
 }
 
 # The LM benchmark pool: every arch that is not a CapsuleNet workload.

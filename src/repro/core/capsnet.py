@@ -81,6 +81,7 @@ class RoutingLayer:
     iters: int
     block: int | None = None     # caps_layers entry index (residual only)
     half: str | None = None      # "f" | "g" coupling half
+    nota: bool = False           # none-of-the-above category in routing
 
     @property
     def jd(self) -> int:
@@ -111,6 +112,11 @@ class CapsNetConfig:
     # entries.  Empty (the default) is the paper's fixed 3-op topology --
     # plans, params, and outputs are bit-identical to the pre-graph code.
     caps_layers: tuple = ()
+    # Sabour et al.'s CIFAR-10 "none-of-the-above" category: a fixed zero
+    # logit joins every routing softmax over the output capsules and its
+    # share is dropped (``couplings``), so a capsule may route part of
+    # itself nowhere.  Off (the default) is the plain softmax.
+    none_of_the_above: bool = False
 
     @property
     def conv1_out(self) -> int:
@@ -133,6 +139,7 @@ class CapsNetConfig:
         resolved routing-layer chain (see ``RoutingLayer``)."""
         layers: list[RoutingLayer] = []
         i, c = self.num_primary, self.primary_dim
+        nota = self.none_of_the_above
         idx = 0
         for k, entry in enumerate(self.caps_layers):
             if isinstance(entry, ResCapsBlock):
@@ -144,12 +151,14 @@ class CapsNetConfig:
                 layers.append(RoutingLayer(
                     name=f"{ROUTING_NAME}[{idx}]", param=f"cc{idx}_w",
                     in_caps=i2, in_dim=c, num_caps=i1, caps_dim=c,
-                    iters=entry.routing_iters, block=k, half="f"))
+                    iters=entry.routing_iters, block=k, half="f",
+                    nota=nota))
                 idx += 1
                 layers.append(RoutingLayer(
                     name=f"{ROUTING_NAME}[{idx}]", param=f"cc{idx}_w",
                     in_caps=i1, in_dim=c, num_caps=i2, caps_dim=c,
-                    iters=entry.routing_iters, block=k, half="g"))
+                    iters=entry.routing_iters, block=k, half="g",
+                    nota=nota))
                 idx += 1
             elif isinstance(entry, CapsLayerSpec):
                 if entry.num_caps < 1 or entry.caps_dim < 1:
@@ -159,7 +168,8 @@ class CapsNetConfig:
                 layers.append(RoutingLayer(
                     name=f"{ROUTING_NAME}[{idx}]", param=f"cc{idx}_w",
                     in_caps=i, in_dim=c, num_caps=entry.num_caps,
-                    caps_dim=entry.caps_dim, iters=entry.routing_iters))
+                    caps_dim=entry.caps_dim, iters=entry.routing_iters,
+                    nota=nota))
                 idx += 1
                 i, c = entry.num_caps, entry.caps_dim
             else:
@@ -169,7 +179,7 @@ class CapsNetConfig:
         layers.append(RoutingLayer(
             name=ROUTING_NAME, param="cc_w", in_caps=i, in_dim=c,
             num_caps=self.num_classes, caps_dim=self.class_dim,
-            iters=self.routing_iters))
+            iters=self.routing_iters, nota=nota))
         return tuple(layers)
 
 
@@ -231,13 +241,32 @@ def compute_votes(u: jax.Array, cc_w: jax.Array) -> jax.Array:
     return jnp.einsum("bic,ijdc->bijd", u, cc_w)
 
 
-def routing_by_agreement(u_hat: jax.Array, iters: int) -> jax.Array:
-    """Dynamic routing (paper Fig. 2 feedback loop).  u_hat: [B, I, J, D]."""
+def couplings(b: jax.Array, axis: int = -1,
+              nota: bool = False) -> jax.Array:
+    """Routing couplings: a softmax of the logits over the output capsules.
+
+    With ``nota`` (the none-of-the-above category of Sabour et al.'s
+    CIFAR-10 network) a fixed zero logit joins the softmax and its column
+    is dropped: ``c_j = exp(b_j) / (1 + sum_k exp(b_k))``, computed from
+    ``m = max(0, max_k b_k)`` so that no exponent overflows.  The result
+    does not depend on ``m``, so no gradient flows through it."""
+    if not nota:
+        return jax.nn.softmax(b, axis=axis)
+    m = jax.lax.stop_gradient(jnp.maximum(
+        jnp.max(b, axis=axis, keepdims=True), 0.0))
+    e = jnp.exp(b - m)
+    return e / (jnp.exp(-m) + jnp.sum(e, axis=axis, keepdims=True))
+
+
+def routing_by_agreement(u_hat: jax.Array, iters: int, *,
+                         nota: bool = False) -> jax.Array:
+    """Dynamic routing (paper Fig. 2 feedback loop).  u_hat: [B, I, J, D].
+    ``nota`` adds the none-of-the-above category (``couplings``)."""
     b0 = jnp.zeros(u_hat.shape[:3], u_hat.dtype)          # logits b[b, i, j]
     u_hat_ng = jax.lax.stop_gradient(u_hat)
 
     def body(it, b):
-        c = jax.nn.softmax(b, axis=2)                     # over classes j
+        c = couplings(b, axis=2, nota=nota)               # over classes j
         # Sum+Squash: s[b, j, d] = sum_i c * u_hat
         uh = jnp.where(it < iters - 1, 0.0, 1.0)          # scalar gate
         u_used = u_hat_ng + uh * (u_hat - u_hat_ng)       # grads last iter only
@@ -247,7 +276,7 @@ def routing_by_agreement(u_hat: jax.Array, iters: int) -> jax.Array:
         return b + jnp.einsum("bijd,bjd->bij", u_hat_ng, v)
 
     b = jax.lax.fori_loop(0, iters, body, b0)
-    c = jax.nn.softmax(b, axis=2)
+    c = couplings(b, axis=2, nota=nota)
     return squash(jnp.einsum("bij,bijd->bjd", c, u_hat))  # v[b, j, d]
 
 
@@ -269,13 +298,16 @@ def routing_stack_ref(params: Params, u: jax.Array,
             g_lay = stack[k + 1]
             x1, x2 = h[:, :lay.num_caps], h[:, lay.num_caps:]
             y1 = x1 + routing_by_agreement(
-                compute_votes(x2, params[lay.param]), lay.iters)
+                compute_votes(x2, params[lay.param]), lay.iters,
+                nota=lay.nota)
             y2 = x2 + routing_by_agreement(
-                compute_votes(y1, params[g_lay.param]), g_lay.iters)
+                compute_votes(y1, params[g_lay.param]), g_lay.iters,
+                nota=g_lay.nota)
             h, k = jnp.concatenate([y1, y2], axis=1), k + 2
         else:
             h = routing_by_agreement(
-                compute_votes(h, params[lay.param]), lay.iters)
+                compute_votes(h, params[lay.param]), lay.iters,
+                nota=lay.nota)
             k += 1
     return h
 

@@ -115,6 +115,11 @@ class OpPlan:
     # static auditor (``repro.verify.lowering``) can diff it against
     # the pass count DERIVED from the lowering's index maps.
     n_passes: int | None = None
+    # The routing kernels' none-of-the-above category
+    # (``CapsNetConfig.none_of_the_above``), carried to the wrappers.  It
+    # adds no scratch and no bytes: every footprint and traffic model is
+    # the same with it on or off.
+    nota: bool = False
 
     @property
     def profile(self) -> OperationProfile:
@@ -1333,7 +1338,7 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
         ops.append(OpPlan(
             name=lay.name, kernel="votes_routing", workload=sched.workload,
             block=None, block_i=sched.block_i, mode=sched.mode,
-            lanes=sched.lanes, n_passes=sched.n_passes,
+            lanes=sched.lanes, n_passes=sched.n_passes, nota=lay.nota,
             vmem_bytes=sched.vmem_bytes,
             est_cycles=votes_cycles * sched.n_passes + routing_cycles,
             hbm_bytes=hbm,
@@ -1376,6 +1381,7 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
             workload=pipe_sched.workload, block=pipe_sched.block,
             block_i=pipe_sched.block_i, block_k=pipe_sched.block_k,
             mode=pipe_sched.mode, lanes="caps", n_passes=pipe_sched.n_passes,
+            nota=first.nota,
             vmem_bytes=pipe_sched.vmem_bytes,
             est_cycles=(prod_cycles + first_votes * pipe_sched.n_passes
                         + first_routing),
@@ -1445,6 +1451,7 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                 workload=bwd_sched.workload, block=None,
                 block_i=bwd_sched.block_i, mode=bwd_sched.mode,
                 lanes=bwd_sched.lanes, n_passes=bwd_sched.n_passes,
+                nota=lay.nota,
                 vmem_bytes=vmem,
                 est_cycles=est,
                 hbm_bytes=hbm,
@@ -1470,8 +1477,9 @@ def compile_plan(cfg: CapsNetConfig = CapsNetConfig(), *, batch: int = 1,
                 workload=wl, block=fwd.block, block_rows=fwd.block_rows,
                 # The at_b/dpatches matmuls' two bm-tall streams can
                 # exceed the forward tiles' peak (measured by the static
-                # auditor).
-                vmem_bytes=max(fwd.vmem_bytes,
+                # auditor); the forward's own peak counts only where the
+                # backward replays the forward matmul.
+                vmem_bytes=max(fwd.vmem_bytes if matmuls == 3 else 0,
                                _conv_bwd_matmul_vmem(fwd.block, wl.m,
                                                      wl.k, wl.n)),
                 est_cycles=matmuls * fwd.est_cycles,

@@ -25,11 +25,15 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.primary_routing import \
     primary_caps_routing as _primary_routing
+from repro.kernels.primary_routing import \
+    primary_caps_routing_nota as _primary_routing_nota
 from repro.kernels.routing import routing as _routing
 from repro.kernels.squash import squash as _squash
 from repro.kernels.votes_routing import \
     res_caps_segment as _res_caps_segment
 from repro.kernels.votes_routing import votes_routing as _votes_routing
+from repro.kernels.votes_routing import \
+    votes_routing_nota as _votes_routing_nota
 
 
 def should_interpret(interpret: bool | None = None) -> bool:
@@ -116,11 +120,14 @@ def caps_votes(u: jax.Array, w: jax.Array, *, plan=None,
 def routing(u_hat: jax.Array, *, plan=None, iters: int | None = None,
             num_classes: int | None = None,
             interpret: bool | None = None) -> jax.Array:
+    """Split-path routing oracle; it has no none-of-the-above category
+    and refuses a plan that asks for one."""
     if iters is None:
         iters = plan.cfg.routing_iters if plan is not None else 3
     if num_classes is None:
         num_classes = plan.cfg.num_classes if plan is not None else 10
-    out = _routing(u_hat, iters=iters, num_classes=num_classes,
+    nota = plan is not None and plan.cfg.none_of_the_above
+    out = _routing(u_hat, iters=iters, num_classes=num_classes, nota=nota,
                    interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_ROUTING, out)
@@ -167,6 +174,7 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
                   mode: str | None = None, block_i: int | None = None,
                   bwd_mode: str | None = None, bwd_block_i: int | None = None,
                   lanes: str | None = None, bwd_lanes: str | None = None,
+                  nota: bool | None = None,
                   interpret: bool | None = None) -> jax.Array:
     """u: [B, I, C], w: [I, J*D, C] -> v: [B, J*D]: fused votes + routing
     (u_hat never leaves the chip).  Schedule (``mode``/``block_i``/
@@ -180,10 +188,13 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
     (``bwd_mode``/``bwd_block_i``) comes from the plan's backward op
     (``compile_plan(train=True)``), falling back to the memoized backward
     plan decision at the plan's VMEM budget -- ``d u_hat`` stays on-chip
-    either way.
+    either way.  ``nota`` (the none-of-the-above category) comes from the
+    plan op unless given; without a plan it is off.
     """
     if op_name is None:
         op_name = execplan.FUSED_NAME
+    if nota is None:
+        nota = plan is not None and plan.op(op_name).nota
     if iters is None:
         iters = plan.cfg.routing_iters if plan is not None else 3
     if num_classes is None:
@@ -240,11 +251,11 @@ def votes_routing(u: jax.Array, w: jax.Array, *, plan=None,
             bwd_mode = bwd_mode or pbmode
             bwd_block_i = bwd_block_i or pbbi
             bwd_lanes = bwd_lanes or pblanes
-    out = _votes_routing(u, w, iters=iters, num_classes=num_classes,
-                         mode=mode, block_i=block_i, bwd_mode=bwd_mode,
-                         bwd_block_i=bwd_block_i, lanes=lanes,
-                         bwd_lanes=bwd_lanes or lanes,
-                         interpret=should_interpret(interpret))
+    kernel = _votes_routing_nota if nota else _votes_routing
+    out = kernel(u, w, iters=iters, num_classes=num_classes, mode=mode,
+                 block_i=block_i, bwd_mode=bwd_mode, bwd_block_i=bwd_block_i,
+                 lanes=lanes, bwd_lanes=bwd_lanes or lanes,
+                 interpret=should_interpret(interpret))
     if faults.enabled():                 # chaos-test site; zero cost when off
         out = faults.corrupt_array(faults.SITE_VOTES_ROUTING, out)
     return out
@@ -272,7 +283,7 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                     mode: str | None = None, block_i: int | None = None,
                     block_k: int | None = None, bwd_mode: str | None = None,
                     bwd_block_i: int | None = None,
-                    bwd_lanes: str | None = None,
+                    bwd_lanes: str | None = None, nota: bool | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """Pipelined PrimaryCaps conv + votes/routing as ONE kernel: x is the
     Conv1 output [B, H, W, Cin], w_pc/b_pc the PrimaryCaps conv params,
@@ -284,10 +295,14 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
     (``compile_plan(pipeline=True)``) or the memoized plan decision.
     Differentiable: the routing-backward schedule resolves exactly like
     ``votes_routing``'s (the pipelined VJP composes the per-op backward
-    kernels, so the plan's backward OpPlans apply unchanged).
+    kernels, so the plan's backward OpPlans apply unchanged).  ``nota``
+    (the none-of-the-above category) comes from the plan op unless given;
+    without a plan it is off.
     """
     if routing_op_name is None:
         routing_op_name = execplan.FUSED_NAME
+    if nota is None:
+        nota = plan is not None and plan.op(execplan.PIPE_NAME).nota
     if stride is None:
         stride = plan.cfg.pc_stride if plan is not None else 2
     if iters is None:
@@ -345,7 +360,8 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
             bwd_mode = bwd_mode or pbmode
             bwd_block_i = bwd_block_i or pbbi
             bwd_lanes = bwd_lanes or pblanes
-    out = _primary_routing(
+    kernel = _primary_routing_nota if nota else _primary_routing
+    out = kernel(
         x, w_pc, b_pc, w_cc, stride=stride, iters=iters,
         num_classes=num_classes, mode=mode, block_i=block_i,
         block_k=block_k, bwd_mode=bwd_mode, bwd_block_i=bwd_block_i,
@@ -357,17 +373,19 @@ def primary_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
 
 
 def _layer_schedule(lay, batch: int, plan) -> tuple[int, int, str, int,
-                                                    str, int, str, str]:
+                                                    str, int, str, str,
+                                                    bool]:
     """Resolve one routing layer's (iters, j, mode, block_i, bwd_mode,
-    bwd_block_i, lanes, bwd_lanes) kernel statics from the plan's
-    per-layer OpPlans (or the memoized plan decision), with the same
-    backward-fallback semantics as ``votes_routing``."""
+    bwd_block_i, lanes, bwd_lanes, nota) kernel statics from the plan's
+    per-layer OpPlans (or the memoized plan decision and the layer), with
+    the same backward-fallback semantics as ``votes_routing``."""
     if plan is not None:
         op = plan.op(lay.name)
-        mode, block_i, lanes = op.mode, op.block_i, op.lanes
+        mode, block_i, lanes, nota = op.mode, op.block_i, op.lanes, op.nota
     else:
         mode, block_i, lanes = planned_votes_routing(
             lay.in_caps, lay.in_dim, lay.jd, lay.num_caps, lay.iters, batch)
+        nota = lay.nota
     budget = plan.vmem_budget if plan is not None else VMEM_BYTES
     if plan is not None and plan.train:
         bwd_op = plan.op(lay.name + execplan.BWD_SUFFIX)
@@ -387,7 +405,7 @@ def _layer_schedule(lay, batch: int, plan) -> tuple[int, int, str, int,
                 f"backward VMEM footprint the plan never validated")
             bwd_mode, bwd_block_i, bwd_lanes = mode, block_i, lanes
     return (lay.iters, lay.num_caps, mode, block_i, bwd_mode, bwd_block_i,
-            lanes, bwd_lanes)
+            lanes, bwd_lanes, nota)
 
 
 def res_caps_segment(x: jax.Array, ws, pairs, *, plan=None,

@@ -103,7 +103,7 @@ def _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr, *,
 def _pipe_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref, pre_scr,
                  u_scr, b_scr, s_scr, v_scr, *votes, k_steps: int,
                  p_pos: int, groups: int, caps_dim: int, n_passes: int,
-                 n_blocks: int, block_i: int, resident: bool):
+                 n_blocks: int, block_i: int, resident: bool, nota: bool):
     """Grid ``k_steps - 1 + n_passes * n_blocks``.  The consume steps are
     ``votes_routing._routing_kernel``'s, reading u from the produce
     scratch instead of an HBM operand.  The first consume block OVERLAPS
@@ -132,7 +132,8 @@ def _pipe_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref, pre_scr,
         else:
             uh4 = _CapsLanes.votes(u_scr[:, :, rows], wcc_ref[...])
         _route_block(_CapsLanes, p, ib, uh4, rows, b_scr, s_scr, v_scr,
-                     o_ref, None, n_passes=n_passes, n_blocks=n_blocks)
+                     o_ref, None, n_passes=n_passes, n_blocks=n_blocks,
+                     nota=nota)
 
 
 # ---------------------------------------------------------------------------
@@ -155,6 +156,7 @@ class _PRStatics(NamedTuple):
     conv_block_n: int
     interpret: bool
     bwd_lanes: str = "caps"  # routing backward layout (votes_routing)
+    nota: bool = False       # none-of-the-above category in the routing
 
 
 def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
@@ -209,7 +211,8 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
     kernel = functools.partial(_pipe_kernel, k_steps=k_steps, p_pos=p_pos,
                                groups=groups, caps_dim=caps_dim,
                                n_passes=n_passes, n_blocks=n_blocks,
-                               block_i=block_i, resident=resident)
+                               block_i=block_i, resident=resident,
+                               nota=st.nota)
     # Produce-phase operands park on their final tile after step
     # k_steps-1 (unchanged block index -> no refetch); W_cc holds its
     # first i-block until the consume steps start walking it.
@@ -260,7 +263,8 @@ def _pr_grad(st: _PRStatics, x, w_pc, b_pc, w_cc, g):
     vr_st = _VRStatics(iters=st.iters, num_classes=st.num_classes,
                        mode=st.bwd_mode, block_i=st.bwd_block_i,
                        bwd_mode=st.bwd_mode, bwd_block_i=st.bwd_block_i,
-                       interpret=st.interpret, bwd_lanes=st.bwd_lanes)
+                       interpret=st.interpret, bwd_lanes=st.bwd_lanes,
+                       nota=st.nota)
     du, dw_cc = _vr_grad(vr_st, u, w_cc, g.astype(jnp.float32))
 
     dpre = pull(du.reshape(m, groups, caps_dim))[0].reshape(m, n_ch)
@@ -298,19 +302,16 @@ def _pr_core_bwd(st: _PRStatics, res, g):
 _pr_core.defvjp(_pr_core_fwd, _pr_core_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "stride", "iters", "num_classes", "mode", "block_i", "block_k",
-    "bwd_mode", "bwd_block_i", "bwd_lanes", "conv_block_m", "conv_block_k",
-    "conv_block_n", "interpret"))
-def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
-                         w_cc: jax.Array, *, stride: int = 2, iters: int = 3,
-                         num_classes: int = 10, mode: str = "resident",
-                         block_i: int = 128, block_k: int = 512,
-                         bwd_mode: str | None = None,
-                         bwd_block_i: int | None = None,
-                         bwd_lanes: str = "caps",
-                         conv_block_m: int = 128, conv_block_k: int = 128,
-                         conv_block_n: int = 128, interpret: bool) -> jax.Array:
+def _primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
+                          w_cc: jax.Array, *, stride: int = 2,
+                          iters: int = 3, num_classes: int = 10,
+                          mode: str = "resident", block_i: int = 128,
+                          block_k: int = 512, bwd_mode: str | None = None,
+                          bwd_block_i: int | None = None,
+                          bwd_lanes: str = "caps", conv_block_m: int = 128,
+                          conv_block_k: int = 128, conv_block_n: int = 128,
+                          nota: bool = False,
+                          interpret: bool) -> jax.Array:
     """x: [B, H, W, Cin] (Conv1 output), w_pc: [KH, KW, Cin, N] HWIO,
     b_pc: [N], w_cc: [I, J*D, C] -> v: [B, J*D].
 
@@ -326,7 +327,8 @@ def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
     Differentiable: the custom VJP replays the producer from patches and
     composes the per-op backward kernels (routing backward per
     ``bwd_mode``/``bwd_block_i``/``bwd_lanes``, conv backward over the
-    ``conv_block_*`` tiles).
+    ``conv_block_*`` tiles).  ``nota`` routes with the none-of-the-above
+    category (``capsnet.couplings``), forward and backward.
     """
     i_dim, jd, caps_dim = w_cc.shape
     kh, kw, _, n_ch = w_pc.shape
@@ -356,5 +358,35 @@ def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
                     bwd_block_i=max(1, min(bwd_block_i or block_i, i_dim)),
                     conv_block_m=conv_block_m, conv_block_k=conv_block_k,
                     conv_block_n=conv_block_n, interpret=interpret,
-                    bwd_lanes=bwd_lanes)
+                    bwd_lanes=bwd_lanes, nota=nota)
     return _pr_core(st, x, w_pc, b_pc, w_cc)
+
+
+_STATIC_ARGNAMES = (
+    "stride", "iters", "num_classes", "mode", "block_i", "block_k",
+    "bwd_mode", "bwd_block_i", "bwd_lanes", "conv_block_m", "conv_block_k",
+    "conv_block_n", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_ARGNAMES)
+def primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
+                         w_cc: jax.Array, **schedule) -> jax.Array:
+    """The pipelined PrimaryCaps -> routing kernel with its custom VJP
+    (``_primary_caps_routing``: operands, ``schedule`` keywords and
+    result), jitted.  A device trace names the call after this jit:
+    ``primary_caps_routing.N``; in training ``jvp_jit_
+    primary_caps_routing__.N``, and the routing backward ``transpose_jvp_
+    jit_primary_caps_routing___.N``."""
+    return _primary_caps_routing(x, w_pc, b_pc, w_cc, nota=False, **schedule)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_ARGNAMES)
+def primary_caps_routing_nota(x: jax.Array, w_pc: jax.Array,
+                              b_pc: jax.Array, w_cc: jax.Array,
+                              **schedule) -> jax.Array:
+    """``primary_caps_routing`` with the none-of-the-above category in the
+    routing, forward and backward, under a jit of its own name so that a
+    trace tells it apart (``primary_caps_routing_nota.N``; in training
+    ``jvp_jit_primary_caps_routing_nota__.N`` and ``transpose_jvp_jit_
+    primary_caps_routing_nota___.N``)."""
+    return _primary_caps_routing(x, w_pc, b_pc, w_cc, nota=True, **schedule)

@@ -20,8 +20,15 @@ def caps_votes(u: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.einsum("bic,inc->bin", u, w)
 
 
-def routing(u_hat: jax.Array, iters: int) -> jax.Array:
-    """u_hat: [B, I, J, D] -> v: [B, J, D] (inference-mode dynamic routing)."""
+def routing(u_hat: jax.Array, iters: int, *, nota: bool = False
+            ) -> jax.Array:
+    """u_hat: [B, I, J, D] -> v: [B, J, D] (inference-mode dynamic routing).
+    The oracle has no none-of-the-above category: ``nota`` is refused
+    (``capsnet.routing_by_agreement`` routes it)."""
+    if nota:
+        raise NotImplementedError(
+            "ref.routing has no none-of-the-above category; use "
+            "capsnet.routing_by_agreement(..., nota=True)")
     b = jnp.zeros(u_hat.shape[:3], u_hat.dtype)
     for _ in range(iters):
         c = jax.nn.softmax(b, axis=2)

@@ -49,11 +49,16 @@ def _routing_kernel(uhat_ref, o_ref, *, iters: int, j: int, d: int):
     o_ref[...] = v.reshape(1, j * d).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("iters", "num_classes", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iters", "num_classes", "nota",
+                                             "interpret"))
 def routing(u_hat: jax.Array, *, iters: int = 3, num_classes: int = 10,
-            interpret: bool) -> jax.Array:
-    """u_hat: [B, I, J*D] -> v: [B, J*D]; fused dynamic routing."""
+            nota: bool = False, interpret: bool) -> jax.Array:
+    """u_hat: [B, I, J*D] -> v: [B, J*D]; fused dynamic routing.  The
+    oracle has no none-of-the-above category: ``nota`` is refused."""
+    if nota:
+        raise NotImplementedError(
+            "the split routing oracle has no none-of-the-above category; "
+            "the planned votes_routing / primary_routing kernels route it")
     bsz, i_dim, jd = u_hat.shape
     j = num_classes
     if jd % j:

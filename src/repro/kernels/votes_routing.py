@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.capsnet import squash
+from repro.core.capsnet import couplings, squash
 from repro.core.execplan import LAYOUTS
 from repro.core.planner import VMEM_LIMIT_BYTES
 
@@ -363,9 +363,13 @@ class _ClassLanes:
 _LAYOUT = {"caps": _CapsLanes, "classes": _ClassLanes}
 
 
-def _couplings(lay, b):
-    """Routing couplings: softmax of a logits block over the classes."""
-    return jax.nn.softmax(b, axis=lay.class_axis)
+def _couplings(lay, b, nota: bool):
+    """Routing couplings of a logits block over the classes
+    (``capsnet.couplings``): a softmax, or with ``nota`` the
+    none-of-the-above category's, whose one zero logit per input capsule
+    is counted once along the class axis, whichever axis the layout puts
+    on the lanes."""
+    return couplings(b, axis=lay.class_axis, nota=nota)
 
 
 def _squash_caps(lay, s):
@@ -378,7 +382,7 @@ def _rows(ib, block_i: int):
 
 
 def _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
-                 *, n_passes: int, n_blocks: int):
+                 *, n_passes: int, n_blocks: int, nota: bool):
     """One (pass, i-block) step of the fused single-stream routing.
 
     Pass ``t`` runs one WHOLE routing iteration: before accumulating
@@ -401,7 +405,8 @@ def _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    s_scr[...] += lay.weighted_sum(_couplings(lay, b_scr[b_rows]), uh4)
+    s_scr[...] += lay.weighted_sum(_couplings(lay, b_scr[b_rows], nota),
+                                   uh4)
 
     @pl.when(ib == n_blocks - 1)
     def _():
@@ -417,7 +422,7 @@ def _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
 
 def _routing_kernel(u_ref, w_ref, *refs, lanes: str, n_passes: int,
                     n_blocks: int, block_i: int, resident: bool,
-                    residual: bool):
+                    residual: bool, nota: bool):
     """Fused votes + routing, grid ``(iters + 1, n_blocks)``.
 
     Resident: pass 0 computes each votes block into the votes scratch and
@@ -443,7 +448,7 @@ def _routing_kernel(u_ref, w_ref, *refs, lanes: str, n_passes: int,
     else:
         uh4 = lay.votes(lay.u_block(u_ref, rows), w_ref[...])
     _route_block(lay, p, ib, uh4, rows, b_scr, s_scr, v_scr, o_ref, r_ref,
-                 n_passes=n_passes, n_blocks=n_blocks)
+                 n_passes=n_passes, n_blocks=n_blocks, nota=nota)
 
 
 # ---------------------------------------------------------------------------
@@ -451,7 +456,9 @@ def _routing_kernel(u_ref, w_ref, *refs, lanes: str, n_passes: int,
 # ---------------------------------------------------------------------------
 
 def _softmax_bwd(lay, c, dc):
-    """VJP of the class softmax given its OUTPUT c (a logits block)."""
+    """VJP of the class softmax given its OUTPUT c (a logits block).  The
+    none-of-the-above softmax has the same VJP in terms of its output:
+    the dropped column carries no cotangent."""
     return c * (dc - jnp.sum(c * dc, axis=lay.class_axis, keepdims=True))
 
 
@@ -463,7 +470,8 @@ def _squash_bwd(lay, s, dv):
 
 def _routing_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr, s2_scr,
                         acc_scr, v_scr, *votes, lanes: str, iters: int,
-                        n_blocks: int, block_i: int, resident: bool):
+                        n_blocks: int, block_i: int, resident: bool,
+                        nota: bool):
     """Grid ``(iters + 4, n_blocks)``.  Passes ``0..T`` replay the forward
     (the same fused s+b pass) over a ROLLING pair of logits slabs: the
     reference's ``stop_gradient(u_hat)`` convention means only ``b_{T-1}``
@@ -518,7 +526,7 @@ def _routing_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr, s2_scr,
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         acc_scr[...] += lay.weighted_sum(
-            _couplings(lay, b2_scr[(p % 2,) + b_rows]), uh4)
+            _couplings(lay, b2_scr[(p % 2,) + b_rows], nota), uh4)
 
         @pl.when(ib == n_blocks - 1)
         def _():
@@ -538,8 +546,9 @@ def _routing_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr, s2_scr,
         def _():
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        db = _softmax_bwd(lay, _couplings(lay, b2_scr[(slot_last,) + b_rows]),
-                          lay.agreement(uh4, s2_scr[slot_last]))
+        db = _softmax_bwd(
+            lay, _couplings(lay, b2_scr[(slot_last,) + b_rows], nota),
+            lay.agreement(uh4, s2_scr[slot_last]))
         acc_scr[...] += lay.weighted_sum(db, uh4)
 
         @pl.when(ib == n_blocks - 1)
@@ -550,8 +559,8 @@ def _routing_bwd_kernel(u_ref, w_ref, g_ref, du_ref, dw_ref, b2_scr, s2_scr,
     # ---- emit (T+3): d u_hat one i-block at a time -> du, dW ----
     @pl.when(p == t_total + 3)
     def _():
-        c_last = _couplings(lay, b2_scr[(slot_last,) + b_rows])
-        c_prev = _couplings(lay, b2_scr[(slot_prev,) + b_rows])
+        c_last = _couplings(lay, b2_scr[(slot_last,) + b_rows], nota)
+        c_prev = _couplings(lay, b2_scr[(slot_prev,) + b_rows], nota)
         duh = (lay.spread(c_last) * s2_scr[slot_last]
                + lay.spread(c_prev) * s2_scr[slot_prev])
         lay.emit(duh, w_ref, u_blk, du_ref, dw_ref)
@@ -573,6 +582,7 @@ class _VRStatics(NamedTuple):
     interpret: bool
     lanes: str = "caps"
     bwd_lanes: str = "caps"
+    nota: bool = False        # none-of-the-above category (``_couplings``)
 
 
 def _vr_apply(st: _VRStatics, u, w, r=None):
@@ -614,7 +624,7 @@ def _vr_apply(st: _VRStatics, u, w, r=None):
     kernel = functools.partial(_routing_kernel, lanes=st.lanes,
                                n_passes=n_passes, n_blocks=n_blocks,
                                block_i=block_i, resident=resident,
-                               residual=residual)
+                               residual=residual, nota=st.nota)
     out = pl.pallas_call(
         kernel,
         grid=(n_passes, n_blocks),
@@ -669,7 +679,8 @@ def _vr_grad(st: _VRStatics, u, w, g):
                                   jnp.float32))
     kernel = functools.partial(_routing_bwd_kernel, lanes=st.bwd_lanes,
                                iters=st.iters, n_blocks=n_blocks,
-                               block_i=block_i, resident=resident)
+                               block_i=block_i, resident=resident,
+                               nota=st.nota)
     du_shape, dw_shape = lay.grad_shapes(bsz, c, j, d, i_pad)
     du, dw = pl.pallas_call(
         kernel,
@@ -812,13 +823,15 @@ def _check_schedule(mode: str, bwd_mode: str, lanes: str,
 
 
 def _seg_statics(stat, i_dim: int, interpret: bool) -> _VRStatics:
-    iters, j, mode, block_i, bwd_mode, bwd_block_i, lanes, bwd_lanes = stat
+    (iters, j, mode, block_i, bwd_mode, bwd_block_i, lanes, bwd_lanes,
+     nota) = stat
     _check_schedule(mode, bwd_mode, lanes, bwd_lanes)
     return _VRStatics(iters=iters, num_classes=j, mode=mode,
                       block_i=max(1, min(block_i, i_dim)),
                       bwd_mode=bwd_mode,
                       bwd_block_i=max(1, min(bwd_block_i, i_dim)),
-                      interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes)
+                      interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes,
+                      nota=nota)
 
 
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
@@ -829,8 +842,8 @@ def res_caps_segment(x: jax.Array, ws, *, blocks,
     ``blocks`` is a tuple of ``(i1, stats_f, stats_g)`` per block, where
     ``i1`` is the coupling split point and each ``stats`` is the half's
     ``(iters, num_out_caps, mode, block_i, bwd_mode, bwd_block_i, lanes,
-    bwd_lanes)`` schedule (from its plan op; see ``repro.kernels.ops`` for the
-    plan-aware wrapper).  ``ws`` are the flat per-half weights, F then G
+    bwd_lanes, nota)`` schedule (from its plan op; see ``repro.kernels.ops``
+    for the plan-aware wrapper).  ``ws`` are the flat per-half weights, F then G
     per block: ``wf [I-i1, i1*C, C]``, ``wg [i1, (I-i1)*C, C]``.
 
     Differentiable with NO saved activations: ``jax.grad`` reconstructs
@@ -858,15 +871,12 @@ def res_caps_segment(x: jax.Array, ws, *, blocks,
     return _res_segment(tuple(resolved), x, tuple(ws))
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "iters", "num_classes", "mode", "block_i", "bwd_mode", "bwd_block_i",
-    "lanes", "bwd_lanes", "interpret"))
-def votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
-                  num_classes: int = 10, mode: str = "resident",
-                  block_i: int = 128, bwd_mode: str | None = None,
-                  bwd_block_i: int | None = None, lanes: str = "caps",
-                  bwd_lanes: str | None = None,
-                  interpret: bool) -> jax.Array:
+def _votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
+                   num_classes: int = 10, mode: str = "resident",
+                   block_i: int = 128, bwd_mode: str | None = None,
+                   bwd_block_i: int | None = None, lanes: str = "caps",
+                   bwd_lanes: str | None = None, nota: bool = False,
+                   interpret: bool) -> jax.Array:
     """u: [B, I, C], w: [I, J*D, C] -> v: [B, J*D]; votes + full routing.
 
     ``mode``/``block_i``/``lanes`` come from the ExecutionPlan
@@ -880,6 +890,9 @@ def votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
     the plan chooses them independently because the backward's scratch is
     larger), recomputing the routing iterations from the saved ``(u, W)``
     residuals so neither ``u_hat`` nor its cotangent touches HBM.
+
+    ``nota`` routes with the none-of-the-above category
+    (``capsnet.couplings``), forward and backward.
     """
     bsz, i_dim, c = u.shape
     _, jd, _ = w.shape
@@ -895,5 +908,27 @@ def votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
                     block_i=max(1, min(block_i, i_dim)),
                     bwd_mode=bwd_mode,
                     bwd_block_i=max(1, min(bwd_block_i or block_i, i_dim)),
-                    interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes)
+                    interpret=interpret, lanes=lanes, bwd_lanes=bwd_lanes,
+                    nota=nota)
     return _vr_core(st, u, w)
+
+
+_STATIC_ARGNAMES = ("iters", "num_classes", "mode", "block_i", "bwd_mode",
+                    "bwd_block_i", "lanes", "bwd_lanes", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_ARGNAMES)
+def votes_routing(u: jax.Array, w: jax.Array, **schedule) -> jax.Array:
+    """The fused votes + routing kernel with its custom VJP
+    (``_votes_routing``: operands, ``schedule`` keywords and result),
+    jitted.  A device trace names the call after this jit."""
+    return _votes_routing(u, w, nota=False, **schedule)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_ARGNAMES)
+def votes_routing_nota(u: jax.Array, w: jax.Array, **schedule) -> jax.Array:
+    """``votes_routing`` with the none-of-the-above category, forward and
+    backward, under a jit of its own name so that a trace tells it apart
+    (``votes_routing_nota.N``; its backward ``transpose_jvp_jit_
+    votes_routing_nota___.N``)."""
+    return _votes_routing(u, w, nota=True, **schedule)

@@ -275,7 +275,8 @@ def _trace_fused_fwd(plan: ExecutionPlan, op: OpPlan):
     st = vr._VRStatics(iters=lay.iters, num_classes=lay.num_caps,
                        mode=op.mode, block_i=op.block_i,
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
-                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes)
+                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes,
+                       nota=op.nota)
     u = _SDS((plan.batch, lay.in_caps, lay.in_dim), jnp.float32)
     w = _SDS((lay.in_caps, lay.jd, lay.in_dim), jnp.float32)
     if lay.residual:
@@ -291,7 +292,8 @@ def _trace_fused_bwd(plan: ExecutionPlan, op: OpPlan):
     st = vr._VRStatics(iters=lay.iters, num_classes=lay.num_caps,
                        mode=op.mode, block_i=op.block_i,
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
-                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes)
+                       interpret=True, lanes=op.lanes, bwd_lanes=op.lanes,
+                       nota=op.nota)
     u = _SDS((plan.batch, lay.in_caps, lay.in_dim), jnp.float32)
     w = _SDS((lay.in_caps, lay.jd, lay.in_dim), jnp.float32)
     g = _SDS((plan.batch, lay.jd), jnp.float32)
@@ -307,7 +309,7 @@ def _trace_fused_bwd(plan: ExecutionPlan, op: OpPlan):
                             mode=fwd_op.mode, block_i=fwd_op.block_i,
                             bwd_mode=fwd_op.mode, bwd_block_i=fwd_op.block_i,
                             interpret=True, lanes=fwd_op.lanes,
-                            bwd_lanes=fwd_op.lanes)
+                            bwd_lanes=fwd_op.lanes, nota=fwd_op.nota)
         fcalls, fouter = trace_lowering(
             lambda uv, wv: vr._vr_apply(fst, uv, wv), u, w)
         calls, outer = calls + fcalls, outer + fouter
@@ -324,7 +326,8 @@ def _trace_pipe_fwd(plan: ExecutionPlan, op: OpPlan):
                        bwd_mode=op.mode, bwd_block_i=op.block_i,
                        conv_block_m=op.block.block_m,
                        conv_block_k=op.block.block_k,
-                       conv_block_n=op.block.block_n, interpret=True)
+                       conv_block_n=op.block.block_n, interpret=True,
+                       nota=op.nota)
     x = _SDS((plan.batch, dims.conv1_out, dims.conv1_out, dims.pc_cin),
              jnp.float32)
     w_pc = _SDS((plan.cfg.pc_kernel, plan.cfg.pc_kernel, dims.pc_cin,

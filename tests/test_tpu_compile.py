@@ -18,13 +18,19 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.core.capsnet import CapsNetConfig
-from repro.core.execplan import BWD_SUFFIX, PIPE_NAME, compile_plan
+from repro.core.execplan import (BWD_SUFFIX, FUSED_NAME, PIPE_NAME,
+                                 compile_plan)
 from repro.kernels.conv_im2col import conv2d_im2col
-from repro.kernels.primary_routing import primary_caps_routing
-from repro.kernels.votes_routing import votes_routing
+from repro.kernels.primary_routing import (primary_caps_routing,
+                                           primary_caps_routing_nota)
+from repro.kernels.votes_routing import votes_routing, votes_routing_nota
 
 CFG = CapsNetConfig()        # capsnet-mnist published widths
 BATCH = 8
+# Sabour et al.'s CIFAR-10 network (none-of-the-above routing) at the
+# batch of its benchmark cells.
+SABOUR = get_config("capsnet-cifar10-sabour")
+SABOUR_BATCH = 16
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +62,7 @@ def _compile(fn, sharding, *shapes):
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text          # a Mosaic kernel, compiled
+    return text
 
 
 def _grad(fn, n_args):
@@ -141,3 +148,56 @@ def test_primary_routing_compiles(one_chip):
              (CFG.pc_kernel, CFG.pc_kernel, CFG.conv1_channels,
               CFG.pc_channels), (CFG.pc_channels,),
              (CFG.num_primary, jd, CFG.primary_dim))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_primary_routing_nota_compiles(one_chip, direction):
+    """The pipe kernel and its VJP with the none-of-the-above category at
+    the CIFAR-10 network's published widths, on its train plan's schedule
+    (the pipelined pair at the VMEM budget's edge); the trace names the
+    calls apart from the default path's."""
+    plan = compile_plan(SABOUR, batch=SABOUR_BATCH, pipeline=True,
+                        train=True)
+    op, bwd = plan.op(PIPE_NAME), plan.op(FUSED_NAME + BWD_SUFFIX)
+    lay = SABOUR.routing_stack()[0]
+
+    def pipe(x, w_pc, b_pc, w_cc):
+        return primary_caps_routing_nota(
+            x, w_pc, b_pc, w_cc, stride=SABOUR.pc_stride, iters=lay.iters,
+            num_classes=lay.num_caps, mode=op.mode, block_i=op.block_i,
+            block_k=op.block_k, bwd_mode=bwd.mode, bwd_block_i=bwd.block_i,
+            bwd_lanes=bwd.lanes, conv_block_m=op.block.block_m,
+            conv_block_k=op.block.block_k, conv_block_n=op.block.block_n,
+            interpret=False)
+
+    text = _compile(pipe if direction == "fwd" else _grad(pipe, 4), one_chip,
+                    (SABOUR_BATCH, SABOUR.conv1_out, SABOUR.conv1_out,
+                     SABOUR.conv1_channels),
+                    (SABOUR.pc_kernel, SABOUR.pc_kernel,
+                     SABOUR.conv1_channels, SABOUR.pc_channels),
+                    (SABOUR.pc_channels,),
+                    (lay.in_caps, lay.jd, lay.in_dim))
+    assert "primary_caps_routing_nota" in text
+    if direction == "bwd":
+        assert "transpose_jvp_jit_primary_caps_routing_nota" in text
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_votes_routing_nota_compiles(one_chip, direction):
+    """The fused routing kernel with the category at the CIFAR-10
+    network's widths, on its per-op train plan's schedules."""
+    plan = compile_plan(SABOUR, batch=SABOUR_BATCH, train=True)
+    op = plan.op(FUSED_NAME + ("" if direction == "fwd" else BWD_SUFFIX))
+    lay = SABOUR.routing_stack()[0]
+
+    def route(u, w):
+        return votes_routing_nota(u, w, iters=lay.iters,
+                                  num_classes=lay.num_caps, mode=op.mode,
+                                  block_i=op.block_i, lanes=op.lanes,
+                                  bwd_mode=op.mode, bwd_block_i=op.block_i,
+                                  bwd_lanes=op.lanes, interpret=False)
+
+    text = _compile(route if direction == "fwd" else _grad(route, 2),
+                    one_chip, (SABOUR_BATCH, lay.in_caps, lay.in_dim),
+                    (lay.in_caps, lay.jd, lay.in_dim))
+    assert "votes_routing_nota" in text
