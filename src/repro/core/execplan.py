@@ -756,13 +756,16 @@ class PrimaryRoutingSchedule:
 def _pipe_produce_vmem(batch: int, p_pos: int, n_ch: int,
                        block_k: int) -> int:
     """Produce-phase residency shared by both pipelined schedules: the
-    transposed pre-activation scratch [B, N, P], double-buffered patch
-    [B, P, block_k] / conv-weight [block_k, N] K tiles and the bias
-    column (u's [B, C, I_pad] scratch is part of the routing state)."""
-    return (tile_padded((batch, n_ch, p_pos))
+    pre-activation scratch [B*P, N] (the conv channels on lanes), the
+    patch-row scratch [B*P, block_k] the step's one dot reads,
+    double-buffered patch [B, P, block_k] / conv-weight [block_k, N] K
+    tiles and the bias row (u's [B, C, I_pad] scratch is part of the
+    routing state)."""
+    rows = batch * p_pos
+    return (tile_padded((rows, n_ch)) + tile_padded((rows, block_k))
             + 2 * (tile_padded((batch, p_pos, block_k))
                    + tile_padded((block_k, n_ch)))
-            + tile_padded((n_ch, 1)))
+            + tile_padded((1, n_ch)))
 
 
 def _pipe_resident_vmem(batch: int, p_pos: int, n_ch: int, block_k: int,

@@ -10,12 +10,17 @@ PrimaryCaps output ``u [B, I, C]`` still round-tripped HBM between two
 
   produce   grid steps ``0 .. k_steps-1``.  Each step streams one K
             tile of the im2col patches and conv weight past a resident
-            ``[B, N, P]`` pre-activation scratch (transposed, so the
-            positions lie on lanes); the last K step applies the bias +
-            per-capsule squash epilogue and lays the capsules out in the
-            ``[B, C, I_pad]`` scratch the consumer reads (u is the
-            SMALLEST tensor in the pair -- ~I*C floats per batch element
-            -- which is exactly why the paper parks it on-chip).
+            ``[B*P, N]`` pre-activation scratch: the ``[B, P, bk]`` patch
+            tile is copied to ``[B*P, bk]`` rows in VMEM and ONE lane-
+            dense ``[B*P, bk] . [bk, N]`` dot puts the N conv channels on
+            the MXU's output lanes (the P = 16-64 positions would fill a
+            fraction of them).  The last K step applies the bias +
+            per-capsule squash epilogue, turning each batch element's
+            ``[P, N]`` rows to ``[N, P]`` once per call, and lays the
+            capsules out in the ``[B, C, I_pad]`` scratch the consumer
+            reads (u is the SMALLEST tensor in the pair -- ~I*C floats
+            per batch element -- which is exactly why the paper parks
+            it on-chip).
             Patches and the conv weight are read exactly ONCE (a
             per-i-block recompute would re-stream the 21 MB MNIST conv
             weight once per i-block -- strictly worse traffic than the
@@ -67,14 +72,20 @@ from repro.kernels.votes_routing import (LAYOUTS, _CapsLanes, _route_block,
 MODES = ("resident", "streamed")
 
 
-def _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr, *,
-               k_steps: int, groups: int, caps_dim: int, p_pos: int):
-    """Produce phase: accumulate one K tile of the im2col matmul into the
-    transposed pre-activation scratch ``[B, N, P]``; the last K step adds
-    the bias, squashes each capsule (channels ``g*C .. (g+1)*C`` of a
+def _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, rows_scr, u_scr,
+               *, k_steps: int, groups: int, caps_dim: int, p_pos: int):
+    """Produce phase: copy one K tile of the im2col patches, ``[B, P, bk]``,
+    into the row scratch ``[B*P, bk]`` (batch element ``b`` from row
+    ``b*P``) and accumulate ``[B*P, bk] . [bk, N]`` into the
+    pre-activation scratch ``[B*P, N]``: one dot per step, the channels on
+    the MXU's output lanes.  (Merged into rows by XLA, the patch matrix
+    would be relaid out in HBM where P is off the sublane grid.)  The last
+    K step adds the bias, turns each batch element's ``[P, N]`` rows to
+    ``[N, P]``, squashes each capsule (channels ``g*C .. (g+1)*C`` of a
     position) and writes group ``g`` to lanes ``g*P .. (g+1)*P`` of the
     capsule scratch ``u [B, C, I_pad]``.  Lanes ``>= I`` keep their zero
     initialisation -- the i-axis padding the consume phase relies on."""
+    bsz = patches_ref.shape[0]
 
     @pl.when(t == 0)
     def _():
@@ -83,25 +94,27 @@ def _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr, *,
 
     @pl.when(t < k_steps)
     def _():
-        w_t = wpc_ref[...].astype(jnp.float32).T               # [N, bk]
-        for b in range(pre_scr.shape[0]):
-            pre_scr[b] += jax.lax.dot_general(
-                w_t, patches_ref[b].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)              # [N, P]
+        for b in range(bsz):
+            rows_scr[pl.ds(b * p_pos, p_pos), :] = (
+                patches_ref[b].astype(jnp.float32))
+        pre_scr[...] += jax.lax.dot(
+            rows_scr[...], wpc_ref[...].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)                  # [B*P, N]
 
         @pl.when(t == k_steps - 1)
         def _():
-            bsz = pre_scr.shape[0]
-            pre = pre_scr[...] + bias_ref[...][None]
+            bias = bias_ref[...]                                 # [1, N]
+            pre = jnp.stack([
+                (pre_scr[pl.ds(b * p_pos, p_pos), :] + bias).T
+                for b in range(bsz)])                            # [B, N, P]
             caps = squash(pre.reshape(bsz, groups, caps_dim, p_pos), axis=2)
             for g in range(groups):
                 u_scr[:, :, pl.ds(g * p_pos, p_pos)] = caps[:, g]
 
 
 def _pipe_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref, pre_scr,
-                 u_scr, b_scr, s_scr, v_scr, *votes, k_steps: int,
+                 rows_scr, u_scr, b_scr, s_scr, v_scr, *votes, k_steps: int,
                  p_pos: int, groups: int, caps_dim: int, n_passes: int,
                  n_blocks: int, block_i: int, resident: bool, nota: bool):
     """Grid ``k_steps - 1 + n_passes * n_blocks``.  The consume steps are
@@ -110,7 +123,7 @@ def _pipe_kernel(patches_ref, wpc_ref, bias_ref, wcc_ref, o_ref, pre_scr,
     the last produce step: u is fully squashed by the time the body
     reaches it (in-body program order)."""
     t = pl.program_id(0)
-    _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, u_scr,
+    _produce_u(t, patches_ref, wpc_ref, bias_ref, pre_scr, rows_scr, u_scr,
                k_steps=k_steps, groups=groups, caps_dim=caps_dim,
                p_pos=p_pos)
 
@@ -200,7 +213,8 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
         return q % n_blocks
 
     scratch = [
-        pltpu.VMEM((bsz, n_ch, p_pos), jnp.float32),       # pre-activation^T
+        pltpu.VMEM((bsz * p_pos, n_ch), jnp.float32),      # pre-activation
+        pltpu.VMEM((bsz * p_pos, bk), jnp.float32),        # patch rows
         pltpu.VMEM((bsz, caps_dim, i_pad), jnp.float32),   # u
         pltpu.VMEM((bsz, j, i_pad), jnp.float32),          # logits b
         pltpu.VMEM((bsz, j, d, 1), jnp.float32),           # s accumulator
@@ -223,7 +237,7 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
             pl.BlockSpec((bsz, p_pos, bk),
                          lambda t: (0, 0, jnp.minimum(t, last_k))),
             pl.BlockSpec((bk, n_ch), lambda t: (jnp.minimum(t, last_k), 0)),
-            pl.BlockSpec((n_ch, 1), lambda t: (0, 0)),
+            pl.BlockSpec((1, n_ch), lambda t: (0, 0)),
             pl.BlockSpec((caps_dim, j, d, block_i),
                          lambda t: (0, 0, 0, consume_block(t))),
         ],
@@ -232,7 +246,7 @@ def _pr_apply(st: _PRStatics, x, w_pc, b_pc, w_cc):
         scratch_shapes=scratch,
         compiler_params=COMPILER_PARAMS,
         interpret=st.interpret,
-    )(patches, wpc2, b_pc.reshape(n_ch, 1), w_t)
+    )(patches, wpc2, b_pc.reshape(1, n_ch), w_t)
     return out.reshape(bsz, jd)
 
 

@@ -140,6 +140,63 @@ def test_grad_parity_vs_unfused(mode):
                                    rtol=1e-5, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# The produce phase's orientation: one [B*P, bk] . [bk, N] dot per K step
+# ---------------------------------------------------------------------------
+
+# (batch, image side, conv channels N) with cin 8, a 3x3 stride-2 conv and
+# 8D capsules: K = 72 is ragged at block_k 32.
+ORIENTATION_EDGES = [
+    (3, 13, 128),   # P = 36 (a 6x6 grid, like mnist), B*P = 108: neither on
+                    # the sublane grid; N one lane tile
+    (1, 13, 256),   # P = 36, B*P = 36; N two lane tiles
+    (2, 9, 256),    # P = 16 (a 4x4 grid, like the CIFAR-10 network)
+    (3, 9, 128),    # P = 16, B*P = 48
+]
+
+
+def _jnp_pipe(x, w_pc, b_pc, w_cc, *, stride, j):
+    """The jnp reference of the pair: the PrimaryCaps conv at HIGHEST,
+    bias, per-capsule squash, then routing by agreement."""
+    y = jax.lax.conv_general_dilated(
+        x, w_pc, (stride, stride), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST) + b_pc
+    bsz, (i_dim, jd, caps_dim) = x.shape[0], w_cc.shape
+    u = capsnet.squash(y.reshape(bsz, i_dim, caps_dim))
+    uh = jax.numpy.einsum("bic,inc->bin", u, w_cc,
+                          precision=jax.lax.Precision.HIGHEST)
+    return capsnet.routing_by_agreement(
+        uh.reshape(bsz, i_dim, j, jd // j), 3).reshape(bsz, jd)
+
+
+@pytest.mark.parametrize("mode", ["resident", "streamed"])
+@pytest.mark.parametrize("b,h,n_ch", ORIENTATION_EDGES)
+def test_produce_orientation_edges_match_jnp_fwd_and_grad(mode, b, h, n_ch):
+    x, w_pc, b_pc, w_cc = _net(b, h, 8, 3, 2, n_ch, 8, 4, 8, seed=h + b)
+
+    def fused(*a):
+        return ops.primary_routing(*a, stride=2, iters=3, num_classes=4,
+                                   mode=mode, block_i=128, block_k=32,
+                                   bwd_mode=mode, bwd_block_i=128)
+
+    def want(*a):
+        return _jnp_pipe(*a, stride=2, j=4)
+
+    args = (x, w_pc, b_pc, w_cc)
+    np.testing.assert_allclose(np.asarray(fused(*args)),
+                               np.asarray(want(*args)),
+                               rtol=1e-5, atol=1e-6)
+    argnums = (0, 1, 2, 3)
+    got = jax.grad(lambda *a: jax.numpy.sum(jax.numpy.sin(fused(*a))),
+                   argnums)(*args)
+    ref = jax.grad(lambda *a: jax.numpy.sum(jax.numpy.sin(want(*a))),
+                   argnums)(*args)
+    for g, w in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_grad_through_total_loss_matches_jnp():
     """End to end: jax.grad through the pipelined train plan equals the
     jnp backend's gradients on every parameter."""

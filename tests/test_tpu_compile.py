@@ -1,5 +1,6 @@
 """Mosaic compile-only checks: the main-path Pallas kernels at
-capsnet-mnist's published widths (batch 8), compiled for a described
+capsnet-mnist's published widths (batch 8; the pipe kernel also at the
+benchmark cells' 16), compiled for a described
 v5e chip with ``interpret=False``.  Nothing runs; a kernel the TPU
 compiler refuses (a misaligned slice, an over-budget VMEM allocation)
 fails here without a chip.
@@ -27,6 +28,7 @@ from repro.kernels.votes_routing import votes_routing, votes_routing_nota
 
 CFG = CapsNetConfig()        # capsnet-mnist published widths
 BATCH = 8
+CELL_BATCH = 16              # the benchmark cells' slots and train batch
 # Sabour et al.'s CIFAR-10 network (none-of-the-above routing) at the
 # batch of its benchmark cells.
 SABOUR = get_config("capsnet-cifar10-sabour")
@@ -132,8 +134,14 @@ def test_votes_routing_class_lanes_compiles(one_chip, mode, direction):
              (1, in_caps, lay.in_dim), (in_caps, lay.jd, lay.in_dim))
 
 
-def test_primary_routing_compiles(one_chip):
-    op = compile_plan(CFG, batch=BATCH, pipeline=True).op(PIPE_NAME)
+@pytest.mark.parametrize("batch", [BATCH, CELL_BATCH])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_primary_routing_compiles(one_chip, batch, direction):
+    """The pipe kernel and its VJP on the train plan's schedule, at batch 8
+    and at the benchmark cells' 16 (streamed, where the produce phase's
+    [B*P, N] scratch and patch rows are largest)."""
+    plan = compile_plan(CFG, batch=batch, pipeline=True, train=True)
+    op, bwd = plan.op(PIPE_NAME), plan.op(FUSED_NAME + BWD_SUFFIX)
     jd = CFG.num_classes * CFG.class_dim
 
     def pipe(x, w_pc, b_pc, w_cc):
@@ -141,10 +149,12 @@ def test_primary_routing_compiles(one_chip):
             x, w_pc, b_pc, w_cc, stride=CFG.pc_stride,
             iters=CFG.routing_iters, num_classes=CFG.num_classes,
             mode=op.mode, block_i=op.block_i, block_k=op.block_k,
-            interpret=False)
+            bwd_mode=bwd.mode, bwd_block_i=bwd.block_i, bwd_lanes=bwd.lanes,
+            conv_block_m=op.block.block_m, conv_block_k=op.block.block_k,
+            conv_block_n=op.block.block_n, interpret=False)
 
-    _compile(pipe, one_chip,
-             (BATCH, CFG.conv1_out, CFG.conv1_out, CFG.conv1_channels),
+    _compile(pipe if direction == "fwd" else _grad(pipe, 4), one_chip,
+             (batch, CFG.conv1_out, CFG.conv1_out, CFG.conv1_channels),
              (CFG.pc_kernel, CFG.pc_kernel, CFG.conv1_channels,
               CFG.pc_channels), (CFG.pc_channels,),
              (CFG.num_primary, jd, CFG.primary_dim))

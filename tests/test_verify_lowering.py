@@ -45,6 +45,18 @@ class TestCleanAudit:
         assert "conv_im2col_bwd" in kernels
         assert "votes_routing_bwd" in kernels
 
+    @pytest.mark.parametrize("arch", ["capsnet-mnist",
+                                      "capsnet-cifar10-sabour"])
+    def test_pipe_kernel_audits_clean_at_the_cells_batch(self, arch):
+        # The benchmark cells' 16 slots: the produce phase's model stays
+        # within the auditor's slack of the lowered footprint.
+        plan = execplan.compile_plan(get_config(arch), batch=16,
+                                     pipeline=True)
+        audit = audit_op(plan, plan.op(execplan.PIPE_NAME))
+        assert audit.ok, [f"{c.name}: {c.detail}" for c in audit.failures()]
+        assert {c.name for c in audit.checks} >= {"vmem-under-model",
+                                                  "vmem-over-model"}
+
     def test_degraded_budget_audits_clean(self):
         # The quarter-budget rung forces a streamed pipelined pair with a
         # smaller produce K tile -- the lowering must still match the
