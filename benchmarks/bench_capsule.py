@@ -5,9 +5,8 @@ forward (interpret mode on CPU -- the comparison is about the shared plan,
 not raw speed off-TPU), the PIPELINED plan (Conv1 -> one
 ``primary_routing`` megakernel) against the per-op plan with the modeled
 inter-layer HBM bytes the pipelining eliminates, times the im2col conv
-kernels and the fused votes+routing megakernel against the split
-``caps_votes`` -> ``routing`` pair (with the modeled HBM bytes each moves
--- the u_hat round-trip the fusion kills), prints the compiled plan,
+kernels and the fused votes+routing megakernel (with its modeled HBM
+bytes), prints the compiled plan,
 times the 3-block CIFAR-10 ResCaps stack (per-layer fused OpPlans,
 modeled per-layer HBM bytes, reversible-backward grad vs jnp), and
 drives the slot-based ``CapsuleEngine`` over a request stream reporting
@@ -30,10 +29,10 @@ from repro.core.execplan import (BWD_SUFFIX, FUSED_NAME, PIPE_NAME,
                                  compile_plan, plan_votes_routing,
                                  primary_intermediate_hbm_bytes,
                                  spilled_votes_routing_bwd_hbm_bytes,
-                                 split_votes_routing_hbm_bytes,
                                  votes_routing_bwd_hbm_bytes,
                                  votes_routing_hbm_bytes)
 from repro.kernels import ops
+from repro.kernels.votes_routing import votes_routing
 from repro.serve.capsule import CapsRequest, CapsuleEngine
 
 CFG = CapsNetConfig(image_hw=14, conv1_channels=16, conv1_kernel=5,
@@ -89,40 +88,28 @@ def main() -> None:
     # Individual plan-driven conv kernels (the PR-2 im2col path).
     c1 = plan.op("Conv1")
     x1, us = timed(lambda: np.asarray(ops.conv2d(
-        imgs, params["conv1_w"], params["conv1_b"], stride=1, plan_op=c1,
-        epilogue="relu")))
+        imgs, params["conv1_w"], params["conv1_b"], plan, "Conv1")))
     row("conv1-im2col", us,
         f"block={c1.block.block_m}x{c1.block.block_k}x{c1.block.block_n}")
     pc = plan.op("PrimaryCaps")
     _, us = timed(lambda: np.asarray(ops.conv2d(
-        x1, params["pc_w"], params["pc_b"], stride=CFG.pc_stride, plan_op=pc,
-        squash_dim=CFG.primary_dim)))
+        x1, params["pc_w"], params["pc_b"], plan, "PrimaryCaps")))
     row("primarycaps-im2col", us,
         f"block={pc.block.block_m}x{pc.block.block_k}x{pc.block.block_n} "
         f"fused_squash={pc.fuses_squash}")
 
-    # Fused votes+routing megakernel vs the split caps_votes -> routing
-    # pair, plus the modeled HBM bytes each schedule moves per forward.
+    # Fused votes+routing megakernel, plus the modeled HBM bytes it
+    # moves per forward.
     fused_op = plan.op(FUSED_NAME)
+    lay = CFG.routing_stack()[0]
     jd = CFG.num_classes * CFG.class_dim
     u = capsnet.squash(jax.random.normal(
         key, (BATCH, CFG.num_primary, CFG.primary_dim)))
     w = params["cc_w"].reshape(CFG.num_primary, jd, CFG.primary_dim)
-    fused, us = timed(lambda: np.asarray(ops.votes_routing(
-        u, w, plan=plan)))
+    _, us = timed(lambda: np.asarray(ops.votes_routing(u, w, lay, plan)))
     row("votes-routing-fused", us,
         f"mode={fused_op.mode} block_i={fused_op.block_i}")
-    split, us = timed(lambda: np.asarray(ops.routing(
-        ops.caps_votes(u, w, plan=plan), plan=plan)))
-    row("votes-routing-split", us,
-        f"maxdiff={np.abs(fused - split).max():.2e}")
-    split_bytes, uhat_bytes = split_votes_routing_hbm_bytes(
-        BATCH, CFG.num_primary, CFG.primary_dim, jd)
     row("votes-routing/hbm-bytes-fused", 0.0, f"{fused_op.hbm_bytes:.0f}")
-    row("votes-routing/hbm-bytes-split", 0.0, f"{split_bytes:.0f}")
-    row("votes-routing/hbm-bytes-uhat-saved", 0.0,
-        f"{uhat_bytes:.0f} (u_hat round-trip killed; fused uhat_hbm_bytes="
-        f"{fused_op.uhat_hbm_bytes:.0f})")
 
     # STREAMED schedule (forced by a budget under the resident floor):
     # the fused s+b pass streams W iters+1 times per forward -- timed,
@@ -134,9 +121,10 @@ def main() -> None:
     tight = plan_votes_routing(CFG.num_primary, CFG.primary_dim, jd,
                                CFG.num_classes, batch=BATCH, iters=iters,
                                vmem_budget=floor - 1)
-    _, us = timed(lambda: np.asarray(ops.votes_routing(
-        u, w, iters=iters, mode=tight.mode, block_i=tight.block_i,
-        bwd_mode=tight.mode, bwd_block_i=tight.block_i)))
+    _, us = timed(lambda: np.asarray(votes_routing(
+        u, w, iters=iters, num_classes=CFG.num_classes, mode=tight.mode,
+        block_i=tight.block_i, bwd_mode=tight.mode,
+        bwd_block_i=tight.block_i, interpret=ops.should_interpret())))
     row("votes-routing-streamed-fused", us,
         f"mode={tight.mode} block_i={tight.block_i} w_passes={tight.n_passes}")
     stre_bytes = votes_routing_hbm_bytes(BATCH, CFG.num_primary,

@@ -8,27 +8,16 @@ import numpy as np
 
 from benchmarks.common import row, timed
 from repro.kernels import ops, ref
+from repro.kernels.squash import squash
 
 KEY = jax.random.PRNGKey(0)
 
 
 def main() -> list[str]:
     rows = []
-    # CapsuleNet-MNIST-shaped inputs (the paper's workload)
-    u = jax.random.normal(KEY, (1, 1152, 8))
-    w = jax.random.normal(KEY, (1152, 160, 8))
-    (votes, us) = timed(lambda: np.asarray(ops.caps_votes(u, w)), repeats=2)
-    err = np.abs(votes - np.asarray(ref.caps_votes(u, w))).max()
-    rows.append(row("kernels.caps_votes_mnist", us, f"maxerr={err:.2e}"))
-
-    uh = 0.1 * jax.random.normal(KEY, (1, 1152, 160))
-    (v, us) = timed(lambda: np.asarray(ops.routing(uh, iters=3)), repeats=2)
-    err = np.abs(v - np.asarray(
-        ref.routing(uh.reshape(1, 1152, 10, 16), 3).reshape(1, 160))).max()
-    rows.append(row("kernels.routing_fused_mnist", us, f"maxerr={err:.2e}"))
-
     x = jax.random.normal(KEY, (4096, 256))
-    (s, us) = timed(lambda: np.asarray(ops.squash(x)), repeats=2)
+    (s, us) = timed(lambda: np.asarray(squash(
+        x, interpret=ops.should_interpret())), repeats=2)
     err = np.abs(s - np.asarray(ref.squash(x))).max()
     rows.append(row("kernels.squash_4kx256", us, f"maxerr={err:.2e}"))
 

@@ -282,8 +282,7 @@ def wide_layer_phase(seed: int, *, cfg=None,
     dv = jax.random.normal(k3, (1, lay.jd))
 
     def fused(u, w):
-        return ops.votes_routing(u, w, plan=plan, op_name=lay.name,
-                                 iters=lay.iters, num_classes=lay.num_caps)
+        return ops.votes_routing(u, w, lay, plan)
 
     def ref(u, w):
         uh = jnp.einsum("bic,inc->bin", u, w)

@@ -344,15 +344,16 @@ def forward(params: Params, images: jax.Array,
     ``backend="pallas"`` runs the WHOLE network through the Pallas kernels
     with block shapes and the resident/streamed routing schedule chosen
     by an ``ExecutionPlan`` (compiled on the fly from ``cfg`` unless
-    ``plan`` is passed).  A pipelined plan (``compile_plan(...,
-    pipeline=True)``, the on-the-fly default) runs Conv1 -> ONE
-    ``primary_routing`` megakernel (PrimaryCaps conv + squash + votes +
-    routing, the inter-layer activation u resident in VMEM); a per-op
-    plan runs the three-call path (conv_im2col PrimaryCaps with fused
-    squash -> fused votes_routing megakernel) -- the pipelined plan's
-    fallback and parity oracle.  The kernels compile with Mosaic on a TPU
-    and run in the Pallas interpreter elsewhere unless ``interpret`` says
-    otherwise (``kernels.ops.should_interpret``).
+    ``plan`` is passed); the plan is the kernels' only schedule source.
+    A pipelined plan (``compile_plan(..., pipeline=True)``, the
+    on-the-fly default) runs Conv1 -> ONE ``primary_routing`` megakernel
+    (PrimaryCaps conv + squash + votes + routing, the inter-layer
+    activation u resident in VMEM); a per-op plan (``pipeline=False``,
+    or the budget's own fallback when the pair does not fit) runs the
+    conv_im2col PrimaryCaps and its squash -> the fused votes_routing
+    megakernel.  The kernels compile with Mosaic on a TPU and run in the
+    Pallas interpreter elsewhere unless ``interpret`` says otherwise
+    (``kernels.ops.should_interpret``).
 
     ``labels`` masks the reconstruction decoder with the true class
     (training semantics); when omitted the decoder masks with argmax.
@@ -365,9 +366,8 @@ def forward(params: Params, images: jax.Array,
         from repro.kernels import ops as _kops
         if plan is None:
             plan = _execplan.compile_plan(cfg, batch=b, pipeline=True)
-        x = _kops.conv2d(images, params["conv1_w"], params["conv1_b"],
-                         stride=1, plan_op=plan.op("Conv1"),
-                         epilogue="relu", interpret=interpret)
+        x = _kops.conv2d(images, params["conv1_w"], params["conv1_b"], plan,
+                         "Conv1", interpret=interpret)
         stack = cfg.routing_stack()
 
         def w_of(lay):
@@ -381,20 +381,16 @@ def forward(params: Params, images: jax.Array,
             # through HBM).
             first = stack[0]
             h = _kops.primary_routing(
-                x, params["pc_w"], params["pc_b"], w_of(first), plan=plan,
-                iters=first.iters, num_classes=first.num_caps,
-                routing_op_name=first.name,
+                x, params["pc_w"], params["pc_b"], w_of(first), first, plan,
                 interpret=interpret).reshape(b, first.num_caps,
                                              first.caps_dim)
             k = 1
         else:
-            pc = plan.op("PrimaryCaps")
-            x = _kops.conv2d(x, params["pc_w"], params["pc_b"],
-                             stride=cfg.pc_stride, plan_op=pc,
-                             squash_dim=cfg.primary_dim, interpret=interpret)
+            x = _kops.conv2d(x, params["pc_w"], params["pc_b"], plan,
+                             "PrimaryCaps", interpret=interpret)
             u = x.reshape(b, cfg.num_primary, cfg.primary_dim)
-            if not pc.fuses_squash:
-                u = _kops.squash(u, plan=plan, interpret=interpret)
+            if not plan.op("PrimaryCaps").fuses_squash:
+                u = _kops.squash(u, plan, interpret=interpret)
             h, k = u, 0
         # Walk the remaining routing-layer graph: one fused votes+routing
         # megakernel per plain layer (u_hat never round-trips through
@@ -409,12 +405,11 @@ def forward(params: Params, images: jax.Array,
                     pairs.append((stack[k], stack[k + 1]))
                     k += 2
                 ws = tuple(w_of(lyr) for pair in pairs for lyr in pair)
-                h = _kops.res_caps_segment(h, ws, tuple(pairs), plan=plan,
+                h = _kops.res_caps_segment(h, ws, tuple(pairs), plan,
                                            interpret=interpret)
             else:
                 h = _kops.votes_routing(
-                    h, w_of(lay), plan=plan, op_name=lay.name,
-                    iters=lay.iters, num_classes=lay.num_caps,
+                    h, w_of(lay), lay, plan,
                     interpret=interpret).reshape(b, lay.num_caps,
                                                  lay.caps_dim)
                 k += 1
@@ -459,7 +454,8 @@ def total_loss(params: Params, images: jax.Array, labels: jax.Array,
     the reconstruction term backpropagates only through that capsule's
     pose -- on the Pallas path the gradient flows through the kernels'
     custom VJPs (compile the plan with ``train=True`` to pin the backward
-    schedule; otherwise the memoized backward plan decision applies).
+    schedule; a forward-only plan's backward is planned at its VMEM
+    budget when the wrapper is traced).
     """
     out = forward(params, images, cfg, labels=labels, backend=backend,
                   plan=plan, interpret=interpret)

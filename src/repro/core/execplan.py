@@ -367,44 +367,6 @@ def activation_residency_bytes(cfg: CapsNetConfig, *, batch: int = 1,
     return total
 
 
-def _votes_vmem(batch: int, block_i: int, caps_dim: int, out_dim: int) -> int:
-    """caps_votes footprint per grid step (double-buffered streams)."""
-    data = batch * block_i * caps_dim * ELEM_BYTES
-    weight = block_i * out_dim * caps_dim * ELEM_BYTES
-    accum = batch * block_i * out_dim * ELEM_BYTES
-    return 2 * (data + weight) + accum
-
-
-def _votes_max_batch(caps_dim: int, out_dim: int, vmem_budget: int) -> int:
-    """Largest batch whose block_i=1 caps-votes footprint fits the budget."""
-    fixed = 2 * out_dim * caps_dim * ELEM_BYTES          # weight tile
-    per_batch = (2 * caps_dim + out_dim) * ELEM_BYTES    # data + accum rows
-    return max((vmem_budget - fixed) // per_batch, 0)
-
-
-def _votes_block_i_raw(num_caps: int, caps_dim: int, out_dim: int,
-                       batch: int, vmem_budget: int) -> int:
-    """Split-path caps-votes i-tile: planner pick shrunk to the budget at
-    the REAL batch (the memoized plan-less wrapper in ``kernels/ops.py``
-    shares this, so a batched call can no longer exceed the footprint the
-    planner guarantees).  Raises ``PlanError`` when even ``block_i=1``
-    exceeds the budget (instead of letting ``validate()`` fail later with
-    a generic footprint message)."""
-    wl = MatmulWorkload(m=num_caps, k=caps_dim, n=out_dim)
-    block = plan_matmul(wl, vmem_budget)
-    bi = max(min(block.block_m, num_caps), 1)
-    while bi > 1 and _votes_vmem(batch, bi, caps_dim, out_dim) > vmem_budget:
-        bi //= 2
-    need = _votes_vmem(batch, bi, caps_dim, out_dim)
-    if need > vmem_budget:
-        raise PlanError(
-            f"ClassCaps-FC: no feasible schedule at batch={batch}: even "
-            f"block_i=1 needs {need} B of VMEM, over the {vmem_budget} B "
-            f"budget; largest feasible batch is "
-            f"{_votes_max_batch(caps_dim, out_dim, vmem_budget)}")
-    return bi
-
-
 # ---------------------------------------------------------------------------
 # Fused votes+routing schedule (the megakernel's resident-vs-streamed DSE)
 # ---------------------------------------------------------------------------
@@ -619,8 +581,8 @@ def plan_votes_routing(num_caps: int, caps_dim: int, jd: int, j: int, *,
     megakernel.
 
     Prefer the caps-on-lanes layout, then **resident** (votes computed
-    once into scratch, routing iterates on-chip -- the split path's
-    behavior minus the u_hat HBM round-trip); fall back to **streamed**
+    once into scratch, routing iterates on-chip, no u_hat round-trip
+    through HBM); fall back to **streamed**
     (votes recomputed from re-streamed W tiles each pass) when the votes
     tensor cannot fit the budget at any i-tile.  The streamed schedule
     fuses each iteration's s-accumulation with its logits update into
@@ -712,18 +674,6 @@ def lane_relayout_bwd_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
         return float(2 * (2 * jd * num_caps * caps_dim + batch * jd
                           + batch * num_caps * caps_dim) * ELEM_BYTES)
     return 2 * lane_relayout_hbm_bytes(batch, num_caps, caps_dim, jd)
-
-
-def split_votes_routing_hbm_bytes(batch: int, num_caps: int, caps_dim: int,
-                                  jd: int) -> tuple[float, float]:
-    """(total, u_hat share) of the split ``caps_votes`` -> ``routing``
-    path: the votes tensor is written by one kernel and read back by the
-    next -- the produce-once/consume-once round-trip the fusion kills."""
-    u = batch * num_caps * caps_dim
-    w = num_caps * jd * caps_dim
-    v = batch * jd
-    uhat = 2 * batch * num_caps * jd                 # write + read back
-    return float((u + w + v + uhat) * ELEM_BYTES), float(uhat * ELEM_BYTES)
 
 
 # ---------------------------------------------------------------------------

@@ -34,8 +34,6 @@ Sites currently wired (the string is the ``FaultSpec.site`` key):
 ``ops.votes_routing``    fused megakernel wrapper output (eager calls)
 ``ops.primary_routing``  pipelined pair wrapper output (eager calls)
 ``ops.conv2d``           conv wrapper output (eager calls)
-``ops.caps_votes``       split-path votes wrapper output (eager calls)
-``ops.routing``          split-path routing wrapper output (eager calls)
 ``ops.res_caps_segment`` reversible segment wrapper output (eager calls)
 ``ops.squash``           squash wrapper output (eager calls)
 ``ops.rmsnorm``          rmsnorm wrapper output (eager calls)
@@ -75,8 +73,6 @@ KINDS = ("nan_output", "inf_output", "vmem_shrink", "plan_error",
 SITE_VOTES_ROUTING = "ops.votes_routing"
 SITE_PRIMARY_ROUTING = "ops.primary_routing"
 SITE_CONV2D = "ops.conv2d"
-SITE_CAPS_VOTES = "ops.caps_votes"
-SITE_ROUTING = "ops.routing"
 SITE_RES_CAPS_SEGMENT = "ops.res_caps_segment"
 SITE_SQUASH = "ops.squash"
 SITE_RMSNORM = "ops.rmsnorm"

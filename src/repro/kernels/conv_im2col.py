@@ -22,10 +22,10 @@ translation, in two stages:
      output block is the accumulator that stays resident across the K grid
      axis -- the paper's accumulator memory.
 
-Ragged final M/N blocks are safe the same way ``caps_votes`` is: Pallas
-clamps the tail block identically on the input and output side, and each
-(mi, ni) grid cell recomputes its full K reduction, so overlapped rows are
-rewritten with identical values.  The K axis is different -- a clamped tail
+Ragged final M/N blocks are safe: Pallas clamps the tail block
+identically on the input and output side, and each (mi, ni) grid cell
+recomputes its full K reduction, so overlapped rows are rewritten with
+identical values.  The K axis is different -- a clamped tail
 block would double-count the overlap -- so K is zero-padded up to a
 multiple of ``block_k`` instead (zero rows contribute nothing).
 

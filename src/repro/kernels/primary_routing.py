@@ -334,9 +334,9 @@ def _primary_caps_routing(x: jax.Array, w_pc: jax.Array, b_pc: jax.Array,
     the inter-layer activation u resident in VMEM scratch.  Schedule
     parameters come from the ExecutionPlan
     (``plan.op("PrimaryCaps-Routing")``); see ``repro.kernels.ops`` for
-    the plan-aware wrapper.  The unfused two-call path
-    (``conv2d_im2col`` + ``votes_routing``) remains the fallback and the
-    parity oracle.
+    the plan-driven wrapper.  The unfused two-call path
+    (``conv2d_im2col`` + ``votes_routing``) is what a per-op plan runs
+    where the pair does not fit the VMEM budget.
 
     Differentiable: the custom VJP replays the producer from patches and
     composes the per-op backward kernels (routing backward per

@@ -1,11 +1,11 @@
 """Fused ClassCaps votes + routing megakernel: u_hat never touches HBM.
 
 CapStore's central claim (Sec. 3.1) is that no routing value leaves the
-chip.  The split Pallas path still violated it on TPU: ``caps_votes``
-wrote the votes tensor ``u_hat [B, I, J*D]`` -- the single largest
-intermediate of the network -- to HBM and ``routing`` immediately read it
-back, a produce-once/consume-once round-trip dominating the traffic of
-the memory-bound ClassCaps stage (CapsAcc: zero weight reuse, so bytes
+chip.  A votes kernel followed by a routing kernel would write the
+votes tensor ``u_hat [B, I, J*D]`` -- the single largest intermediate of
+the network -- to HBM and immediately read it back, a
+produce-once/consume-once round-trip dominating the traffic of the
+memory-bound ClassCaps stage (CapsAcc: zero weight reuse, so bytes
 moved, not FLOPs, are the lever).  This kernel computes the votes from
 the u-tile and streamed ``W`` i-blocks and runs ALL routing iterations
 with the routing state (logits ``b``, couplings ``c``, candidates
@@ -881,8 +881,8 @@ def _votes_routing(u: jax.Array, w: jax.Array, *, iters: int = 3,
 
     ``mode``/``block_i``/``lanes`` come from the ExecutionPlan
     (``plan.op("ClassCaps-Routing")``); see ``repro.kernels.ops`` for the
-    plan-aware wrapper.  The split ``caps_votes`` -> ``routing`` pair
-    remains available as the oracle/fallback path.
+    plan-driven wrapper.  The jnp routing (``capsnet.routing_by_agreement``)
+    is the reference it is tested against.
 
     Differentiable: ``jax.grad`` runs the mode's backward Pallas kernel
     (``bwd_mode``/``bwd_block_i``/``bwd_lanes``, defaulting to the

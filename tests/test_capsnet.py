@@ -188,23 +188,18 @@ def test_total_loss_reconstructs_labeled_capsule():
 
 
 def test_pallas_capsnet_head_equivalence():
-    """core.capsnet votes+routing == kernels (caps_votes + fused routing)."""
+    """core.capsnet votes+routing == the plan's fused votes_routing."""
+    from repro.core.execplan import compile_plan
     from repro.kernels import ops
     cfg = SMOKE
     params = capsnet.init_params(KEY, cfg)
     u = capsnet.squash(jax.random.normal(KEY, (2, cfg.num_primary,
                                                cfg.primary_dim)))
     want_votes = capsnet.compute_votes(u, params["cc_w"])
-    w = params["cc_w"].transpose(0, 1, 2, 3).reshape(
-        cfg.num_primary, cfg.num_classes * cfg.class_dim, cfg.primary_dim)
-    got_votes = ops.caps_votes(u, w, block_i=16)
-    np.testing.assert_allclose(
-        np.asarray(got_votes),
-        np.asarray(want_votes.reshape(2, cfg.num_primary, -1)),
-        rtol=1e-5, atol=1e-5)
     want_v = capsnet.routing_by_agreement(want_votes, cfg.routing_iters)
-    got_v = ops.routing(got_votes, iters=cfg.routing_iters,
-                        num_classes=cfg.num_classes)
+    lay = cfg.routing_stack()[0]
+    w = params["cc_w"].reshape(lay.in_caps, lay.jd, lay.in_dim)
+    got_v = ops.votes_routing(u, w, lay, compile_plan(cfg, batch=2))
     np.testing.assert_allclose(np.asarray(got_v),
                                np.asarray(want_v.reshape(2, -1)),
                                rtol=1e-5, atol=1e-5)

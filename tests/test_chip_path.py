@@ -63,21 +63,30 @@ def chip_smoke():
     return mod
 
 
-def test_chip_smoke_rejects_backward_fallback(chip_smoke, monkeypatch):
+def test_chip_smoke_rejects_backward_fallback(chip_smoke):
     """A backward that cannot be planned falls back to the forward
     schedule with a RuntimeWarning; the smoke's warning check must turn
     that into a failure."""
     import warnings
 
     import jax.numpy as jnp
+
+    from repro.core import execplan
+    cfg = capsnet.CapsNetConfig(image_hw=8, conv1_channels=8, conv1_kernel=3,
+                                pc_kernel=3, num_primary_groups=4,
+                                primary_dim=4, num_classes=3, class_dim=8,
+                                use_decoder=False)
+    lay = cfg.routing_stack()[0]                # 16 x 4D -> 3 x 8D
+    floor = execplan._fused_streamed_bwd_vmem(
+        1, lay.in_caps, execplan._min_block_i(lay.in_caps), lay.in_dim,
+        lay.jd, lay.num_caps, lay.iters)
+    plan = execplan.compile_plan(cfg, batch=1, vmem_budget=floor - 1)
     ops._warn_bwd_fallback_once.cache_clear()
-    monkeypatch.setattr(ops, "VMEM_BYTES", 4096 + 17)   # under any bwd floor
     u = jnp.ones((1, 16, 4), jnp.float32)
     w = jnp.full((16, 3 * 8, 4), 0.01, jnp.float32)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        jax.grad(lambda x: ops.votes_routing(x, w, iters=2,
-                                             num_classes=3).sum())(u)
+        jax.grad(lambda x: ops.votes_routing(x, w, lay, plan).sum())(u)
     assert any("no feasible" in str(c.message) for c in caught)
     with pytest.raises(chip_smoke.SmokeFailure, match="RuntimeWarning"):
         chip_smoke.check_no_kernel_warnings(caught)

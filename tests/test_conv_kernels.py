@@ -1,7 +1,7 @@
 """Plan-driven im2col conv kernels vs the XLA convolution oracle.
 
 Covers ragged M/N grid tiles, K zero-padding, strided patch extraction,
-the bias/ReLU/squash epilogues, and the plan-aware ``ops.conv2d`` wrapper.
+the bias/ReLU/squash epilogues, and the plan-driven ``ops.conv2d`` wrapper.
 """
 
 import jax
@@ -108,31 +108,37 @@ def test_patches_match_manual_extraction(c, stride):
                                               patch.reshape(-1))
 
 
-def test_ops_conv2d_uses_planned_blocks_without_plan():
-    """The memoized planner pick drives the wrapper when no plan is given."""
-    x = jax.random.uniform(KEY, (2, 14, 14, 1))
-    w = 0.1 * jax.random.normal(KEY, (5, 5, 1, 16))
-    b = 0.1 * jax.random.normal(KEY, (16,))
-    want = _conv_ref(x, w, b, 1)
-    got = ops.conv2d(x, w, b, stride=1)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-    bm, bk, bn = ops.planned_conv_blocks(2 * 10 * 10, 25, 16)
-    assert bm >= 8 and bk >= 25 and bn >= 16     # aligned planner tiles
-
-
-def test_ops_conv2d_uses_plan_op_blocks():
+def _plan():
     from repro.core.capsnet import CapsNetConfig
     from repro.core.execplan import compile_plan
     cfg = CapsNetConfig(image_hw=14, conv1_channels=16, conv1_kernel=5,
                         pc_kernel=3, num_primary_groups=4, primary_dim=4,
                         class_dim=8, use_decoder=False)
-    plan = compile_plan(cfg, batch=2)
+    return compile_plan(cfg, batch=2)
+
+
+def test_ops_conv2d_primarycaps_takes_stride_and_squash_from_plan():
+    """The PrimaryCaps op runs at the config's stride with the squash
+    epilogue its plan op fuses, over the op's tiles."""
+    plan = _plan()
+    op = plan.op("PrimaryCaps")
+    assert op.fuses_squash and plan.cfg.pc_stride == 2
+    x = jax.random.uniform(KEY, (2, 10, 10, 16))
+    w = 0.1 * jax.random.normal(KEY, (3, 3, 16, 16))
+    b = 0.1 * jax.random.normal(KEY, (16,))
+    pre = _conv_ref(x, w, b, 2)
+    want = squash(pre.reshape(2, 4, 4, 4, 4)).reshape(pre.shape)
+    got = ops.conv2d(x, w, b, plan, "PrimaryCaps")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_ops_conv2d_uses_plan_op_blocks():
+    plan = _plan()
     params_w = 0.1 * jax.random.normal(KEY, (5, 5, 1, 16))
     params_b = jnp.zeros((16,))
     x = jax.random.uniform(KEY, (2, 14, 14, 1))
     want = jnp.maximum(_conv_ref(x, params_w, params_b, 1), 0.0)
-    got = ops.conv2d(x, params_w, params_b, stride=1,
-                     plan_op=plan.op("Conv1"), epilogue="relu")
+    got = ops.conv2d(x, params_w, params_b, plan, "Conv1")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

@@ -20,7 +20,7 @@ SMOKE = CapsNetConfig(image_hw=14, conv1_channels=16, conv1_kernel=5,
                       pc_kernel=3, num_primary_groups=4, primary_dim=4,
                       class_dim=8, decoder_hidden=(32, 64))
 # pc_out = (10 - 6)//2 + 1 = 3, groups = 3 -> num_primary = 27: odd and
-# non-power-of-two, the case that used to collapse planned_block_i to 1.
+# non-power-of-two, the case that used to collapse the i-tile pick to 1.
 ODD = CapsNetConfig(image_hw=14, conv1_channels=8, conv1_kernel=5,
                     pc_kernel=6, pc_stride=2, num_primary_groups=3,
                     primary_dim=4, class_dim=8, use_decoder=False)
@@ -137,27 +137,67 @@ def test_plan_rejects_impossible_budget():
         compile_plan(CFG, vmem_budget=1024)
 
 
-def test_votes_block_i_raises_plan_error_at_source():
-    """An infeasible batch fails in the split-path i-tile pick with a
-    message naming the batch, the budget, and the largest feasible batch
-    -- not later in validate() with a generic footprint complaint."""
-    from repro.core.execplan import _votes_block_i_raw, _votes_max_batch
-    dims = analysis.dims_from_config(SMOKE)
-    out_dim = dims.num_classes * dims.class_dim
-    budget = 200_000
-    feasible = _votes_max_batch(dims.primary_dim, out_dim, budget)
-    assert feasible > 0
-    # boundary: the largest feasible batch plans, one past it raises
-    bi = _votes_block_i_raw(dims.num_primary, dims.primary_dim, out_dim,
-                            feasible, budget)
-    assert bi >= 1
-    with pytest.raises(PlanError) as exc:
-        _votes_block_i_raw(dims.num_primary, dims.primary_dim, out_dim,
-                           feasible + 1, budget)
-    msg = str(exc.value)
-    assert f"batch={feasible + 1}" in msg
-    assert str(budget) in msg
-    assert f"largest feasible batch is {feasible}" in msg
+# The benchmark cells' plans, pinned: (name, kernel, mode, lanes, block_i,
+# block_k, conv tiles (m, k, n), block_rows, W passes, nota, vmem_bytes)
+# per OpPlan, then the first routing layer's kernel statics as the pipe
+# wrapper resolves them (iters, classes, mode, block_i, bwd_mode,
+# bwd_block_i, lanes, bwd_lanes, nota) -- the static arguments of the
+# ``primary_caps_routing``/``primary_caps_routing_nota`` jits.
+_MNIST_FWD = [
+    ("Conv1", "conv_im2col", None, None, None, None, (512, 128, 256), None,
+     None, False, 1327104),
+    ("PrimaryCaps-Routing", "primary_routing", "streamed", "caps", 128, 256,
+     (256, 256, 256), None, 4, False, 11345920),
+]
+_MNIST_BWD = [
+    ("ClassCaps-Routing-bwd", "votes_routing_bwd", "streamed", "caps", 128,
+     None, None, None, 7, False, 12845056),
+    ("PrimaryCaps-bwd", "conv_im2col_bwd", None, None, None, None,
+     (256, 256, 256), 1024, None, False, 1327104),
+    ("Conv1-bwd", "conv_im2col_bwd", None, None, None, None,
+     (512, 128, 256), None, None, False, 1662976),
+]
+_SABOUR_FWD = [
+    ("Conv1", "conv_im2col", None, None, None, None, (4096, 256, 256), None,
+     None, False, 13123584),
+    ("PrimaryCaps-Routing", "primary_routing", "streamed", "caps", 256, 256,
+     (256, 256, 512), None, 4, True, 13123584),
+]
+_SABOUR_BWD = [
+    ("ClassCaps-Routing-bwd", "votes_routing_bwd", "streamed", "caps", 256,
+     None, None, None, 7, True, 15859712),
+    ("PrimaryCaps-bwd", "conv_im2col_bwd", None, None, None, None,
+     (256, 256, 512), 1024, None, False, 2129920),
+    ("Conv1-bwd", "conv_im2col_bwd", None, None, None, None,
+     (4096, 256, 256), None, None, False, 8658944),
+]
+_MNIST_PIPE = (3, 10, "streamed", 128, "streamed", 128, "caps", "caps", False)
+_SABOUR_PIPE = (3, 10, "streamed", 256, "streamed", 256, "caps", "caps", True)
+
+
+@pytest.mark.parametrize("arch,train,ops,pipe", [
+    ("capsnet-mnist", False, _MNIST_FWD, _MNIST_PIPE),
+    ("capsnet-mnist", True, _MNIST_FWD + _MNIST_BWD, _MNIST_PIPE),
+    ("capsnet-cifar10-sabour", False, _SABOUR_FWD, _SABOUR_PIPE),
+    ("capsnet-cifar10-sabour", True, _SABOUR_FWD + _SABOUR_BWD,
+     _SABOUR_PIPE),
+], ids=["mnist-serve", "mnist-train", "cifar10-serve", "cifar10-train"])
+def test_benchmark_cell_plans_are_pinned(arch, train, ops, pipe):
+    """The plans the benchmark cells run -- 16 serving slots, or a
+    training batch of 16 -- and the pipe kernel's statics resolved from
+    them, as they stand; a change to either is a change to every cell's
+    chip program."""
+    from repro.configs.registry import get_config
+    from repro.kernels.ops import _schedule
+    cfg = get_config(arch)
+    plan = compile_plan(cfg, batch=16, train=train, pipeline=True)
+    got = [(op.name, op.kernel, op.mode, op.lanes, op.block_i, op.block_k,
+            (op.block.block_m, op.block.block_k, op.block.block_n)
+            if op.block else None, op.block_rows, op.n_passes, op.nota,
+            op.vmem_bytes) for op in plan.ops]
+    assert got == ops
+    assert tuple(_schedule(cfg.routing_stack()[0], 16, plan,
+                           pipe=True)) == pipe
 
 
 def test_compile_plan_surfaces_fused_plan_error():

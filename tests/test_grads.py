@@ -24,6 +24,8 @@ from repro.core.execplan import (BWD_SUFFIX, FUSED_NAME, PlanError,
                                  spilled_votes_routing_bwd_hbm_bytes,
                                  votes_routing_bwd_hbm_bytes)
 from repro.kernels import ops
+from repro.kernels import squash as squash_mod
+from repro.kernels import votes_routing as vr_mod
 from repro.kernels.conv_im2col import (col2im_patches, conv2d_im2col,
                                        im2col_patches, matmul_at_b)
 
@@ -119,8 +121,8 @@ def test_conv_grad_matches_lax_conv_reference(epilogue, squash_dim, stride):
 def test_squash_kernel_grad_matches_reference():
     x = jax.random.normal(KEY, (3, 37, 6))       # ragged rows vs block 16
     dy = jax.random.normal(jax.random.fold_in(KEY, 1), x.shape)
-    g_pal = jax.grad(lambda x: jnp.sum(
-        ops.squash(x, block_rows=16) * dy))(x)
+    g_pal = jax.grad(lambda x: jnp.sum(squash_mod.squash(
+        x, block_rows=16, interpret=ops.should_interpret()) * dy))(x)
     g_ref = jax.grad(lambda x: jnp.sum(capsnet.squash(x) * dy))(x)
     assert _rel(g_pal, g_ref) <= TOL
 
@@ -133,9 +135,10 @@ def _vr_grad_pair(u, w, dv, *, iters, j, d, mode, bwd_mode, bi, bwd_bi):
     b, i, c = u.shape
 
     def loss_pal(u, w):
-        v = ops.votes_routing(u, w, iters=iters, num_classes=j, mode=mode,
-                              block_i=bi, bwd_mode=bwd_mode,
-                              bwd_block_i=bwd_bi)
+        v = vr_mod.votes_routing(u, w, iters=iters, num_classes=j,
+                                 mode=mode, block_i=bi, bwd_mode=bwd_mode,
+                                 bwd_block_i=bwd_bi,
+                                 interpret=ops.should_interpret())
         return jnp.sum(v.reshape(b, j, d) * dv)
 
     def loss_ref(u, w):
@@ -203,27 +206,35 @@ def test_streamed_bwd_matches_resident_bwd(b, i, c, j, d, bi, iters):
         assert _rel(g_f, g_r) <= TOL
 
 
-def test_grad_through_planless_wrapper():
-    """Without a plan the wrapper resolves the backward schedule through
-    the memoized backward plan decision and still matches the reference."""
-    u, w, k3 = _uv(2, 150, 8, 80, seed=7)
-    dv = jax.random.normal(k3, (2, 10, 8))
+def test_grad_through_forward_only_plan():
+    """A ``train=False`` plan has no backward OpPlans: the wrapper's one
+    resolver plans the backward at the plan's budget, and the gradients
+    still match the reference."""
+    plan = compile_plan(NONPOW2, batch=2)
+    assert not plan.train
+    lay = NONPOW2.routing_stack()[0]
+    i, c, j, d = lay.in_caps, lay.in_dim, lay.num_caps, lay.caps_dim
+    u, w, k3 = _uv(2, i, c, j * d, seed=7)
+    dv = jax.random.normal(k3, (2, j, d))
 
     def loss(u, w):
-        return jnp.sum(ops.votes_routing(u, w, iters=3, num_classes=10
-                                         ).reshape(2, 10, 8) * dv)
+        return jnp.sum(ops.votes_routing(u, w, lay, plan).reshape(2, j, d)
+                       * dv)
 
     def loss_ref(u, w):
-        uh = capsnet.compute_votes(u, w.reshape(150, 10, 8, 8))
+        uh = capsnet.compute_votes(u, w.reshape(i, j, d, c))
         return jnp.sum(capsnet.routing_by_agreement(uh, 3) * dv)
 
     got = jax.grad(loss, argnums=(0, 1))(u, w)
     want = jax.grad(loss_ref, argnums=(0, 1))(u, w)
     for g, r in zip(got, want):
         assert _rel(g, r) <= TOL
-    mode, bi, lanes = ops.planned_votes_routing_bwd(150, 8, 80, 10, 3, 2)
-    assert lanes == "caps"
-    assert mode in ("resident", "streamed") and 1 <= bi <= 150
+    s = ops._schedule(lay, 2, plan)
+    bwd = plan_votes_routing_bwd(i, c, j * d, j, batch=2, iters=3,
+                                 vmem_budget=plan.vmem_budget)
+    assert (s.bwd_mode, s.bwd_block_i, s.bwd_lanes) == (
+        bwd.mode, bwd.block_i, bwd.lanes)
+    assert bwd.lanes == "caps" and 1 <= bwd.block_i <= i
 
 
 # ---------------------------------------------------------------------------
@@ -332,15 +343,16 @@ def test_forward_only_backward_fallback_warns_once():
         2, dims.num_primary, execplan._min_block_i(dims.num_primary),
         dims.primary_dim, jd, dims.num_classes, dims.routing_iters)
     plan = compile_plan(NONPOW2, batch=2, vmem_budget=floor - 1)
+    lay = NONPOW2.routing_stack()[0]
     u, w, _ = _uv(2, dims.num_primary, dims.primary_dim, jd, seed=77)
     _warn_bwd_fallback_once.cache_clear()
     with pytest.warns(RuntimeWarning, match="no feasible backward") as rec:
-        got = ops.votes_routing(u, w, plan=plan)
+        got = ops.votes_routing(u, w, lay, plan)
     assert f"{floor - 1} B" in str(rec[0].message)      # names the budget
     assert FUSED_NAME + BWD_SUFFIX in str(rec[0].message)  # names the op
     with _warnings.catch_warnings():
         _warnings.simplefilter("error")                 # second call: silent
-        ops.votes_routing(u, w, plan=plan)
+        ops.votes_routing(u, w, lay, plan)
     want = capsnet.routing_by_agreement(
         capsnet.compute_votes(u, w.reshape(dims.num_primary,
                                            dims.num_classes, dims.class_dim,

@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import squash as squash_mod
+from repro.kernels.flash_attention import flash_attention
 
 KEY = jax.random.PRNGKey(0)
 
@@ -17,80 +19,10 @@ def rand(shape, dtype=jnp.float32, k=KEY, scale=1.0):
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
-# ---------------------------------------------------------------------------
-# caps_votes
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,i,c,n", [(1, 64, 8, 160), (2, 256, 8, 160),
-                                     (3, 128, 16, 80), (1, 1152, 8, 160)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_caps_votes(b, i, c, n, dtype):
-    u = rand((b, i, c), dtype)
-    w = rand((i, n, c), dtype)
-    got = ops.caps_votes(u, w)
-    want = ref.caps_votes(u.astype(jnp.float32), w.astype(jnp.float32))
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
-                               rtol=TOL[dtype], atol=TOL[dtype] * 8)
-
-
-def test_caps_votes_block_sweep():
-    u = rand((2, 256, 8))
-    w = rand((256, 160, 8))
-    want = ref.caps_votes(u, w)
-    for bi in (32, 64, 128, 256):
-        got = ops.caps_votes(u, w, block_i=bi)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("i,bi", [(300, 128), (135, 32), (27, 8), (100, 256)])
-def test_caps_votes_ragged_tail(i, bi):
-    """I need not divide block_i (grid = cdiv, masked/clamped tail)."""
-    u = rand((2, i, 8))
-    w = rand((i, 40, 8))
-    got = ops.caps_votes(u, w, block_i=bi)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(ref.caps_votes(u, w)),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_caps_votes_planned_block_not_degenerate():
-    """Regression: non-power-of-two capsule counts used to collapse the
-    planner pick to block_i=1 via the old ``while i % bi: bi //= 2``."""
-    for i in (27, 300, 1100):
-        bi = ops.planned_block_i(i, 8, 160)
-        assert 8 <= bi <= i
-    u = rand((1, 1100, 8))
-    w = rand((1100, 160, 8))
-    got = ops.caps_votes(u, w)                    # default = planner pick
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(ref.caps_votes(u, w)),
-                               rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# routing (fused) -- the paper's on-chip-resident loop
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("iters", [1, 2, 3, 5])
-@pytest.mark.parametrize("b,i,j,d", [(1, 64, 10, 16), (2, 1152, 10, 16),
-                                     (3, 96, 4, 8)])
-def test_routing_fused(iters, b, i, j, d):
-    uh = 0.1 * rand((b, i, j * d))
-    got = ops.routing(uh, iters=iters, num_classes=j)
-    want = ref.routing(uh.reshape(b, i, j, d), iters).reshape(b, j * d)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_routing_matches_capsnet_module():
-    from repro.core.capsnet import routing_by_agreement
-    uh = 0.1 * rand((2, 128, 160))
-    got = ops.routing(uh, iters=3, num_classes=10)
-    want = routing_by_agreement(uh.reshape(2, 128, 10, 16), 3)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(want.reshape(2, 160)),
-                               rtol=1e-5, atol=1e-6)
+def squash(x, block_rows=1024):
+    """The squash kernel on a block of the test's choosing."""
+    return squash_mod.squash(x, block_rows=block_rows,
+                             interpret=ops.should_interpret())
 
 
 # ---------------------------------------------------------------------------
@@ -101,40 +33,47 @@ def test_routing_matches_capsnet_module():
                                    (2, 3, 5, 8)])
 def test_squash(shape):
     x = rand(shape)
-    np.testing.assert_allclose(np.asarray(ops.squash(x)),
+    np.testing.assert_allclose(np.asarray(squash(x)),
                                np.asarray(ref.squash(x)), rtol=1e-5,
                                atol=1e-6)
 
 
 def test_squash_norm_bound():
     x = 100.0 * rand((16, 32))
-    v = ops.squash(x)
+    v = squash(x)
     norms = np.linalg.norm(np.asarray(v), axis=-1)
     assert (norms <= 1.0 + 1e-5).all()
 
 
 def test_squash_ragged_rows():
     x = rand((7, 5, 8))                      # 35 rows, not a block multiple
-    got = ops.squash(x, block_rows=16)
+    got = squash(x, block_rows=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.squash(x)),
                                rtol=1e-5, atol=1e-6)
 
 
 def test_squash_single_canonical_implementation():
-    """The kernel and the fused routing kernel share core.capsnet.squash
+    """The kernel and the fused routing kernels share core.capsnet.squash
     (ref.squash stays an independent oracle)."""
     from repro.core.capsnet import squash as canonical
-    from repro.kernels import routing as routing_mod
-    from repro.kernels import squash as squash_mod
+    from repro.kernels import primary_routing, votes_routing
     assert squash_mod.squash_reference is canonical
-    assert routing_mod.squash is canonical
+    assert votes_routing.squash is canonical
+    assert primary_routing.squash is canonical
     x = rand((13, 16), scale=5.0)
     np.testing.assert_allclose(np.asarray(canonical(x)),
                                np.asarray(ref.squash(x)),
                                rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(ops.squash(x)),
+    np.testing.assert_allclose(np.asarray(squash(x)),
                                np.asarray(canonical(x)),
                                rtol=1e-5, atol=1e-6)
+    # the wrapper: the primary capsules, blocked over the plan's rows
+    from repro.core.capsnet import CapsNetConfig
+    from repro.core.execplan import compile_plan
+    u = rand((2, 1152, 8), scale=5.0)
+    np.testing.assert_allclose(
+        np.asarray(ops.squash(u, compile_plan(CapsNetConfig(), batch=2))),
+        np.asarray(canonical(u)), rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("rows,d", [(8, 64), (1024, 512), (7, 384)])
@@ -191,8 +130,8 @@ def test_flash_attention_block_sweep():
     q, k, v = (rand((1, 2, 256, 64), k=kk) for kk in ks)
     want = ref.attention(q, k, v, causal=True)
     for bq, bk in [(32, 32), (64, 128), (128, 64), (256, 256)]:
-        got = ops.flash_attention(q, k, v, causal=True, block_q=bq,
-                                  block_k=bk)
+        got = flash_attention(q, k, v, causal=True, block_q=bq,
+                              block_k=bk, interpret=ops.should_interpret())
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 

@@ -3,8 +3,8 @@ the leaky coupling itself, the fused votes+routing kernel in both lane
 layouts and both schedules, the pipelined PrimaryCaps->routing kernel and
 the reversible residual segment against the jnp leaky routing, forward
 and VJP; the default path unchanged; the plan's schedules and bytes
-unchanged by the leak; the split oracles refusing it; the auditor
-tracing the leaky kernels."""
+unchanged by the leak; the jnp oracle refusing it; the auditor tracing
+the leaky kernels."""
 
 import dataclasses
 
@@ -18,7 +18,8 @@ from repro.core import capsnet
 from repro.core.capsnet import CapsNetConfig, ResCapsBlock
 from repro.core.execplan import BWD_SUFFIX, PIPE_NAME, PlanError, compile_plan
 from repro.kernels import ops, ref
-from repro.kernels import routing as routing_kernel
+from repro.kernels.primary_routing import primary_caps_routing_nota
+from repro.kernels.votes_routing import votes_routing_nota
 from repro.verify import lowering
 
 KEY = jax.random.PRNGKey(0)
@@ -95,9 +96,10 @@ def test_votes_routing_leak_matches_reference_fwd_and_grad(mode, lanes, b,
     dv = jax.random.normal(jax.random.fold_in(KEY, 7), (b, j * d))
 
     def fused(u, w):
-        return ops.votes_routing(u, w, iters=3, num_classes=j, mode=mode,
-                                 block_i=bi, lanes=lanes, bwd_mode=mode,
-                                 bwd_block_i=bi, bwd_lanes=lanes, nota=True)
+        return votes_routing_nota(u, w, iters=3, num_classes=j, mode=mode,
+                                  block_i=bi, lanes=lanes, bwd_mode=mode,
+                                  bwd_block_i=bi, bwd_lanes=lanes,
+                                  interpret=ops.should_interpret())
 
     got = fused(u, w)
     want = _routing_ref(u, w, j, nota=True)
@@ -138,10 +140,10 @@ def test_primary_caps_routing_leak_matches_reference_fwd_and_grad(
     x, w_pc, b_pc, w_cc = _net(2, 10, 8, 6, 2, 12, 4, 4, 8, seed=3)
 
     def fused(*a):
-        return ops.primary_routing(*a, stride=2, iters=3, num_classes=4,
-                                   mode=mode, block_i=8, block_k=32,
-                                   bwd_mode=mode, bwd_block_i=8,
-                                   bwd_lanes=bwd_lanes, nota=True)
+        return primary_caps_routing_nota(
+            *a, stride=2, iters=3, num_classes=4, mode=mode, block_i=8,
+            block_k=32, bwd_mode=mode, bwd_block_i=8, bwd_lanes=bwd_lanes,
+            interpret=ops.should_interpret())
 
     def want(*a):
         return _pipe_ref(*a, stride=2, j=4, caps_dim=4)
@@ -169,9 +171,10 @@ def test_pipe_produce_orientation_edges_with_the_leak(mode, b, h, n_ch):
     x, w_pc, b_pc, w_cc = _net(b, h, 8, 3, 2, n_ch, 8, 4, 8, seed=h + b)
 
     def fused(*a):
-        return ops.primary_routing(*a, stride=2, iters=3, num_classes=4,
-                                   mode=mode, block_i=128, block_k=32,
-                                   bwd_mode=mode, bwd_block_i=128, nota=True)
+        return primary_caps_routing_nota(
+            *a, stride=2, iters=3, num_classes=4, mode=mode, block_i=128,
+            block_k=32, bwd_mode=mode, bwd_block_i=128,
+            interpret=ops.should_interpret())
 
     def want(*a):
         return _pipe_ref(*a, stride=2, j=4, caps_dim=8)
@@ -253,16 +256,19 @@ def test_default_jaxprs_equal_explicit_false(arch):
 
 def test_leaky_kernels_run_under_jits_of_their_own_names():
     """A device trace names a Pallas call after the jit that makes it:
-    the leaky pipe kernel is ``primary_caps_routing_nota``."""
+    the leaky pipe kernel is ``primary_caps_routing_nota``, and the
+    wrapper picks the leaky ``votes_routing_nota`` for a leaky layer."""
     params = capsnet.init_params(KEY, SMOKE)
     imgs = jnp.zeros((2, SMOKE.image_hw, SMOKE.image_hw, 3))
     plan = compile_plan(SMOKE, batch=2, pipeline=True)
     text = str(jax.make_jaxpr(lambda p, x: capsnet.forward(
         p, x, SMOKE, backend="pallas", plan=plan)["lengths"])(params, imgs))
     assert "name=primary_caps_routing_nota" in text
-    u, w = _uv(2, 16, 4, 40)
+    perop = compile_plan(SMOKE, batch=2)
+    lay = SMOKE.routing_stack()[0]
+    u, w = _uv(2, lay.in_caps, lay.in_dim, lay.jd)
     text = str(jax.make_jaxpr(lambda u, w: ops.votes_routing(
-        u, w, iters=2, num_classes=10, nota=True))(u, w))
+        u, w, lay, perop))(u, w))
     assert "name=votes_routing_nota" in text
 
 
@@ -301,20 +307,16 @@ def test_sabour_plan_is_pipelined_at_sixteen_slots_and_up_to_twenty_two():
 
 
 # ---------------------------------------------------------------------------
-# The split oracles refuse the leak; the auditor traces it
+# The split jnp oracle refuses the leak; the auditor traces it
 # ---------------------------------------------------------------------------
 
 def test_split_oracles_refuse_the_leak():
+    """``ref.caps_votes`` -> ``ref.routing`` has no leak: it refuses one
+    rather than route without it."""
     u, w = _uv(2, 16, 4, 40)
     uh = ref.caps_votes(u, w)
     with pytest.raises(NotImplementedError, match="none-of-the-above"):
-        routing_kernel.routing(uh, iters=2, num_classes=10, nota=True,
-                               interpret=True)
-    with pytest.raises(NotImplementedError, match="none-of-the-above"):
         ref.routing(uh.reshape(2, 16, 10, 4), 2, nota=True)
-    plan = compile_plan(SMOKE, batch=2)
-    with pytest.raises(NotImplementedError, match="none-of-the-above"):
-        ops.routing(uh, plan=plan)
 
 
 @pytest.mark.parametrize("train", [False, True])

@@ -17,6 +17,9 @@ from repro.core.execplan import (FUSED_NAME, PIPE_NAME, BWD_SUFFIX,
                                  primary_routing_hbm_bytes)
 from repro.core.planner import LANES
 from repro.kernels import ops
+from repro.kernels.conv_im2col import conv2d_im2col
+from repro.kernels.primary_routing import primary_caps_routing
+from repro.kernels.votes_routing import votes_routing
 
 KEY = jax.random.PRNGKey(0)
 
@@ -43,12 +46,20 @@ def _net(b, h, cin, kh, stride, n_ch, caps_dim, j, d, seed=0):
 
 
 def _unfused(x, w_pc, b_pc, w_cc, *, stride, iters, j, caps_dim):
-    """The per-op oracle: conv_im2col with fused squash -> reshape ->
-    votes_routing -- exactly the fallback path a per-op plan runs."""
-    pc = ops.conv2d(x, w_pc, b_pc, stride=stride, epilogue="squash",
-                    squash_dim=caps_dim)
+    """The per-op pair: conv_im2col with fused squash -> reshape ->
+    votes_routing -- the kernels a per-op plan runs."""
+    interpret = ops.should_interpret()
+    pc = conv2d_im2col(x, w_pc, b_pc, stride=stride, epilogue="squash",
+                       squash_dim=caps_dim, interpret=interpret)
     u = pc.reshape(x.shape[0], w_cc.shape[0], caps_dim)
-    return ops.votes_routing(u, w_cc, iters=iters, num_classes=j)
+    return votes_routing(u, w_cc, iters=iters, num_classes=j,
+                         interpret=interpret)
+
+
+def pipe(x, w_pc, b_pc, w_cc, **schedule):
+    """The pipe kernel on a schedule of the test's choosing."""
+    return primary_caps_routing(x, w_pc, b_pc, w_cc,
+                                interpret=ops.should_interpret(), **schedule)
 
 
 # ---------------------------------------------------------------------------
@@ -66,9 +77,8 @@ def test_pipelined_matches_unfused_pair(mode, b, h, cin, kh, stride, n_ch,
                                         c, j, d, bi, bk):
     x, w_pc, b_pc, w_cc = _net(b, h, cin, kh, stride, n_ch, c, j, d,
                                seed=h + n_ch)
-    got = ops.primary_routing(x, w_pc, b_pc, w_cc, stride=stride, iters=3,
-                              num_classes=j, mode=mode, block_i=bi,
-                              block_k=bk)
+    got = pipe(x, w_pc, b_pc, w_cc, stride=stride, iters=3, num_classes=j,
+               mode=mode, block_i=bi, block_k=bk)
     want = _unfused(x, w_pc, b_pc, w_cc, stride=stride, iters=3, j=j,
                     caps_dim=c)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -79,39 +89,40 @@ def test_pipelined_matches_unfused_pair(mode, b, h, cin, kh, stride, n_ch,
 def test_pipelined_iteration_sweep(iters):
     x, w_pc, b_pc, w_cc = _net(2, 10, 16, 3, 2, 16, 4, 5, 8, seed=iters)
     for mode in ("resident", "streamed"):
-        got = ops.primary_routing(x, w_pc, b_pc, w_cc, stride=2,
-                                  iters=iters, num_classes=5, mode=mode,
-                                  block_i=16, block_k=32)
+        got = pipe(x, w_pc, b_pc, w_cc, stride=2, iters=iters,
+                   num_classes=5, mode=mode, block_i=16, block_k=32)
         want = _unfused(x, w_pc, b_pc, w_cc, stride=2, iters=iters, j=5,
                         caps_dim=4)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_pipelined_planless_wrapper_picks_schedule():
-    """Without a plan the wrapper resolves (mode, block_i, block_k, conv
-    tiles) through the memoized plan decision and still matches."""
+def test_pipelined_wrapper_takes_schedule_from_plan():
+    """The wrapper runs the first routing layer on the plan's pipe op
+    (its mode, i-tile, K tile and conv tiles) and still matches."""
+    plan = compile_plan(SMOKE, batch=2, pipeline=True)
+    lay = SMOKE.routing_stack()[0]
     x, w_pc, b_pc, w_cc = _net(2, 10, 16, 3, 2, 16, 4, 10, 8, seed=9)
-    got = ops.primary_routing(x, w_pc, b_pc, w_cc, stride=2, iters=3,
-                              num_classes=10)
+    got = ops.primary_routing(x, w_pc, b_pc, w_cc, lay, plan)
     want = _unfused(x, w_pc, b_pc, w_cc, stride=2, iters=3, j=10,
                     caps_dim=4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
-    mode, bi, bk, cb = ops.planned_primary_routing(16, 144, 16, 64, 4, 80,
-                                                   10, 3, 2)
-    assert mode == "resident"            # smoke-scale votes fit VMEM
-    assert 1 <= bi <= 64 and 1 <= bk <= 144 and len(cb) == 3
+    op = plan.op(PIPE_NAME)
+    s = ops._schedule(lay, 2, plan, pipe=True)
+    assert (s.mode, s.block_i, s.lanes) == (op.mode, op.block_i, "caps")
+    assert op.mode == "resident"         # smoke-scale votes fit VMEM
+    assert 1 <= op.block_i <= 64 and 1 <= op.block_k <= 144
 
 
 def test_pipelined_rejects_bad_args():
     x, w_pc, b_pc, w_cc = _net(1, 10, 8, 3, 2, 12, 4, 4, 8)
     with pytest.raises(ValueError, match="unknown mode"):
-        ops.primary_routing(x, w_pc, b_pc, w_cc, stride=2, num_classes=4,
-                            mode="hybrid", block_i=8, block_k=16)
+        pipe(x, w_pc, b_pc, w_cc, stride=2, num_classes=4, mode="hybrid",
+             block_i=8, block_k=16)
     with pytest.raises(ValueError, match="not divisible"):
-        ops.primary_routing(x, w_pc, b_pc, w_cc, stride=2, num_classes=3,
-                            mode="resident", block_i=8, block_k=16)
+        pipe(x, w_pc, b_pc, w_cc, stride=2, num_classes=3,
+             mode="resident", block_i=8, block_k=16)
 
 
 # ---------------------------------------------------------------------------
@@ -123,9 +134,8 @@ def test_grad_parity_vs_unfused(mode):
     x, w_pc, b_pc, w_cc = _net(2, 10, 8, 6, 2, 12, 4, 4, 8, seed=3)
 
     def loss_fused(x, w_pc, b_pc, w_cc):
-        v = ops.primary_routing(x, w_pc, b_pc, w_cc, stride=2, iters=3,
-                                num_classes=4, mode=mode, block_i=8,
-                                block_k=32)
+        v = pipe(x, w_pc, b_pc, w_cc, stride=2, iters=3, num_classes=4,
+                 mode=mode, block_i=8, block_k=32)
         return jax.numpy.sum(jax.numpy.sin(v))
 
     def loss_split(x, w_pc, b_pc, w_cc):
@@ -176,9 +186,9 @@ def test_produce_orientation_edges_match_jnp_fwd_and_grad(mode, b, h, n_ch):
     x, w_pc, b_pc, w_cc = _net(b, h, 8, 3, 2, n_ch, 8, 4, 8, seed=h + b)
 
     def fused(*a):
-        return ops.primary_routing(*a, stride=2, iters=3, num_classes=4,
-                                   mode=mode, block_i=128, block_k=32,
-                                   bwd_mode=mode, bwd_block_i=128)
+        return pipe(*a, stride=2, iters=3, num_classes=4, mode=mode,
+                    block_i=128, block_k=32, bwd_mode=mode,
+                    bwd_block_i=128)
 
     def want(*a):
         return _jnp_pipe(*a, stride=2, j=4)
@@ -284,10 +294,11 @@ def test_pipelined_forward_matches_perop_plan_end_to_end():
 
 def test_wrapper_rejects_batch_over_plan():
     plan = compile_plan(SMOKE, batch=2, pipeline=True)
+    lay = SMOKE.routing_stack()[0]
     x, w_pc, b_pc, w_cc = _net(4, 10, 16, 3, 2, 16, 4, 10, 8)
     with pytest.raises(ValueError, match="exceeds the plan's batch"):
-        ops.primary_routing(x, w_pc, b_pc, w_cc, plan=plan)
-    out = ops.primary_routing(x[:1], w_pc, b_pc, w_cc, plan=plan)
+        ops.primary_routing(x, w_pc, b_pc, w_cc, lay, plan)
+    out = ops.primary_routing(x[:1], w_pc, b_pc, w_cc, lay, plan)
     assert out.shape == (1, 80)
 
 
@@ -358,4 +369,6 @@ def test_summary_and_pmu_cover_pipelined_phase():
 
 
 def test_plan_cache_bounded():
-    assert ops.planned_primary_routing.cache_info().maxsize == 64
+    """The one plan cache left (the wrappers resolve their schedules at
+    trace time) is bounded."""
+    assert compile_plan.cache_info().maxsize == 64

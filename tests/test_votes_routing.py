@@ -1,7 +1,8 @@
-"""Fused votes+routing megakernel: parity vs the jnp reference and the
-split caps_votes->routing path (ragged i-blocks, non-power-of-two capsule
-counts, batch>1, both schedules), the plan's resident-vs-streamed
-decision, PlanError boundaries, and the modeled u_hat HBM savings."""
+"""Fused votes+routing megakernel: parity vs the jnp reference (ragged
+i-blocks, non-power-of-two capsule counts, batch>1, bf16 inputs, both
+schedules), the wrapper's schedule from the plan, the plan's
+resident-vs-streamed decision, PlanError boundaries, and the modeled
+u_hat HBM savings."""
 
 import jax
 import jax.numpy as jnp
@@ -12,11 +13,11 @@ from repro.configs.registry import get_config, get_smoke_config
 from repro.core import capsnet, execplan
 from repro.core.capsnet import CapsNetConfig
 from repro.core.execplan import (FUSED_NAME, PlanError, compile_plan,
-                                 plan_votes_routing,
-                                 split_votes_routing_hbm_bytes,
+                                 plan_votes_routing, plan_votes_routing_bwd,
                                  votes_routing_hbm_bytes)
 from repro.core.planner import VMEM_BYTES
 from repro.kernels import ops, ref
+from repro.kernels.votes_routing import votes_routing as vr_kernel
 
 KEY = jax.random.PRNGKey(0)
 
@@ -34,9 +35,31 @@ def _uv(b, i, c, jd, seed=0):
     return u, w
 
 
+def fused(u, w, *, j, iters=3, **schedule):
+    """The kernel on a schedule of the test's choosing."""
+    return vr_kernel(u, w, iters=iters, num_classes=j,
+                     interpret=ops.should_interpret(), **schedule)
+
+
+def _ref(u, w, j, iters=3):
+    b, i, _ = u.shape
+    return ref.routing(ref.caps_votes(u, w).reshape(b, i, j, -1),
+                       iters).reshape(b, -1)
+
+
 # ---------------------------------------------------------------------------
-# Kernel parity: fused == jnp reference == split caps_votes -> routing
+# Kernel parity: fused == jnp reference
 # ---------------------------------------------------------------------------
+
+# (b, i, c, j, d, block_i): the MNIST head's widths and its batch-1 case,
+# wider capsules, and i-tiles that do not divide I.
+SHAPES = [
+    (1, 64, 8, 10, 16, 64),
+    (2, 256, 8, 10, 16, 128),
+    (3, 128, 16, 5, 16, 128),
+    (1, 1152, 8, 10, 16, 128),
+]
+
 
 @pytest.mark.parametrize("mode", ["resident", "streamed"])
 @pytest.mark.parametrize("b,i,c,j,d,bi", [
@@ -44,54 +67,78 @@ def _uv(b, i, c, jd, seed=0):
     (2, 100, 8, 10, 16, 32),      # ragged final i-block (100 % 32)
     (3, 135, 8, 5, 8, 64),        # batch > 1 + ragged tail
     (2, 27, 4, 4, 8, 8),          # odd non-power-of-two capsule count
-])
-def test_fused_matches_reference_and_split(mode, b, i, c, j, d, bi):
+    (2, 300, 8, 5, 8, 128),       # ragged tail of a lane-wide i-tile
+    (2, 100, 8, 5, 8, 256),       # i-tile wider than I (clamped)
+] + SHAPES)
+def test_fused_matches_reference(mode, b, i, c, j, d, bi):
     u, w = _uv(b, i, c, j * d, seed=i)
-    got = ops.votes_routing(u, w, iters=3, num_classes=j, mode=mode,
-                            block_i=bi)
-    want = ref.routing(ref.caps_votes(u, w).reshape(b, i, j, d),
-                       3).reshape(b, j * d)
-    split = ops.routing(ops.caps_votes(u, w, block_i=bi), iters=3,
-                        num_classes=j)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(split),
+    got = fused(u, w, j=j, mode=mode, block_i=bi)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_ref(u, w, j)),
                                rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("mode", ["resident", "streamed"])
+@pytest.mark.parametrize("b,i,c,j,d,bi", SHAPES)
+def test_fused_bf16_inputs(mode, b, i, c, j, d, bi):
+    """bf16 capsules and weights route to the fp32 reference of the same
+    (rounded) inputs within bf16's precision."""
+    u, w = (a.astype(jnp.bfloat16) for a in _uv(b, i, c, j * d, seed=i))
+    got = fused(u, w, j=j, mode=mode, block_i=bi)
+    want = _ref(u.astype(jnp.float32), w.astype(jnp.float32), j)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("mode", ["resident", "streamed"])
 @pytest.mark.parametrize("iters", [1, 2, 5])
-def test_fused_iteration_sweep(mode, iters):
-    u, w = _uv(2, 96, 8, 40, seed=iters)
-    got = ops.votes_routing(u, w, iters=iters, num_classes=5, mode=mode,
-                            block_i=32)
-    want = ref.routing(ref.caps_votes(u, w).reshape(2, 96, 5, 8),
-                       iters).reshape(2, 40)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+@pytest.mark.parametrize("b,i,j,d,bi", [(2, 96, 5, 8, 32),
+                                        (1, 64, 10, 16, 32),
+                                        (2, 1152, 10, 16, 128)])
+def test_fused_iteration_sweep(mode, iters, b, i, j, d, bi):
+    u, w = _uv(b, i, 8, j * d, seed=iters)
+    got = fused(u, w, j=j, iters=iters, mode=mode, block_i=bi)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_ref(u, w, j, iters)),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_fused_routing_matches_capsnet_module():
+    """The kernel routes exactly like the model's own jnp routing."""
+    u, w = _uv(2, 128, 8, 160, seed=5)
+    got = fused(u, w, j=10, mode="resident", block_i=128)
+    want = capsnet.routing_by_agreement(
+        jnp.einsum("bic,inc->bin", u, w).reshape(2, 128, 10, 16), 3)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want.reshape(2, 160)),
                                rtol=1e-5, atol=1e-6)
 
 
 def test_fused_rejects_bad_mode_and_classes():
     u, w = _uv(1, 16, 4, 20)
     with pytest.raises(ValueError, match="unknown mode"):
-        ops.votes_routing(u, w, num_classes=5, mode="hybrid", block_i=8)
+        fused(u, w, j=5, mode="hybrid", block_i=8)
     with pytest.raises(ValueError, match="not divisible"):
-        ops.votes_routing(u, w, num_classes=3, mode="resident", block_i=8)
+        fused(u, w, j=3, mode="resident", block_i=8)
 
 
-def test_fused_planless_wrapper_picks_schedule():
-    """Without a plan the wrapper resolves (mode, block_i) through the
-    memoized plan decision and still matches the reference."""
-    u, w = _uv(2, 150, 8, 80, seed=7)
-    got = ops.votes_routing(u, w, iters=3, num_classes=10)
-    want = ref.routing(ref.caps_votes(u, w).reshape(2, 150, 10, 8),
-                       3).reshape(2, 80)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+def test_fused_wrapper_takes_schedule_from_plan():
+    """The wrapper runs the layer on its plan op's schedule, plans a
+    forward-only plan's backward at the plan's budget, and matches the
+    reference."""
+    plan = compile_plan(NONPOW2, batch=2)
+    lay = NONPOW2.routing_stack()[0]
+    u, w = _uv(2, lay.in_caps, lay.in_dim, lay.jd, seed=7)
+    got = ops.votes_routing(u, w, lay, plan)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_ref(u, w, lay.num_caps)),
                                rtol=1e-5, atol=1e-6)
-    mode, bi, lanes = ops.planned_votes_routing(150, 8, 80, 10, 3, 2)
-    assert lanes == "caps"
-    assert mode == "resident"               # MNIST-scale votes fit VMEM
-    assert 1 <= bi <= 150
+    op = plan.op(lay.name)
+    bwd = plan_votes_routing_bwd(lay.in_caps, lay.in_dim, lay.jd,
+                                 lay.num_caps, batch=2, iters=lay.iters)
+    assert ops._schedule(lay, 2, plan) == (
+        lay.iters, lay.num_caps, op.mode, op.block_i, bwd.mode,
+        bwd.block_i, op.lanes, bwd.lanes, False)
+    assert op.mode == "resident" and op.lanes == "caps"
 
 
 # ---------------------------------------------------------------------------
@@ -154,23 +201,22 @@ def test_streamed_plan_executes_config_old_path_could_not():
 def test_fused_wrapper_rejects_batch_over_plan():
     """A batch larger than the plan's would scale the VMEM scratch past
     the validated footprint; smaller batches are within the bound."""
-    plan = compile_plan(CapsNetConfig(use_decoder=False), batch=2)
-    cfg = CapsNetConfig()
+    cfg = CapsNetConfig(use_decoder=False)
+    plan = compile_plan(cfg, batch=2)
+    lay = cfg.routing_stack()[0]
     u, w = _uv(4, cfg.num_primary, cfg.primary_dim,
                cfg.num_classes * cfg.class_dim, seed=11)
     with pytest.raises(ValueError, match="exceeds the plan's batch"):
-        ops.votes_routing(u, w, plan=plan)
-    out = ops.votes_routing(u[:1], w, plan=plan)          # smaller: fine
+        ops.votes_routing(u, w, lay, plan)
+    out = ops.votes_routing(u[:1], w, lay, plan)          # smaller: fine
     assert out.shape == (1, cfg.num_classes * cfg.class_dim)
 
 
 def test_fused_modes_agree_on_same_network():
     """Resident and streamed schedules are numerically interchangeable."""
     u, w = _uv(2, 600, 4, 80, seed=3)
-    res = ops.votes_routing(u, w, iters=3, num_classes=10, mode="resident",
-                            block_i=128)
-    stre = ops.votes_routing(u, w, iters=3, num_classes=10, mode="streamed",
-                             block_i=16)
+    res = fused(u, w, j=10, mode="resident", block_i=128)
+    stre = fused(u, w, j=10, mode="streamed", block_i=16)
     np.testing.assert_allclose(np.asarray(res), np.asarray(stre),
                                rtol=1e-5, atol=1e-6)
 
@@ -192,15 +238,12 @@ def test_fused_streamed_pass_matches_resident_and_reference(b, i, c, j, d,
     s-accumulation stream, votes recomputed per pass) matches the
     resident schedule (votes computed once) and the jnp reference."""
     u, w = _uv(b, i, c, j * d, seed=i + iters)
-    fused = ops.votes_routing(u, w, iters=iters, num_classes=j,
-                              mode="streamed", block_i=bi)
-    resident = ops.votes_routing(u, w, iters=iters, num_classes=j,
-                                 mode="resident", block_i=bi)
-    want = ref.routing(ref.caps_votes(u, w).reshape(b, i, j, d),
-                       iters).reshape(b, j * d)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(resident),
+    streamed = fused(u, w, j=j, iters=iters, mode="streamed", block_i=bi)
+    resident = fused(u, w, j=j, iters=iters, mode="resident", block_i=bi)
+    np.testing.assert_allclose(np.asarray(streamed), np.asarray(resident),
                                rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(want),
+    np.testing.assert_allclose(np.asarray(streamed),
+                               np.asarray(_ref(u, w, j, iters)),
                                rtol=1e-5, atol=1e-6)
 
 
@@ -250,46 +293,21 @@ def test_streamed_w_traffic_halved_vs_2pass():
 # ---------------------------------------------------------------------------
 
 def test_plan_reports_zero_uhat_traffic_and_savings():
+    """The fused op's modeled traffic is u, W and v alone (plus the
+    relayout onto lanes): the u_hat round-trip a votes kernel and a
+    routing kernel would pay between them is never counted."""
     plan = compile_plan(CapsNetConfig(), batch=8)
-    fused = plan.op(FUSED_NAME)
-    assert fused.uhat_hbm_bytes == 0
-    dims = (8, CapsNetConfig().num_primary, CapsNetConfig().primary_dim,
-            CapsNetConfig().num_classes * CapsNetConfig().class_dim)
-    split_total, uhat = split_votes_routing_hbm_bytes(*dims)
-    # u_hat is written once and read back once by the split pair
-    assert uhat == 2 * 8 * 1152 * 160 * execplan.ELEM_BYTES
-    assert fused.mode == "resident"
-    fused_total = votes_routing_hbm_bytes(*dims, n_passes=1)
-    relayout = execplan.lane_relayout_hbm_bytes(*dims)
-    assert fused.hbm_bytes == fused_total + relayout
-    assert split_total - fused_total == uhat    # savings == the round-trip
-
-
-# ---------------------------------------------------------------------------
-# Satellite: plan-less split-path pick respects batch + budget, caches
-# bounded
-# ---------------------------------------------------------------------------
-
-def test_planned_block_i_shrinks_with_batch():
-    bi1 = ops.planned_block_i(1152, 8, 160)
-    bi_big = ops.planned_block_i(1152, 8, 160, batch=4096)
-    assert bi_big <= bi1
-    for batch, bi in ((1, bi1), (4096, bi_big)):
-        assert execplan._votes_vmem(batch, bi, 8, 160) <= VMEM_BYTES
-
-
-def test_planned_block_i_respects_small_budget():
-    budget = 200_000
-    bi = ops.planned_block_i(1152, 8, 160, 4, budget)
-    assert execplan._votes_vmem(4, bi, 8, 160) <= budget
-    with pytest.raises(PlanError, match="largest feasible batch"):
-        ops.planned_block_i(1152, 8, 160, 10_000, budget)
-
-
-def test_plan_caches_are_bounded():
-    assert ops.planned_block_i.cache_info().maxsize == 64
-    assert ops.planned_votes_routing.cache_info().maxsize == 64
-    assert ops.planned_conv_blocks.cache_info().maxsize == 64
+    fused_op = plan.op(FUSED_NAME)
+    assert fused_op.uhat_hbm_bytes == 0
+    b, i, c, jd = (8, CapsNetConfig().num_primary,
+                   CapsNetConfig().primary_dim,
+                   CapsNetConfig().num_classes * CapsNetConfig().class_dim)
+    assert fused_op.mode == "resident"
+    fused_total = votes_routing_hbm_bytes(b, i, c, jd, n_passes=1)
+    assert fused_total == (b * i * c + i * jd * c + b * jd) \
+        * execplan.ELEM_BYTES
+    relayout = execplan.lane_relayout_hbm_bytes(b, i, c, jd)
+    assert fused_op.hbm_bytes == fused_total + relayout
 
 
 # ---------------------------------------------------------------------------
@@ -312,14 +330,13 @@ def test_class_lanes_matches_reference_fwd_and_grad(mode, b, i, j, d, bi):
     u, w = _uv(b, i, 4, j * d, seed=i + j)
     dv = jax.random.normal(jax.random.fold_in(KEY, 99), (b, j * d))
 
-    def fused(u, w):
-        return ops.votes_routing(u, w, iters=3, num_classes=j, mode=mode,
-                                 block_i=bi, lanes="classes")
+    def run(u, w):
+        return fused(u, w, j=j, mode=mode, block_i=bi, lanes="classes")
 
-    np.testing.assert_allclose(np.asarray(fused(u, w)),
+    np.testing.assert_allclose(np.asarray(run(u, w)),
                                np.asarray(_routing_ref(u, w, j)),
                                rtol=1e-5, atol=1e-6)
-    got = jax.grad(lambda u, w: jnp.sum(fused(u, w) * dv), (0, 1))(u, w)
+    got = jax.grad(lambda u, w: jnp.sum(run(u, w) * dv), (0, 1))(u, w)
     want = jax.grad(lambda u, w: jnp.sum(_routing_ref(u, w, j) * dv),
                     (0, 1))(u, w)
     for g, r in zip(got, want):
